@@ -174,15 +174,10 @@ def iter_eqns(jaxpr) -> Iterator[Tuple[jcore.JaxprEqn, jcore.Jaxpr]]:
 
 def eqn_source(eqn) -> Optional[str]:
     """'file:line' of the user frame that staged the equation."""
-    try:
-        frame = source_info_util.user_frame(eqn.source_info)
-    except Exception:
-        frame = None
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
     if frame is None:
         return None
-    line = getattr(frame, "start_line", None) or getattr(
-        frame, "line_num", None)
-    return f"{frame.file_name}:{line}"
+    return f"{frame.file_name}:{frame.start_line}"
 
 
 def format_eqn(eqn, width: int = 140) -> str:

@@ -12,8 +12,9 @@ flags any shape that would not route to Pallas.
 
 Two further audits ride on the same rule (ISSUE 13):
 
-* a tuned table attached as ``meta["tuned_table"]`` (the live table on
-  the ``kernel_inventory`` target) is checked entry-by-entry against
+* tuned tables attached as ``meta["tuned_tables"]`` (every committed
+  table on the ``kernel_inventory`` target) are checked entry-by-entry
+  against
   the declared candidate spaces — the membership test
   ``tuning.resolve`` applies at dispatch, so a finding here means
   dispatch is silently ignoring that entry (recording ``stale``) and
@@ -109,34 +110,32 @@ class PallasRoutingRule(Rule):
         declared candidate space — the exact membership test dispatch
         (tuning.resolve) applies, so a finding means the entry is dead
         weight: dispatch records ``stale`` and uses hand-picked params."""
-        table = ctx.meta.get("tuned_table")
-        if table is None:
-            return
         from bigdl_tpu.ops.pallas import tuning
 
-        src = str(getattr(table, "path", "") or "")
-        for key, ent in sorted(getattr(table, "entries", {}).items()):
-            try:
-                kernel, shape = tuning.parse_key(key)
-            except ValueError:
-                yield Finding(rule=self.name, target=ctx.name,
-                              message=f"malformed tuned-table key "
-                                      f"'{key}'", source=src)
-                continue
-            params = ent.get("params", {})
-            try:
-                cands = tuning.candidates(kernel, shape)
-            except Exception:
-                cands = []
-            if params not in cands:
-                yield Finding(
-                    rule=self.name, target=ctx.name,
-                    message=f"{kernel} {shape}: tuned-table entry "
-                            f"{params} is outside the declared "
-                            "candidate space — dispatch falls back to "
-                            "hand-picked params (source=stale); re-run "
-                            "tools/autotune.py --sweep",
-                    primitive=kernel, source=src)
+        for table in ctx.meta.get("tuned_tables", ()):
+            src = str(getattr(table, "path", "") or "")
+            for key, ent in sorted(table.entries.items()):
+                try:
+                    kernel, shape = tuning.parse_key(key)
+                except ValueError:
+                    yield Finding(rule=self.name, target=ctx.name,
+                                  message=f"malformed tuned-table key "
+                                          f"'{key}'", source=src)
+                    continue
+                params = ent.get("params", {})
+                try:
+                    cands = tuning.candidates(kernel, shape)
+                except Exception:
+                    cands = []
+                if params not in cands:
+                    yield Finding(
+                        rule=self.name, target=ctx.name,
+                        message=f"{kernel} {shape}: tuned-table entry "
+                                f"{params} is outside the declared "
+                                "candidate space — dispatch falls back "
+                                "to hand-picked params (source=stale); "
+                                "re-run tools/autotune.py --sweep",
+                        primitive=kernel, source=src)
 
     def _check_remat(self, ctx: LintContext):
         """A context declaring ``expect_remat`` (the fused-block

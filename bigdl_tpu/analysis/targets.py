@@ -10,6 +10,7 @@ intended compute dtype, and the donated-leaf expectation.
 """
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -910,24 +911,25 @@ def _kernel_shapes():
 # --------------------------------------------------------------------------
 
 @target("kernel_inventory", "inventory",
-        "tools/kernel_shapes.py fused-path shapes + live tuned table")
+        "tools/kernel_shapes.py fused-path shapes + committed tuned "
+        "tables")
 def _inventory():
-    # attach the live tuned table (tools/autotune.py output) when one
-    # is configured: the pallas-routing rule then audits every entry
-    # against the declared candidate spaces, so a stale table fails
-    # lint instead of silently downgrading dispatch to hand-picked
-    # params (ops/pallas/tuning.py resolve records source=stale)
+    # attach every committed tuned table (tools/autotune.py output —
+    # dispatch loads the one of the running device kind): the
+    # pallas-routing rule then audits every entry against the declared
+    # candidate spaces, so a stale table fails lint instead of silently
+    # downgrading dispatch to hand-picked params (ops/pallas/tuning.py
+    # resolve records source=stale)
+    import glob
+
     from bigdl_tpu.ops.pallas import tuning
 
-    meta = {"inventory": _kernel_shapes()}
-    path = tuning.table_path()
-    if path:
-        try:
-            meta["tuned_table"] = tuning.TunedTable.load(path)
-        except Exception:
-            pass  # unreadable table = no table, same as dispatch
+    tables = [tuning.TunedTable.load(p) for p in sorted(
+        glob.glob(os.path.join(tuning.tuned_dir(), "*.json")))]
     return LintContext(name="kernel_inventory", kind="inventory",
-                       jaxpr=None, meta=meta)
+                       jaxpr=None,
+                       meta={"inventory": _kernel_shapes(),
+                             "tuned_tables": tables})
 
 
 @target("fused_block_bwd", "model",

@@ -34,6 +34,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from bigdl_tpu.nn.criterion import Criterion
@@ -46,18 +47,15 @@ from bigdl_tpu.parallel.mesh import (
     plan_info,
     replicated,
 )
-from bigdl_tpu.utils.jax_compat import shard_map
 
-# wire dtypes the collective may run at; fp8 keys appear only when the
-# toolchain ships the dtype (jax>=0.4.14)
+# wire dtypes the collective may run at
 WIRE_DTYPES: Dict[str, Any] = {
     "bf16": jnp.bfloat16,
     "bfloat16": jnp.bfloat16,
+    "fp8": jnp.float8_e4m3fn,
+    "float8_e4m3fn": jnp.float8_e4m3fn,
+    "float8_e5m2": jnp.float8_e5m2,
 }
-if hasattr(jnp, "float8_e4m3fn"):
-    WIRE_DTYPES["fp8"] = jnp.float8_e4m3fn
-    WIRE_DTYPES["float8_e4m3fn"] = jnp.float8_e4m3fn
-    WIRE_DTYPES["float8_e5m2"] = jnp.float8_e5m2
 
 
 def fp16_compress(arr: np.ndarray) -> np.ndarray:

@@ -16,7 +16,7 @@ from __future__ import annotations
 import logging
 from typing import Optional
 
-import numpy as np
+import jax.numpy as jnp
 
 import bigdl_tpu.nn as nn
 import bigdl_tpu.optim as optim
@@ -68,10 +68,9 @@ class _ScaledSchedule(optim.LearningRateSchedule):
         return self.scale * self.inner.rate(step, epoch)
 
 
-
-
-def main(argv: Optional[list] = None) -> dict:
-    init_logging()
+def build(argv: Optional[list] = None):
+    """Parse ``argv`` and build the run exactly as :func:`main` trains
+    it; returns ``(configured Optimizer, validation DataSet)``."""
     p = base_parser("resnet_train", batch_size=8192, max_epoch=90, lr=0.1)
     p.add_argument("--depth", type=int, default=50)
     p.add_argument("--classNum", type=int, default=1000)
@@ -123,15 +122,15 @@ def main(argv: Optional[list] = None) -> dict:
     )
     method = make_recipe_optim(args, train_ds.batches_per_epoch())
     opt.set_optim_method(method)
-    try:
-        import jax.numpy as jnp
-
-        opt.set_compute_dtype(jnp.bfloat16)  # bf16 hot loop (north star)
-    except Exception:
-        pass
+    opt.set_compute_dtype(jnp.bfloat16)  # bf16 hot loop (north star)
     opt.set_validation(optim.Trigger.every_epoch(), val_ds,
                        [optim.Top1Accuracy(), optim.Top5Accuracy()])
-    configure(opt, args)
+    return configure(opt, args), val_ds
+
+
+def main(argv: Optional[list] = None) -> dict:
+    init_logging()
+    opt, val_ds = build(argv)
     trained = opt.optimize()
     return report_validation(
         opt, trained, val_ds, [optim.Top1Accuracy(), optim.Top5Accuracy()])

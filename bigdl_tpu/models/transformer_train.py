@@ -19,6 +19,8 @@ import logging
 import math
 from typing import Optional
 
+import jax.numpy as jnp
+
 import bigdl_tpu.nn as nn
 import bigdl_tpu.optim as optim
 from bigdl_tpu.dataset import DataSet
@@ -35,8 +37,9 @@ def _window_dataset(ids, batch: int, steps: int):
         xs.reshape(-1, steps), ys.reshape(-1, steps), batch_size=batch)
 
 
-def main(argv: Optional[list] = None) -> dict:
-    init_logging()
+def build(argv: Optional[list] = None):
+    """Parse ``argv`` and build the run exactly as :func:`main` trains
+    it; returns ``(configured Optimizer, validation DataSet)``."""
     p = base_parser("transformer_train", batch_size=8, max_epoch=5,
                     lr=1e-3)
     p.add_argument("--seqLen", type=int, default=512)
@@ -193,17 +196,18 @@ def main(argv: Optional[list] = None) -> dict:
     opt.set_gradient_clipping_by_l2_norm(args.gradClip)
     opt.set_validation(optim.Trigger.every_epoch(), val_ds,
                        [optim.Loss(crit)])
-    try:
-        import jax.numpy as jnp
+    opt.set_compute_dtype(jnp.bfloat16)
+    return configure(opt, args), val_ds
 
-        opt.set_compute_dtype(jnp.bfloat16)
-    except Exception:
-        pass
-    configure(opt, args)
+
+def main(argv: Optional[list] = None) -> dict:
+    init_logging()
+    opt, val_ds = build(argv)
     opt.optimize()
 
+    crit = opt.criterion
     results = optim.evaluate(
-        model, opt.final_params, opt.final_state, val_ds,
+        opt.model, opt.final_params, opt.final_state, val_ds,
         [optim.Loss(crit)])
     val_loss = results[0][1].result()[0]
     ppl = math.exp(min(val_loss, 30.0))

@@ -25,16 +25,19 @@ def dot_product_attention(
 ) -> jnp.ndarray:
     if use_flash is None:
         # auto: the fused kernel handles exactly the mask-free/bias-free
-        # cases, and flash_attention itself falls back to the XLA path
+        # cases, and flash_attention itself routes to the XLA path
         # off-TPU or on non-tileable shapes — so auto-enable is safe
         use_flash = mask is None and bias is None
-    if use_flash and mask is None and bias is None:
+    # the kernel's contract: no mask/bias, V shaped like K, and causal
+    # only over matching q/kv lengths (a cached decode step, Tq < Tk,
+    # takes the XLA path below).  Inside that contract a kernel error
+    # is a real error and propagates.
+    if (use_flash and mask is None and bias is None
+            and v.shape == k.shape
+            and not (causal and q.shape[2] != k.shape[2])):
         from bigdl_tpu.ops.pallas.flash_attention import flash_attention
 
-        try:
-            return flash_attention(q, k, v, causal=causal, sm_scale=scale)
-        except Exception:  # pragma: no cover - fall back off-TPU
-            pass
+        return flash_attention(q, k, v, causal=causal, sm_scale=scale)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(jnp.float32)
     scores = jnp.einsum(

@@ -198,15 +198,12 @@ def _int8_eligible(tq: int, length: int, head_dim: int) -> bool:
     that kernel's own eligibility (128-aligned contraction/output dims,
     a block size that divides Tq) plus a hard TPU-backend gate — the
     CPU tier always takes the XLA dequant path."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-        from bigdl_tpu.ops.pallas import int8_matmul as i8
-
-        return (bool(i8.candidate_params((tq, head_dim, length)))
-                and bool(i8.candidate_params((tq, length, head_dim))))
-    except Exception:
+    if jax.default_backend() != "tpu":
         return False
+    from bigdl_tpu.ops.pallas import int8_matmul as i8
+
+    return (bool(i8.candidate_params((tq, head_dim, length)))
+            and bool(i8.candidate_params((tq, length, head_dim))))
 
 
 def int8_scores(q, k_q, k_scale, out_dtype):

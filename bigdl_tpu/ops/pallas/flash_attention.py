@@ -27,8 +27,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.utils.jax_compat import tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -99,7 +99,6 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, bq, bk, interpret):
     vr = v.reshape(b * h, s, d)
     kernel = functools.partial(_attn_kernel, bq=bq, bk=bk, causal=causal,
                                sm_scale=sm_scale)
-    from jax.experimental.pallas import tpu as pltpu
 
     out, lse = pl.pallas_call(
         kernel,
@@ -122,7 +121,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, bq, bk, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
@@ -271,6 +270,7 @@ def flash_attention(
                          f"lengths, got {t} vs {s}")
     from bigdl_tpu.ops.pallas import report as _report
 
+    key_shape = (q.shape[0], q.shape[1], t, s, q.shape[3])  # tuning key
     on_tpu = (_report.force_pallas()
               or jax.default_backend() == "tpu")
     if interpret is None:
@@ -278,7 +278,7 @@ def flash_attention(
             # off TPU the interpreter would be orders of magnitude slower
             # than plain XLA — use the fused-einsum reference path unless
             # the caller explicitly opts into interpret mode (tests)
-            _report.record("flash_attention", "xla")
+            _report.record("flash_attention", "xla", key_shape)
             out, _ = _xla_attention_lse(q, k, v, causal, sm_scale)
             return out.astype(q.dtype)
         interpret = False
@@ -287,7 +287,7 @@ def flash_attention(
         # honor the requested blocks so the kernel itself is exercised
         bq, bk = min(block_q, t), min(block_k, s)
         if t % bq or s % bk:
-            _report.record("flash_attention", "xla")
+            _report.record("flash_attention", "xla", key_shape)
             out, _ = _xla_attention_lse(q, k, v, causal, sm_scale)
             return out.astype(q.dtype)
     else:
@@ -297,13 +297,11 @@ def flash_attention(
         from bigdl_tpu.ops.pallas import tuning as _tuning
 
         bq, bk = fit_block(t, block_q), fit_block(s, block_k, multiple=8)
-        tp = _tuning.resolve(
-            "flash_attention",
-            (q.shape[0], q.shape[1], t, s, q.shape[3]),
-            {"bq": bq, "bk": bk})
+        tp = _tuning.resolve("flash_attention", key_shape,
+                             {"bq": bq, "bk": bk})
         bq, bk = tp["bq"], tp["bk"]
         if bq is None or bk is None:
-            _report.record("flash_attention", "xla")
+            _report.record("flash_attention", "xla", key_shape)
             out, _ = _xla_attention_lse(q, k, v, causal, sm_scale)
             return out.astype(q.dtype)
     _report.record("flash_attention", "pallas")

@@ -99,7 +99,7 @@ def int8_matmul_dequant(x_q: jnp.ndarray, w_q: jnp.ndarray,
               or jax.default_backend() == "tpu")
     if interpret is None:
         if not on_tpu or os.environ.get("BIGDL_TPU_INT8_PALLAS_DISABLE"):
-            _report.record("int8_matmul", "xla")
+            _report.record("int8_matmul", "xla", (m, k, n))
             acc = jax.lax.dot_general(
                 x_q, w_q, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
@@ -112,7 +112,7 @@ def int8_matmul_dequant(x_q: jnp.ndarray, w_q: jnp.ndarray,
     bm = _tuning.resolve("int8_matmul", (m, k, n),
                          {"bm": _pick_bm(m, k, n)})["bm"]
     if bm is None or k % 128 or n % 128 or k * n > 8 * 1024 * 1024:
-        _report.record("int8_matmul", "xla")
+        _report.record("int8_matmul", "xla", (m, k, n))
         acc = jax.lax.dot_general(
             x_q, w_q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
@@ -129,7 +129,8 @@ def int8_matmul_dequant(x_q: jnp.ndarray, w_q: jnp.ndarray,
         bm_l = bm if m_l == m else _tuning.resolve(
             "int8_matmul", (m_l, k, n), {"bm": _pick_bm(m_l, k, n)})["bm"]
         if bm_l is None:  # local rows no longer tileable
-            _report.record("int8_matmul", "pallas_local_xla")
+            _report.record("int8_matmul", "pallas_local_xla",
+                           (m_l, k, n))
             acc = jax.lax.dot_general(
                 x_, w_, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)

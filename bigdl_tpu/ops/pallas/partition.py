@@ -24,11 +24,11 @@ pipeline schedule proved it in round 4).
 
 Mesh discovery at trace time (:func:`current_kernel_mesh`):
 
-* inside a ``shard_map`` body the compat layer
-  (``utils/jax_compat.py``) reports which axes are already Manual —
-  the kernel may nest a shard_map over the remaining Auto axes only
-  (e.g. flash over ``model`` inside a pipeline stage whose
-  ``pipe``/``data`` are manual), and a fully-manual region
+* inside a ``shard_map`` body the ambient abstract mesh
+  (``jax.sharding.get_abstract_mesh()``) reports which axes are
+  already Manual — the kernel may nest a shard_map over the remaining
+  Auto axes only (e.g. flash over ``model`` inside a pipeline stage
+  whose ``pipe``/``data`` are manual), and a fully-manual region
   (ring/Ulysses bodies) yields no candidates, so the kernel runs as a
   plain per-device call;
 * under plain ``jit`` no region is being traced — the engine
@@ -45,12 +45,12 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import math
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-from bigdl_tpu.utils.jax_compat import active_mesh, manual_axes, shard_map
 
 _KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
     "bigdl_tpu_kernel_mesh", default=None)
@@ -70,6 +70,10 @@ def kernel_mesh_scope(mesh):
 def current_kernel_mesh():
     """-> (mesh, shardable_axes, remaining_axes) or None at trace time.
 
+    ``mesh`` is the ambient abstract mesh inside a ``shard_map`` body
+    (nested kernel shard_maps must be built on it, not on a concrete
+    ``Mesh``), else the engine-published concrete mesh.
+
     ``shardable_axes``: mesh axes a kernel may shard its batch dims
     over (size > 1, not already manual in the ambient region).
     ``remaining_axes``: EVERY axis not already manual — Mosaic custom
@@ -78,10 +82,14 @@ def current_kernel_mesh():
     so a kernel shard_map must take all of these, sharding over the
     shardable ones and replicating along the rest.
     """
-    mesh = active_mesh() or _KERNEL_MESH.get()
-    if mesh is None:
-        return None
-    manual = manual_axes() & frozenset(mesh.axis_names)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        mesh = _KERNEL_MESH.get()
+        if mesh is None:
+            return None
+        manual = frozenset()
+    else:
+        manual = frozenset(mesh.manual_axes)
     remaining = frozenset(n for n in mesh.axis_names if n not in manual)
     avail = frozenset(n for n in remaining if mesh.shape[n] > 1)
     return mesh, avail, remaining
@@ -122,9 +130,7 @@ def shard_kernel_call(
     # single-device mesh under plain jit: ShardingContext(num_devices=1)
     # lowers as-is; inside a partially-manual region we must still wrap
     # (Mosaic refuses partial-manual even over size-1 auto axes)
-    ambient_manual = bool(manual_axes())
-    import math
-
+    ambient_manual = len(remaining) < len(mesh.axis_names)
     if not ambient_manual and \
             math.prod(mesh.shape[a] for a in remaining) == 1:
         return fn(*args)
@@ -159,7 +165,9 @@ def shard_kernel_call(
 
     # manual over EVERY remaining axis (the Mosaic full-manual rule),
     # sharded over the kept ones, replicated along the rest
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+    # (an ambient abstract mesh is taken from the context, mesh=None)
+    return jax.shard_map(
+        body, mesh=mesh if isinstance(mesh, jax.sharding.Mesh) else None,
+        in_specs=in_specs, out_specs=out_specs,
         axis_names=remaining, check_vma=False,
     )(*args)

@@ -29,9 +29,10 @@ the offending shape named.
 Env knobs (docs/observability.md):
 
 * ``BIGDL_TPU_TUNED_TABLE=<path>`` — load this table at first kernel
-  dispatch (default: ``tuned/<device_kind>.json`` next to the repo
-  root, if present; missing file means an empty table, i.e. hand-picked
-  params everywhere).
+  dispatch (default: the committed ``tuned/<device_kind>.json`` of the
+  RUNNING device kind; no table for this device means hand-picked
+  params everywhere, recorded as ``source=default`` — never another
+  chip's tiles).
 * ``BIGDL_TPU_TUNE=0`` — ignore any table entirely (A/B escape hatch).
 """
 from __future__ import annotations
@@ -43,8 +44,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
     "TunedTable", "candidates", "default_params", "entry_key",
-    "get_tuned_table", "resolve", "set_tuned_table", "table_path",
-    "tuning_enabled",
+    "get_tuned_table", "resolve", "set_tuned_table", "table_file",
+    "table_path", "tuning_enabled",
 ]
 
 SCHEMA = "bigdl_tpu_tuned_table_v1"
@@ -242,42 +243,48 @@ def tuning_enabled() -> bool:
     return os.environ.get("BIGDL_TPU_TUNE", "") != "0"
 
 
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
+def tuned_dir() -> str:
+    """The directory of committed tables, ``tuned/`` under the repo
+    root."""
+    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))), "tuned")
 
 
-def table_path() -> Optional[str]:
+def table_file(device_kind: str) -> str:
+    """``tuned/<device-kind>.json`` — where the sweep persists, and
+    dispatch looks for, the table of one device kind
+    (``"TPU v5 lite"`` -> ``tuned/tpu-v5-lite.json``)."""
+    return os.path.join(tuned_dir(),
+                        device_kind.lower().replace(" ", "-") + ".json")
+
+
+def table_path(device_kind: Optional[str] = None) -> Optional[str]:
     """Where the live table comes from: ``BIGDL_TPU_TUNED_TABLE`` when
-    set, else the first existing ``tuned/*.json`` under the repo root
-    (the sweep's default output location)."""
+    set, else the committed table of ``device_kind`` (default: the
+    running ``jax.devices()[0].device_kind``) when there is one.  A
+    deviceless compile names its target kind; nothing ever loads
+    another chip's table."""
     env = os.environ.get("BIGDL_TPU_TUNED_TABLE")
     if env:
         return env
-    tuned_dir = os.path.join(_repo_root(), "tuned")
-    try:
-        names = sorted(n for n in os.listdir(tuned_dir)
-                       if n.endswith(".json"))
-    except OSError:
-        return None
-    return os.path.join(tuned_dir, names[0]) if names else None
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    path = table_file(device_kind)
+    return path if os.path.exists(path) else None
 
 
 def get_tuned_table() -> Optional[TunedTable]:
-    """The process-wide table, lazily loaded once.  None when no table
-    is configured or the file is unreadable (unreadable is reported as
-    a ``stale`` fallback by :func:`resolve`, not an exception — kernel
-    dispatch runs at trace time inside jit)."""
+    """The process-wide table, lazily loaded once; None when there is
+    no table for the running device.  A table that exists but cannot be
+    read is an error, raised at the first kernel dispatch."""
     global _TABLE, _TABLE_LOADED
     with _LOCK:
         if not _TABLE_LOADED:
-            _TABLE_LOADED = True
             path = table_path()
-            if path:
-                try:
-                    _TABLE = TunedTable.load(path)
-                except Exception:
-                    _TABLE = None
+            _TABLE = TunedTable.load(path) if path else None
+            _TABLE_LOADED = True
         return _TABLE
 
 

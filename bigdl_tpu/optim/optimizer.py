@@ -60,7 +60,7 @@ from bigdl_tpu.telemetry import costmodel, numerics as numerics_mod, programs
 from bigdl_tpu.telemetry import debug_server, flightrecorder
 from bigdl_tpu.telemetry.tracer import CAT_TRAIN, get_tracer, set_correlation
 from bigdl_tpu.utils import file_io
-from bigdl_tpu.utils.flatten import global_norm
+from bigdl_tpu.utils.flatten import cast_floating, global_norm
 from bigdl_tpu.utils.serialization import load_pytree, save_pytree
 
 logger = logging.getLogger("bigdl_tpu.optim")
@@ -299,6 +299,13 @@ def make_train_step(
         return {key: tree[key]}
 
     def _loss_and_grad(params, model_state, rng, features, targets):
+        if compute_dtype is not None:
+            # the layers compute in their INPUT's dtype (weights are
+            # cast to it), so float features must enter in the compute
+            # dtype too — an f32 image batch would otherwise drag the
+            # whole network back to f32 activations
+            features = cast_floating(features, compute_dtype)
+
         def loss_fn(p):
             p_c = (
                 jax.tree_util.tree_map(lambda x: x.astype(compute_dtype), p)
@@ -901,15 +908,18 @@ class LocalOptimizer(Optimizer):
                     "grad_norm", round(mon.last["grad_norm"], 6))
                 metrics.set_value(
                     "update_ratio", round(mon.last["update_ratio"], 8))
-            if self._step_cost is not None and throughput > 0 \
-                    and n_records:
+            # (a lowered-stage stamp carries no flops on some backends:
+            # then there is no MFU to print, not an MFU of zero)
+            if self._step_cost is not None and self._step_cost.flops \
+                    and throughput > 0 and n_records:
                 step_s = n_records / throughput
-                metrics.set_value("mfu", round(
-                    self._step_cost.mfu(step_s), 5))
+                mfu = self._step_cost.mfu(step_s)
+                if mfu is not None:  # None on CPU: no device metric
+                    metrics.set_value("mfu", round(mfu, 5))
+                    programs.get_program_registry().record_mfu(
+                        self._step_program, mfu)
                 metrics.set_value("bytes_per_sec", round(
                     self._step_cost.bytes_per_s(step_s), 1))
-                programs.get_program_registry().record_mfu(
-                    self._step_program, self._step_cost.mfu(step_s))
             # HBM ledger rides the training log cadence (rate-limited
             # by its own knob; no-op device query + dict merge on CPU)
             programs.get_hbm_ledger().maybe_sample()

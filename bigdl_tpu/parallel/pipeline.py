@@ -49,10 +49,10 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bigdl_tpu.nn.module import Module
-from bigdl_tpu.utils.jax_compat import shard_map
 
 logger = logging.getLogger("bigdl_tpu.parallel")
 

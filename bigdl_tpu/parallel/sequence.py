@@ -23,10 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from bigdl_tpu.utils.jax_compat import shard_map
 
 from bigdl_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 

@@ -267,10 +267,10 @@ class ServingMetrics:
             b = self._bytes_done
         return b / dt if dt > 0 else 0.0
 
-    def mfu(self) -> float:
+    def mfu(self) -> Optional[float]:
         """Model-flops-utilization over wall-clock since engine start
         (idle time counts against it — a serving engine's honest
-        number)."""
+        number); None on a CPU backend, which has no peak."""
         dt = time.perf_counter() - self._t0
         with self._lock:
             f, n = self._flops_done, self._compute_devices
@@ -279,6 +279,7 @@ class ServingMetrics:
         return costmodel.mfu(f, dt, n_devices=n)
 
     def snapshot(self) -> dict:
+        mfu = self.mfu()
         return {
             "completed": self.completed,
             "rejected": self.rejected,
@@ -297,7 +298,7 @@ class ServingMetrics:
             "p95_tick_ms": round(self.tick_ms(95), 3),
             "prefill_ms": round(1e3 * self.base.get(PREFILL), 3),
             "decode_ms": round(1e3 * self.base.get(TICK), 3),
-            "mfu": round(self.mfu(), 5),
+            "mfu": None if mfu is None else round(mfu, 5),
             "gflops_per_sec": round(self.gflops_per_sec(), 3),
             "bytes_per_sec": round(self.bytes_per_sec(), 1),
             "pages_in_use": self.pages_in_use,
@@ -339,7 +340,8 @@ class ServingMetrics:
         training runs; returns the snapshot written."""
         snap = self.snapshot()
         for key, tag in self.SUMMARY_TAGS.items():
-            summary.add_scalar(tag, float(snap[key]), step)
+            if snap[key] is not None:
+                summary.add_scalar(tag, float(snap[key]), step)
         return snap
 
     def log_line(self) -> str:
@@ -365,8 +367,9 @@ class ServingMetrics:
         if s["spec_acceptance_rate"]:
             line += f" | spec acc={100 * s['spec_acceptance_rate']:.0f}%"
         if s["gflops_per_sec"]:
-            line += (f" | {s['gflops_per_sec']:.1f} GF/s | "
-                     f"mfu={100 * s['mfu']:.2f}%")
+            line += f" | {s['gflops_per_sec']:.1f} GF/s"
+        if s["mfu"]:
+            line += f" | mfu={100 * s['mfu']:.2f}%"
         return line
 
 

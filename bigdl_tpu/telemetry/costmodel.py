@@ -2,16 +2,15 @@
 
 Every compiled program (train step, serving forward, decode tick) is
 stamped at warmup with XLA's ``cost_analysis()`` / ``memory_analysis()``
-flops + bytes, routed through :mod:`bigdl_tpu.utils.jax_compat` so
-0.4.x backends that return nothing degrade to zeros instead of raising.
-From the stamp we derive model-flops-utilization (MFU) and bytes/s per
-step, surfaced into ``Metrics`` / ``log_line()`` / JSONL, and persist a
-per-program cost table that ``tools/autotune.py`` can later consult for
-block/tile selection.
+flops + bytes.  From the stamp we derive model-flops-utilization (MFU)
+and bytes/s per step, surfaced into ``Metrics`` / ``log_line()`` /
+JSONL, and persist a per-program cost table that ``tools/autotune.py``
+can later consult for block/tile selection.
 
-Peak FLOP/s is resolved per device kind (override with
-``BIGDL_TPU_PEAK_FLOPS``); on CPU hosts the peak is a nominal constant,
-so CPU MFU is only meaningful as a relative number across runs.
+Peak FLOP/s is resolved per device kind from the one table below
+(override with ``BIGDL_TPU_PEAK_FLOPS``).  A device kind that is not in
+the table is an error, not a default, and a host-CPU backend has no
+peak at all: MFU is a device metric and a CPU run reports none.
 Disable the whole subsystem with ``BIGDL_TPU_COST_DISABLE=1``.
 """
 from __future__ import annotations
@@ -25,8 +24,10 @@ from typing import Optional
 
 from ..utils import jax_compat
 
-# per-chip peak dense (bf16) FLOP/s, matched as substrings of the
-# lowercased device_kind; CPU falls through to the nominal constant
+# per-chip peak dense (bf16) FLOP/s from the public TPU specs, matched
+# as substrings of the lowercased device_kind — the one peaks table of
+# the repo (bench.py and the tools read it through
+# peak_flops_per_device)
 _PEAK_BY_KIND = (
     ("v6 lite", 918e12),
     ("v6e", 918e12),
@@ -38,7 +39,6 @@ _PEAK_BY_KIND = (
     ("v3", 123e12),
     ("v2", 45e12),
 )
-_NOMINAL_CPU_PEAK = 1.0e11
 
 
 def cost_accounting_enabled() -> bool:
@@ -47,35 +47,38 @@ def cost_accounting_enabled() -> bool:
 
 
 def peak_flops_per_device(device=None) -> float:
-    """Peak dense FLOP/s of one device (``BIGDL_TPU_PEAK_FLOPS`` wins)."""
+    """Peak dense FLOP/s of one device (``BIGDL_TPU_PEAK_FLOPS`` wins).
+    Raises ``LookupError`` for a device kind the table does not hold:
+    an assumed peak would print a made-up utilization."""
     env = os.environ.get("BIGDL_TPU_PEAK_FLOPS")
     if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
-        if device is None:
-            import jax
+        return float(env)
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", "cpu")).lower()
-    except Exception:
-        return _NOMINAL_CPU_PEAK
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
     for key, peak in _PEAK_BY_KIND:
-        if key in kind:
+        if key in kind.lower():
             return peak
-    return _NOMINAL_CPU_PEAK
+    raise LookupError(
+        f"no peak FLOP/s on record for device_kind {kind!r}: add it to "
+        "telemetry/costmodel._PEAK_BY_KIND or set BIGDL_TPU_PEAK_FLOPS")
 
 
 def mfu(flops_per_step: float, step_time_s: float, *, n_devices: int = 1,
-        peak: Optional[float] = None) -> float:
-    """Model-flops-utilization of one step across ``n_devices``."""
+        peak: Optional[float] = None) -> Optional[float]:
+    """Model-flops-utilization of one step across ``n_devices``; None
+    on a host-CPU backend, which has no peak to be utilized."""
     if not flops_per_step or not step_time_s or step_time_s <= 0:
         return 0.0
-    peak = peak_flops_per_device() if peak is None else peak
-    denom = step_time_s * peak * max(1, n_devices)
-    return flops_per_step / denom if denom > 0 else 0.0
+    if peak is None:
+        import jax
+
+        if jax.default_backend() == "cpu":
+            return None
+        peak = peak_flops_per_device()
+    return flops_per_step / (step_time_s * peak * max(1, n_devices))
 
 
 @dataclasses.dataclass
@@ -92,7 +95,8 @@ class ProgramCost:
     n_devices: int = 1
     stamped_unix: float = 0.0
 
-    def mfu(self, step_time_s: float, peak: Optional[float] = None) -> float:
+    def mfu(self, step_time_s: float,
+            peak: Optional[float] = None) -> Optional[float]:
         return mfu(self.flops, step_time_s, n_devices=self.n_devices,
                    peak=peak)
 
@@ -115,20 +119,15 @@ def program_cost(name: str, *, lowered=None, compiled=None,
     """Extract a :class:`ProgramCost` from a Lowered and/or Compiled.
 
     Prefers the lowered-stage analysis (no backend compile); memory
-    numbers only exist on the compiled stage.  Backends that return
-    nothing (0.4.x CPU variants) yield an all-zero stamp, never raise.
+    numbers only exist on the compiled stage.
     """
     ca = jax_compat.cost_analysis(lowered) if lowered is not None else {}
     if not ca and compiled is not None:
         ca = jax_compat.cost_analysis(compiled)
-    mem = jax_compat.memory_analysis(compiled) if compiled is not None \
-        else None
+    mem = compiled.memory_analysis() if compiled is not None else None
 
     def _m(attr):
-        try:
-            return int(getattr(mem, attr, 0) or 0)
-        except Exception:
-            return 0
+        return int(getattr(mem, attr, 0) or 0)
 
     return ProgramCost(
         name=name,

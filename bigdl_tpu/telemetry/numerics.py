@@ -350,6 +350,10 @@ def nan_provenance(model, params, model_state, features, targets,
     cast = (lambda t: jax.tree_util.tree_map(
         lambda x: x.astype(compute_dtype), t)) if compute_dtype \
         else (lambda t: t)
+    if compute_dtype:  # the step feeds float features in compute dtype
+        from bigdl_tpu.utils.flatten import cast_floating
+
+        features = cast_floating(features, compute_dtype)
 
     # forward walk (per-child apply) for ordered containers
     keys = getattr(model, "child_keys", None)

@@ -28,6 +28,14 @@ def tree_zeros_like(tree: Any) -> Any:
     return tree_map(jnp.zeros_like, tree)
 
 
+def cast_floating(tree: Any, dtype) -> Any:
+    """Cast the floating-point leaves of ``tree`` to ``dtype``; integer
+    leaves (token ids, labels) pass through."""
+    return tree_map(
+        lambda a: a.astype(dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+
+
 def ravel_pytree(tree: Any) -> Tuple[jnp.ndarray, Callable[[jnp.ndarray], Any]]:
     """Flatten ``tree`` to one 1-D array; return it and an unflattener.
 

@@ -1,151 +1,37 @@
-"""Version bridge for the shard_map / Pallas surface.
+"""Backend bridges: the few jax queries whose answer depends on which
+backend runs (XLA:CPU in tests, TPU on the chip), flattened to plain
+dicts so callers never branch on the backend themselves.
 
-The codebase is written against the current jax API (top-level
-``jax.shard_map`` with ``axis_names=``/``check_vma=``, the ambient
-abstract mesh, ``pltpu.CompilerParams``); the baked-in toolchain may
-ship an older jax (0.4.x) where the same features live under
-``jax.experimental.shard_map.shard_map(..., auto=, check_rep=)`` and
-``pltpu.TPUCompilerParams``.  Everything that touches those APIs goes
-through this module so the rest of the tree stays written in the new
-dialect.
-
-Beyond renaming, the old API has no ambient-mesh query — there is no
-way to ask "which mesh axes is the region I'm being traced in already
-manual over", which ops/pallas/partition.py needs to nest kernel
-shard_maps correctly.  The shim therefore tracks it directly: every
-``shard_map`` built here wraps the body so that, while the body traces,
-:func:`manual_axes` reports the axes taken manual and
-:func:`active_mesh` the mesh in scope.  This is version-independent
-(works identically under new jax) and is what
-``current_kernel_mesh`` builds on.
+Written against the installed toolchain (jax 0.9.0); shard_map, the
+ambient abstract mesh and ``pltpu.CompilerParams`` are used directly
+where they are needed.
 """
 from __future__ import annotations
 
-import contextvars
 from typing import Optional
 
 import jax
 
-__all__ = [
-    "shard_map",
-    "manual_axes",
-    "active_mesh",
-    "tpu_compiler_params",
-    "cost_analysis",
-    "memory_analysis",
-    "device_memory_stats",
-    "NEW_SHARD_MAP",
-]
-
-# new API: jax.shard_map (jax >= 0.6); old: jax.experimental.shard_map
-NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if NEW_SHARD_MAP:  # pragma: no cover - exercised on newer toolchains
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_MANUAL: contextvars.ContextVar = contextvars.ContextVar(
-    "bigdl_tpu_manual_axes", default=frozenset())
-_MESH: contextvars.ContextVar = contextvars.ContextVar(
-    "bigdl_tpu_active_mesh", default=None)
-
-
-def manual_axes() -> frozenset:
-    """Mesh axes already taken manual by an enclosing shard_map being
-    traced right now (trace-time signal; empty outside any region)."""
-    return _MANUAL.get()
-
-
-def active_mesh():
-    """The mesh of the innermost shard_map being traced, or None."""
-    return _MESH.get()
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              axis_names: Optional[frozenset] = None,
-              check_vma: bool = False):
-    """``jax.shard_map`` in the new-API dialect on any jax version.
-
-    ``axis_names``: axes to take manual (None = every mesh axis — the
-    classic fully-manual shard_map); the rest stay auto for GSPMD.
-    ``check_vma`` maps onto the old API's ``check_rep``.
-    """
-    names = (frozenset(axis_names) if axis_names is not None
-             else frozenset(mesh.axis_names))
-
-    def body(*args, **kwargs):
-        tok_a = _MANUAL.set(_MANUAL.get() | names)
-        tok_m = _MESH.set(mesh)
-        try:
-            return f(*args, **kwargs)
-        finally:
-            _MESH.reset(tok_m)
-            _MANUAL.reset(tok_a)
-
-    if NEW_SHARD_MAP:  # pragma: no cover - exercised on newer toolchains
-        return _shard_map_impl(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=names, check_vma=check_vma)
-    auto = frozenset(mesh.axis_names) - names
-    return _shard_map_impl(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma), auto=auto)
+__all__ = ["cost_analysis", "device_memory_stats"]
 
 
 def cost_analysis(stage) -> dict:
-    """XLA cost analysis from a ``Lowered`` or ``Compiled`` stage as a
+    """XLA cost analysis of a ``Lowered`` or ``Compiled`` stage as a
     flat ``{metric: float}`` dict (keys like ``flops``,
-    ``bytes accessed``).
-
-    The return shape drifts across versions and backends: newer stages
-    hand back a dict, ``Compiled`` on 0.4.x a list of per-executable
-    dicts, and some 0.4.x CPU/TPU backends return None or raise.  All
-    of those degrade to ``{}`` — cost accounting is advisory and must
-    never take down a warmup path.
-    """
-    fn = getattr(stage, "cost_analysis", None)
-    if fn is None:
-        return {}
-    try:
-        ca = fn()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return {}
-    out = {}
-    for k, v in ca.items():
-        if isinstance(v, (int, float)):
-            out[str(k)] = float(v)
-    return out
-
-
-def memory_analysis(compiled):
-    """``Compiled.memory_analysis()`` (an object with
-    ``*_size_in_bytes`` attributes) or None when the backend offers
-    nothing (0.4.x variants return None or raise)."""
-    fn = getattr(compiled, "memory_analysis", None)
-    if fn is None:
-        return None
-    try:
-        return fn()
-    except Exception:
-        return None
+    ``bytes accessed``); empty when the backend offers none."""
+    ca = stage.cost_analysis() or {}
+    return {str(k): float(v) for k, v in ca.items()
+            if isinstance(v, (int, float))}
 
 
 def device_memory_stats(device=None) -> Optional[dict]:
     """``device.memory_stats()`` as a flat ``{key: number}`` dict
     (keys like ``bytes_in_use``, ``peak_bytes_in_use``,
     ``bytes_limit``), or None when the backend offers nothing —
-    XLA:CPU returns None or raises depending on the jaxlib, and the
-    HBM ledger (telemetry/programs.py) then falls back to
-    :func:`memory_analysis` estimates."""
+    XLA:CPU returns None, and the HBM ledger (telemetry/programs.py)
+    then falls back to ``memory_analysis`` estimates."""
     if device is None:
-        try:
-            device = jax.local_devices()[0]
-        except Exception:
-            return None
+        device = jax.local_devices()[0]
     fn = getattr(device, "memory_stats", None)
     if fn is None:
         return None
@@ -157,12 +43,3 @@ def device_memory_stats(device=None) -> Optional[dict]:
         return None
     return {str(k): v for k, v in stats.items()
             if isinstance(v, (int, float))}
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` under either spelling."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

@@ -275,3 +275,43 @@ def test_fused_block_remat_shrinks_temp_bytes(monkeypatch):
     on, off = temps(True), temps(False)
     assert on > 0 and off > 0, "CPU memory_analysis returned no temps"
     assert on <= off, (on, off)
+
+
+# ---------------------------------------------------------------------------
+# the live table is the RUNNING device kind's, never another chip's
+# ---------------------------------------------------------------------------
+def test_table_path_is_keyed_by_device_kind(monkeypatch):
+    monkeypatch.delenv("BIGDL_TPU_TUNED_TABLE", raising=False)
+    v5e = tuning.table_path("TPU v5 lite")
+    assert v5e == tuning.table_file("TPU v5 lite")
+    assert v5e.endswith("tuned/tpu-v5-lite.json")
+    assert tuning.table_path("TPU v6 lite") is None  # no table committed
+    # the tier runs on the CPU backend: defaults, not the v5e's tiles
+    assert tuning.table_path() is None
+    monkeypatch.setenv("BIGDL_TPU_TUNED_TABLE", "/some/explicit.json")
+    assert tuning.table_path("TPU v5 lite") == "/some/explicit.json"
+
+
+def test_unreadable_table_is_an_error_at_first_dispatch(tmp_path,
+                                                        monkeypatch):
+    prev = tuning.get_tuned_table()
+    bad = tmp_path / "broken.json"
+    bad.write_text("{not json")
+    monkeypatch.setenv("BIGDL_TPU_TUNED_TABLE", str(bad))
+    monkeypatch.setattr(tuning, "_TABLE_LOADED", False)
+    try:
+        with pytest.raises(ValueError):
+            tuning.resolve("fused_matmul", (256, 128, 128), {"bm": 64})
+    finally:
+        tuning.set_tuned_table(prev)
+
+
+def test_report_keeps_the_shape_of_every_non_pallas_route(probe_table):
+    from bigdl_tpu.ops.pallas.fused_matmul import fused_matmul_bn
+
+    x = jnp.ones((64, 32), jnp.float32)
+    w = jnp.ones((32, 16), jnp.float32)
+    fused_matmul_bn(x, w)                      # off-TPU: the XLA route
+    fused_matmul_bn(x, w, interpret=True)      # the kernel, interpreted
+    assert report.fallbacks() == [("fused_matmul", "xla", (64, 32, 16))]
+    assert report.report()["fused_matmul"] == {"pallas": 1, "xla": 1}

@@ -330,6 +330,20 @@ def test_costmodel_stamps_real_program_and_mfu(tmp_path, monkeypatch):
     assert costmodel.mfu(1e12, 1.0, n_devices=2, peak=1e12) == \
         pytest.approx(0.5)
     assert costmodel.mfu(1.0, 0.0) == 0.0  # degenerate step time
+    # one peaks table keyed by device kind: a CPU has no peak, an
+    # unknown kind is an error, and a CPU run reports no MFU at all
+    assert costmodel.mfu(1e12, 1.0) is None
+    assert cost.mfu(1.0) is None
+    with pytest.raises(LookupError, match="device_kind 'cpu'"):
+        costmodel.peak_flops_per_device()
+
+    class Dev:
+        device_kind = "TPU v5 lite"
+
+    assert costmodel.peak_flops_per_device(Dev()) == 197e12
+    Dev.device_kind = "TPU v9 imaginary"
+    with pytest.raises(LookupError, match="v9 imaginary"):
+        costmodel.peak_flops_per_device(Dev())
     monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "2e12")
     assert costmodel.peak_flops_per_device() == 2e12
     assert cost.mfu(1.0, peak=cost.flops) == pytest.approx(1.0)

@@ -50,7 +50,7 @@ def test_fixture_findings_carry_source_and_equation():
     _, build = fx.get_fixture("debug_callback")
     (f,) = [f for f in analysis.lint_context(build())
             if f.rule == "host-transfer"]
-    assert f.primitive == "debug_callback"
+    assert f.primitive == "debug_print"
     assert "fixtures.py" in f.source
     assert f.equation  # jaxpr equation rendering present
 
@@ -84,7 +84,7 @@ def test_json_report_names_rule_model_and_equation_source():
     for f in t["findings"]:
         assert f["rule"] == "donation"
         assert f["target"] == "fixture:undonated_step"
-        assert f["equation"] and f["primitive"] == "pjit"
+        assert f["equation"] and f["primitive"] == "jit"
 
 
 # ---------------------------------------------------------------------------

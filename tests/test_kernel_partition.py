@@ -109,14 +109,12 @@ def test_flash_nested_inside_manual_region():
 
     from jax.sharding import PartitionSpec as P
 
-    from bigdl_tpu.utils.jax_compat import shard_map
-
     rs = np.random.RandomState(2)
     q = jnp.asarray(rs.randn(4, 4, 32, 8), jnp.float32)
     ref = flash_attention(q, q, q, causal=True, interpret=True)
     mesh = _mesh(data=2, model=2)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=P("data", None, None, None),
              out_specs=P("data", None, None, None),
              axis_names=frozenset({"data"}), check_vma=False)

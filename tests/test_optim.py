@@ -383,3 +383,33 @@ def test_lbfgs_wolfe_line_search_on_rosenbrock():
     m2 = LBFGS(max_iter=60, learning_rate=1.0)
     _, losses2 = m2.optimize(feval, x0)
     assert not losses2[-1] < 1e-5 or not np.isfinite(losses2[-1])
+
+
+def test_compute_dtype_casts_float_features_not_integer_ones():
+    """set_compute_dtype(bf16) must put the whole network in bf16: the
+    layers compute in their input's dtype, so an f32 image batch left
+    as it is would keep every activation in f32 (on the chip: 14.4 GiB
+    of temporaries for ResNet-50 at batch 256 instead of 8.5)."""
+    from bigdl_tpu.optim.optimizer import make_train_step
+
+    seen = {}
+
+    class Probe(nn.Linear):
+        def apply(self, params, state, x, training=False, rng=None):
+            seen["dtype"] = x.dtype
+            return super().apply(params, state, x, training=training,
+                                 rng=rng)
+
+    model = Probe(4, 3)
+    var = model.init(jax.random.PRNGKey(0))
+    methods = {"__all__": optim.SGD(0.1)}
+    step = make_train_step(model, nn.ClassNLLCriterion(logits=True),
+                           methods, compute_dtype=jnp.bfloat16)
+    opt = {"__all__": methods["__all__"].init_state(var["params"])}
+    x = jnp.ones((2, 4), jnp.float32)
+    t = jnp.zeros((2,), jnp.int32)
+    out = step(var["params"], var["state"], opt, jnp.int32(1),
+               jax.random.PRNGKey(1), x, t, [jnp.float32(0.1)])
+    assert seen["dtype"] == jnp.bfloat16
+    assert out[0]["weight"].dtype == jnp.float32  # master weights stay
+    assert np.isfinite(float(out[3]))

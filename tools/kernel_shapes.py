@@ -1,7 +1,8 @@
 """Shape inventory of every Pallas-kernel call site the fused
 ResNet-50 bench + quantized/LM paths hit — shared by the on-chip smoke
-(tools/kernel_smoke.py) and the offline deviceless AOT check
-(tools/tpu_aot_check.py) so the two can never drift apart."""
+(chip_smoke.py's kernel phase), the offline deviceless AOT check
+(tools/tpu_aot_check.py) and the in-process gate (tests/test_tpu_aot.py)
+so they can never drift apart."""
 
 BATCH = 256
 
@@ -28,8 +29,10 @@ MATMUL = [(BATCH * 56 * 56, 64, 64), (BATCH * 56 * 56, 64, 256),
 # 8-row tile; single-token decode stays on XLA like DECODE_ATTN.
 INT8 = [(4096, 768, 3072), (4096, 3072, 768), (8, 128, 4096)]
 
-# flash attention bench smoke shape: (B, H, T, D)
-FLASH = (1, 2, 1024, 128)
+# flash attention (B, H, T, D): the long-standing smoke shape, and the
+# LM train cell's own (tools/lm_bench.py LM_DEFAULTS: batch 8, 12 heads
+# of 64 over hidden 768, seq 2048)
+FLASH = [(1, 2, 1024, 128), (8, 12, 2048, 64)]
 
 # ---------------------------------------------------------------------
 # cached-decode serving shapes (serving/decode.py, docs/decoding.md):

@@ -2,9 +2,10 @@
 
 Compiles (and runs one call of) every fused-kernel shape the fused
 ResNet-50 hits at batch 256, plus flash attention, asserting the Pallas
-path actually lowered — the fast first step of a chip session
-(tools/chip_session.sh), so a Mosaic regression is localized to a shape
-in ~2 minutes instead of surfacing as a whole-bench failure.
+path actually lowered, so a Mosaic regression is localized to a shape
+instead of surfacing as a whole-bench failure.  (chip_smoke.py's kernel
+phase runs one shape per family AND compares numerics; this tool runs
+every inventory shape.)
 
 VERDICT r2 weak #6 context: interpret-mode tests once accepted a block
 shape Mosaic rejects; this round the 56x56x64 conv3 kernel exceeded the

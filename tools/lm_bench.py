@@ -11,14 +11,10 @@ tokens/sec + MFU from XLA's own cost analysis.
 """
 import argparse
 import json
-import os
 import sys
 import time
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(__file__.rsplit("/", 2)[0],
-                                   ".jax_cache"))
 
 
 # the bench's canonical configuration — single source for the argparse
@@ -67,11 +63,16 @@ def main():
 
     from bigdl_tpu.optim.optimizer import make_train_step
     from bigdl_tpu.ops.pallas import report as kernel_report
+    from bigdl_tpu.telemetry import costmodel
+    from bigdl_tpu.utils import jax_compat
+    from bigdl_tpu.utils.compile_cache import enable_compile_cache
 
     dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    if not on_tpu:
-        args.batchSize, args.seqLen, args.numLayers, args.steps = 2, 128, 2, 2
+    if dev.platform != "tpu":
+        sys.exit(f"lm_bench.py measures the chip: jax found platform "
+                 f"{dev.platform!r}, not a TPU")
+    enable_compile_cache()
+    peak = costmodel.peak_flops_per_device(dev)  # unknown kind raises
 
     model, crit, methods = build_lm(
         args.vocabSize, args.hiddenSize, args.numHeads, args.filterSize,
@@ -92,54 +93,43 @@ def main():
 
     compiled = step.lower(params, mstate, opt, jnp.asarray(0, jnp.int32),
                           jax.random.PRNGKey(0), x, t, lrs).compile()
-    flops = None
-    try:
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        flops = float(ca.get("flops", 0.0)) or None
-    except Exception:
-        pass
+    flops = jax_compat.cost_analysis(compiled).get("flops")
 
     for i in range(2):
         params, mstate, opt, loss = compiled(
             params, mstate, opt, jnp.asarray(i, jnp.int32),
             jax.random.PRNGKey(i), x, t, lrs)
-    float(loss)  # scalar sync (bench.py TIMING CAVEAT)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for i in range(args.steps):
         params, mstate, opt, loss = compiled(
             params, mstate, opt, jnp.asarray(i, jnp.int32),
             jax.random.PRNGKey(i), x, t, lrs)
-    float(loss)
+    jax.block_until_ready(loss)
     dt = (time.perf_counter() - t0) / args.steps
 
     tokens = args.batchSize * args.seqLen
-    if flops is None:
+    if not flops:
         # 6 * params * tokens (dense-LM rule of thumb), attention extra
         n_par = sum(int(p.size) for p in
                     jax.tree_util.tree_leaves(params))
         flops = 6.0 * n_par * tokens
-    from bench import _table_peak
-
-    peak = _table_peak(dev)
-    mfu = (flops / dt / peak) if on_tpu else 0.0
+    mfu = flops / dt / peak
     fa = kernel_report.report().get("flash_attention", {})
     rec = {
         "metric": "transformer_lm_train_throughput",
         "value": round(tokens / dt, 1),
         "unit": "tokens/sec/chip",
-        # off-TPU: MFU-vs-peak is meaningless (bench.py convention)
-        "vs_baseline": round(mfu / 0.50, 4) if on_tpu else 0.0,
+        "vs_baseline": round(mfu / 0.50, 4),
         "detail": {
             "batch": args.batchSize, "seq_len": args.seqLen,
             "layers": args.numLayers, "hidden": args.hiddenSize,
             "step_time_ms": round(1000 * dt, 2),
-            "mfu": round(mfu, 4) if on_tpu else 0.0,
-            "device": str(getattr(dev, "device_kind", dev.platform)),
-            # null off-chip: the lowering question is unanswerable there
-            "flash_attention_pallas": fa.get("pallas", 0) if on_tpu
-            else None,
-            "fallback": None if on_tpu else dev.platform,
+            "mfu": round(mfu, 4),
+            "device": {"platform": dev.platform,
+                       "kind": dev.device_kind,
+                       "count": len(jax.devices())},
+            "flash_attention_pallas": fa.get("pallas", 0),
         },
     }
     print(json.dumps(rec), flush=True)

@@ -61,7 +61,7 @@ def capture(logdir: str, batch: int, steps: int):
         params, mstate, opt, loss = step(
             params, mstate, opt, jnp.asarray(i, jnp.int32),
             jax.random.PRNGKey(i), x, t, lrs)
-    float(loss)  # scalar sync (bench.py TIMING CAVEAT)
+    jax.block_until_ready(loss)
     jax.profiler.stop_trace()
     return fused
 

@@ -11,8 +11,8 @@ and the PTB-LM recipe to REPRODUCIBLE curves:
 ``--record`` runs each leg with fixed seeds and stores the per-iteration
 loss series (ResNet) / final perplexity (PTB) under tools/fixtures/.
 ``--check`` re-runs identically and compares windowed-mean loss
-trajectories — the chip-session step replays this with the fused Pallas
-kernels on TPU, so a fused-path numerics regression shows up as curve
+trajectories — run on the chip, this replays with the fused Pallas
+kernels, so a fused-path numerics regression shows up as curve
 divergence rather than surviving unseen (the published 0.76114 top-1
 recipe is too big for CI; trajectory-equivalence on the scaled recipe
 is the provable invariant).
