@@ -25,7 +25,11 @@ import threading
 import time
 from typing import Any, Callable, Iterator, Optional
 
-from bigdl_tpu.telemetry.tracer import CAT_DATA, get_tracer
+from bigdl_tpu.telemetry.tracer import (
+    CAT_DATA,
+    get_tracer,
+    set_correlation,
+)
 
 DEFAULT_DEPTH = 2
 
@@ -65,23 +69,27 @@ class Prefetcher:
 
         def run():
             tracer = get_tracer()
+            source = iter(it)
             idx = 0
             try:
-                t0 = time.perf_counter()
-                for item in it:
-                    if self._stop.is_set():
-                        return
-                    if transform is not None:
-                        item = transform(item)
-                    if timer is not None:
-                        timer(time.perf_counter() - t0)
+                while not self._stop.is_set():
+                    if tracer.poll():
+                        set_correlation(f"item:{idx}")
                     # producer-thread span per item (pull + transform +
                     # device placement), correlated by item index so the
                     # shared timeline shows which batch the loop's
                     # data_stall waited on (docs/observability.md)
-                    tracer.add_span("prefetch_item", CAT_DATA, t0,
-                                    time.perf_counter(),
-                                    corr=f"item:{idx}")
+                    with tracer.span("prefetch_item", CAT_DATA):
+                        t0 = time.perf_counter()
+                        with tracer.span("host_transform", CAT_DATA):
+                            item = next(source, self._done)
+                        if item is self._done or self._stop.is_set():
+                            return
+                        if transform is not None:
+                            with tracer.span("h2d", CAT_DATA):
+                                item = transform(item)
+                        if timer is not None:
+                            timer(time.perf_counter() - t0)
                     idx += 1
                     # put AFTER the stop check so close() never strands
                     # a producer blocked on a full queue forever (close
@@ -90,7 +98,6 @@ class Prefetcher:
                     if self._stop.is_set():
                         return
                     self._q.put(item)
-                    t0 = time.perf_counter()
             except BaseException as e:  # surface in the consumer thread
                 self._error = e
             finally:

@@ -8,6 +8,7 @@ chosen so tensor parallelism can shard heads (see bigdl_tpu.parallel).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Optional
 
@@ -344,12 +345,14 @@ class TransformerLayer(Container):
             )
 
     def apply(self, params, state, x, training=False, rng=None):
-        h, s0 = self._child_apply(0, params, state, x, training=training, rng=rng)
-        a, s1 = self._child_apply(1, params, state, h, training=training, rng=rng)
-        x = x + a
-        h, s2 = self._child_apply(2, params, state, x, training=training, rng=rng)
-        f, s3 = self._child_apply(3, params, state, h, training=training, rng=rng)
-        x = x + f
+        with jax.named_scope("attention"):
+            h, s0 = self._child_apply(0, params, state, x, training=training, rng=rng)
+            a, s1 = self._child_apply(1, params, state, h, training=training, rng=rng)
+            x = x + a
+        with jax.named_scope("ffn"):
+            h, s2 = self._child_apply(2, params, state, x, training=training, rng=rng)
+            f, s3 = self._child_apply(3, params, state, h, training=training, rng=rng)
+            x = x + f
         return x, self._merge_state(
             state,
             {self._keys[0]: s0, self._keys[1]: s1, self._keys[2]: s2, self._keys[3]: s3},
@@ -364,24 +367,28 @@ class TransformerLayer(Container):
         through the KV cache.  LN and the FFN are per-position, so the
         same code serves prefill chunks and single-token decode steps."""
         lnk, mhak, ln2k, ffnk = self._keys
-        h, _ = self._children[0].apply(params[lnk], state[lnk], x)
-        a, cache = self.mha.apply_cached(params[mhak], h, cache)
-        x = x + a
-        h, _ = self._children[2].apply(params[ln2k], state[ln2k], x)
-        f, _ = self._children[3].apply(params[ffnk], state[ffnk], h)
-        return x + f, cache
+        with jax.named_scope("attention"):
+            h, _ = self._children[0].apply(params[lnk], state[lnk], x)
+            a, cache = self.mha.apply_cached(params[mhak], h, cache)
+            x = x + a
+        with jax.named_scope("ffn"):
+            h, _ = self._children[2].apply(params[ln2k], state[ln2k], x)
+            f, _ = self._children[3].apply(params[ffnk], state[ffnk], h)
+            return x + f, cache
 
     def apply_paged(self, params, state, x, cache, table, active):
         """``apply_cached`` with the attention core routed through the
         paged pool (LN/FFN are per-position either way)."""
         lnk, mhak, ln2k, ffnk = self._keys
-        h, _ = self._children[0].apply(params[lnk], state[lnk], x)
-        a, cache = self.mha.apply_paged(params[mhak], h, cache, table,
-                                        active)
-        x = x + a
-        h, _ = self._children[2].apply(params[ln2k], state[ln2k], x)
-        f, _ = self._children[3].apply(params[ffnk], state[ffnk], h)
-        return x + f, cache
+        with jax.named_scope("attention"):
+            h, _ = self._children[0].apply(params[lnk], state[lnk], x)
+            a, cache = self.mha.apply_paged(params[mhak], h, cache,
+                                            table, active)
+            x = x + a
+        with jax.named_scope("ffn"):
+            h, _ = self._children[2].apply(params[ln2k], state[ln2k], x)
+            f, _ = self._children[3].apply(params[ffnk], state[ffnk], h)
+            return x + f, cache
 
 
 class PositionEncode(Module):
@@ -407,6 +414,13 @@ class PositionEncode(Module):
         angle = pos / jnp.power(10000.0, 2.0 * i / d)
         return jnp.concatenate(
             [jnp.sin(angle), jnp.cos(angle)], axis=-1).astype(dtype)
+
+
+# device scopes of the Transformer's own children: embed (+ positions)
+# and head (final LN; the tied matmul joins it); the layers scope their
+# attention / ffn themselves
+_TOP_SCOPES = {"embed": "embed", "pos": "embed", "drop": "embed",
+               "ln_f": "head"}
 
 
 class Transformer(Container):
@@ -464,14 +478,15 @@ class Transformer(Container):
         h = x
         updates = {}
         for i, k in enumerate(self._keys):
-            if k == "embed":
+            scope = _TOP_SCOPES.get(k)
+            with jax.named_scope(scope) if scope \
+                    else contextlib.nullcontext():
                 h, s = self._child_apply(i, params, state, h, training=training, rng=rng)
-                h = h * math.sqrt(self.hidden_size)
-            else:
-                h, s = self._child_apply(i, params, state, h, training=training, rng=rng)
+                if k == "embed":
+                    h = h * math.sqrt(self.hidden_size)
             updates[k] = s
-        # weight-tied LM head
-        logits = h @ params["embed"]["weight"].astype(h.dtype).T
+        with jax.named_scope("head"):  # weight-tied LM head
+            logits = h @ params["embed"]["weight"].astype(h.dtype).T
         return logits, self._merge_state(state, updates)
 
     # ------------------------------------------------------------------
@@ -492,11 +507,20 @@ class Transformer(Container):
     def _embed_positions(self, params, ids, positions):
         """Embedding + sqrt(d) scaling + positional encoding at explicit
         absolute ``positions`` — the cached twin of the apply() head."""
-        emb = jnp.take(params["embed"]["weight"],
-                       ids.astype(jnp.int32), axis=0)
-        emb = emb * math.sqrt(self.hidden_size)
-        return emb + PositionEncode.encode_at(
-            positions, self.hidden_size, emb.dtype)
+        with jax.named_scope("embed"):
+            emb = jnp.take(params["embed"]["weight"],
+                           ids.astype(jnp.int32), axis=0)
+            emb = emb * math.sqrt(self.hidden_size)
+            return emb + PositionEncode.encode_at(
+                positions, self.hidden_size, emb.dtype)
+
+    def _head(self, params, state, h):
+        """Final LayerNorm + the weight-tied vocabulary matmul of the
+        cached paths."""
+        with jax.named_scope("head"):
+            h, _ = self._children[self._keys.index("ln_f")].apply(
+                params["ln_f"], state["ln_f"], h)
+            return h @ params["embed"]["weight"].astype(h.dtype).T
 
     def prefill(self, params, state, ids, cache, lengths=None):
         """Run the causal forward over (padded) prompts ``ids`` (N, T),
@@ -520,9 +544,7 @@ class Transformer(Container):
             h, new = layer.apply_cached(params[lk], state[lk], h,
                                         cache[lk])
             cache[lk] = dict(new, length=lengths)
-        h, _ = self._children[self._keys.index("ln_f")].apply(
-            params["ln_f"], state["ln_f"], h)
-        logits = h @ params["embed"]["weight"].astype(h.dtype).T
+        logits = self._head(params, state, h)
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None].astype(jnp.int32),
             axis=1)[:, 0]
@@ -542,9 +564,7 @@ class Transformer(Container):
             layer = self._children[self._keys.index(lk)]
             h, cache[lk] = layer.apply_cached(params[lk], state[lk], h,
                                               cache[lk])
-        h, _ = self._children[self._keys.index("ln_f")].apply(
-            params["ln_f"], state["ln_f"], h)
-        logits = h @ params["embed"]["weight"].astype(h.dtype).T
+        logits = self._head(params, state, h)
         return logits[:, 0], cache
 
     def extend(self, params, state, cache, ids, advance=None):
@@ -572,9 +592,7 @@ class Transformer(Container):
             if advance is not None:
                 new = dict(new, length=pos0 + advance.astype(jnp.int32))
             cache[lk] = new
-        h, _ = self._children[self._keys.index("ln_f")].apply(
-            params["ln_f"], state["ln_f"], h)
-        logits = h @ params["embed"]["weight"].astype(h.dtype).T
+        logits = self._head(params, state, h)
         return logits, cache
 
     # ------------------------------------------------------------------
@@ -610,9 +628,7 @@ class Transformer(Container):
             if advance is not None:
                 new = dict(new, length=pos0 + advance.astype(jnp.int32))
             cache[lk] = new
-        h, _ = self._children[self._keys.index("ln_f")].apply(
-            params["ln_f"], state["ln_f"], h)
-        logits = h @ params["embed"]["weight"].astype(h.dtype).T
+        logits = self._head(params, state, h)
         return logits, cache
 
     def decode_step_paged(self, params, state, cache, table, ids_t,

@@ -123,6 +123,7 @@ def flat_positions(table, pos, active, page_size, max_len):
     return jnp.where(ok, idx, pos % page_size)            # trash page 0
 
 
+@jax.named_scope("paged_append")
 def paged_append(pool, table, active, k_new, v_new, page_size, max_len):
     """Scatter ``k_new``/``v_new`` (S, H, T, D) into the pool at each
     slot's current ``length``..``length + T - 1``; returns the updated
@@ -149,6 +150,7 @@ def paged_append(pool, table, active, k_new, v_new, page_size, max_len):
     return pool
 
 
+@jax.named_scope("paged_gather")
 def paged_gather(pool, table, page_size, dtype):
     """Gather each slot's full logical extent out of the pool:
     returns ``(k, v)`` each (S, H, M*Q, D) in ``dtype`` (dequantized
@@ -171,6 +173,7 @@ def paged_gather(pool, table, page_size, dtype):
     return out[0], out[1]
 
 
+@jax.named_scope("paged_gather")
 def paged_gather_q(pool, table, page_size):
     """Raw gather for the int8 Pallas score path: returns
     ``(k_q (S, H, L, D) int8, k_scale (S, H, L) f32, v (S, H, L, D)

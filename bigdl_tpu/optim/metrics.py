@@ -27,10 +27,12 @@ keep a bounded window of raw samples for :meth:`percentile`, and
 (completed/rejected/expired requests) alongside the timers.
 
 Telemetry (docs/observability.md): every ``Metrics`` is also a SPAN
-SINK — each :meth:`add` of a timed phase emits a span into the global
+SINK — each :meth:`time` block is a span of the global
 :mod:`bigdl_tpu.telemetry` tracer (category = this instance's
-``category``), so the existing phase timers across the training loop,
-prefetcher, and serving engines land on one shared timeline for free.
+``category``; ring and live profiler trace), and each :meth:`add`
+leaves the reconstructed span in the ring, so the phase timers across
+the training loop, prefetcher, and serving engines land on one shared
+timeline for free.
 Non-interval samples (latencies measured across threads, occupancy
 fractions) opt out via :meth:`no_span`.  The disabled-tracer cost is
 one attribute check per add.
@@ -40,7 +42,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Deque, Dict, Set
 
 from bigdl_tpu.telemetry.tracer import get_tracer
@@ -67,7 +69,7 @@ class Metrics:
         self._no_span.add(name)
         return self
 
-    def add(self, name: str, seconds: float):
+    def _sample(self, name: str, seconds: float):
         with self._lock:
             self._sums[name] = self._sums.get(name, 0.0) + seconds
             self._counts[name] = self._counts.get(name, 0) + 1
@@ -75,20 +77,29 @@ class Metrics:
             window = self._samples.get(name)
             if window is not None:
                 window.append(seconds)
+
+    def add(self, name: str, seconds: float):
+        """Record a sample timed by the caller.  The ring gets the
+        reconstructed span ``[now - seconds, now]``; only :meth:`time`
+        can put a span into a live profiler trace."""
+        self._sample(name, seconds)
         tr = self._tracer
         if tr.enabled and name not in self._no_span:
-            # the phase just ended: reconstruct [now - seconds, now] so
-            # timers become spans with no change at any call site
             t1 = time.perf_counter()
             tr.add_span(name, self.category, t1 - seconds, t1)
 
     @contextmanager
     def time(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+        """Time the block as phase ``name``; while the tracer is on the
+        block is also a :meth:`Tracer.span` (ring + profiler trace)."""
+        span = nullcontext() if name in self._no_span \
+            else self._tracer.span(name, self.category)
+        with span:
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self._sample(name, time.perf_counter() - t0)
 
     def get(self, name: str) -> float:
         if name in self._gauges:

@@ -36,6 +36,7 @@ and debugging.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import os
@@ -315,7 +316,8 @@ def make_train_step(
             out, new_state = model.apply(
                 p_c, model_state, features, training=True, rng=rng
             )
-            loss = criterion.forward(out, targets).astype(jnp.float32)
+            with jax.named_scope("loss"):
+                loss = criterion.forward(out, targets).astype(jnp.float32)
             # fold in module-surfaced auxiliary losses (MoE load balance)
             for aux in _aux_losses(new_state):
                 loss = loss + aux_loss_weight * aux.astype(jnp.float32)
@@ -358,15 +360,17 @@ def make_train_step(
             grads = tm(lambda p, g: (g * scale).astype(p.dtype),
                        params, gsum)
             loss = lsum * scale
-        grads = _clip_grads(grads, grad_clip_const, grad_clip_norm)
+        with jax.named_scope("clip"):
+            grads = _clip_grads(grads, grad_clip_const, grad_clip_norm)
         new_params = dict(params) if isinstance(params, dict) else params
         new_opt_states = {}
         for (name, method), lr in zip(method_items, lrs):
             sub_p = select(params, name)
             sub_g = select(grads, name)
-            upd, new_opt_states[name] = method.update(
-                sub_g, opt_states[name], sub_p, lr, step
-            )
+            with jax.named_scope("optimizer"):
+                upd, new_opt_states[name] = method.update(
+                    sub_g, opt_states[name], sub_p, lr, step
+                )
             if name == "__all__":
                 new_params = upd
             else:
@@ -482,8 +486,11 @@ class LocalOptimizer(Optimizer):
         ckpt_dir = self._prepare_ckpt_dir()
 
         try:
-            while not self._stop_requested \
-                    and not self.end_trigger(driver_state):
+            tracer = get_tracer()
+            while not self._stop_requested:
+                with tracer.span("trigger", CAT_TRAIN):
+                    if self.end_trigger(driver_state):
+                        break
                 try:
                     self._one_iteration(
                         step_fn, params, model_state, opt_states,
@@ -496,8 +503,9 @@ class LocalOptimizer(Optimizer):
                     if driver_state["epoch_finished"]:
                         for m in self.optim_methods.values():
                             m.state["epoch"] = driver_state["epoch"]
-                    self._maybe_validate(
-                        model, params, model_state, driver_state)
+                    with tracer.span("trigger", CAT_TRAIN):
+                        self._maybe_validate(
+                            model, params, model_state, driver_state)
                     self._maybe_checkpoint(
                         ckpt_dir, params, model_state, opt_states,
                         driver_state)
@@ -783,7 +791,7 @@ class LocalOptimizer(Optimizer):
         data_iter, metrics, batches_per_epoch, wall_start,
     ):
         tracer = get_tracer()
-        if tracer.enabled:
+        if tracer.poll():
             # ambient correlation: every phase span this thread records
             # during the iteration carries its step index
             set_correlation(f"step:{driver_state['neval'] + 1}")
@@ -831,7 +839,15 @@ class LocalOptimizer(Optimizer):
             # are not donated, so holding them costs no extra copies)
             self._recent_batches.append(
                 (driver_state["neval"] + 1, features, targets))
-        with metrics.time("dispatch" if self._async_engine else "compute"):
+        # which step paid for the XLA compile
+        compiling = tracer.span(
+            "compile", CAT_TRAIN, args={"program": self._step_program}
+        ) if xray_sig is not None else contextlib.nullcontext()
+        with jax.profiler.StepTraceAnnotation(
+                "train_step", step_num=driver_state["neval"] + 1), \
+                compiling, \
+                metrics.time("dispatch" if self._async_engine
+                             else "compute"):
             outs = step_fn(
                 params, model_state, opt_states, step_idx, it_rng,
                 features, targets, lrs,
@@ -936,33 +952,39 @@ class LocalOptimizer(Optimizer):
                 metrics.summary(),
             )
         if self.train_summary is not None:
-            if not self._async_engine:
-                # async-mode Loss scalars are written at drain time
-                self.train_summary.add_scalar(
-                    "Loss", driver_state["loss"], driver_state["neval"])
-            throughput = (
-                self._last_throughput if self._async_engine
-                else n_records / max(metrics.get("compute"), 1e-9))
+            with tracer.span("trigger", CAT_TRAIN):
+                self._write_train_summary(driver_state, metrics, params,
+                                          n_records)
+
+    def _write_train_summary(self, driver_state, metrics, params,
+                             n_records):
+        if not self._async_engine:
+            # async-mode Loss scalars are written at drain time
             self.train_summary.add_scalar(
-                "Throughput", throughput, driver_state["neval"],
-            )
-            lr0 = sorted(self.optim_methods.items())[0][1].current_rate()
+                "Loss", driver_state["loss"], driver_state["neval"])
+        throughput = (
+            self._last_throughput if self._async_engine
+            else n_records / max(metrics.get("compute"), 1e-9))
+        self.train_summary.add_scalar(
+            "Throughput", throughput, driver_state["neval"],
+        )
+        lr0 = sorted(self.optim_methods.items())[0][1].current_rate()
+        self.train_summary.add_scalar(
+            "LearningRate", lr0, driver_state["neval"]
+        )
+        mon = self._numerics_monitor
+        if mon is not None and mon.last is not None:
             self.train_summary.add_scalar(
-                "LearningRate", lr0, driver_state["neval"]
+                "GradNorm", mon.last["grad_norm"],
+                mon.last["iteration"])
+            self.train_summary.add_scalar(
+                "UpdateRatio", mon.last["update_ratio"],
+                mon.last["iteration"])
+        if hasattr(self.train_summary, "maybe_add_parameters"):
+            self.train_summary.maybe_add_parameters(
+                params, driver_state["neval"],
+                stats=mon.last_stats if mon is not None else None,
             )
-            mon = self._numerics_monitor
-            if mon is not None and mon.last is not None:
-                self.train_summary.add_scalar(
-                    "GradNorm", mon.last["grad_norm"],
-                    mon.last["iteration"])
-                self.train_summary.add_scalar(
-                    "UpdateRatio", mon.last["update_ratio"],
-                    mon.last["iteration"])
-            if hasattr(self.train_summary, "maybe_add_parameters"):
-                self.train_summary.maybe_add_parameters(
-                    params, driver_state["neval"],
-                    stats=mon.last_stats if mon is not None else None,
-                )
 
     def _eval_batches(self, model, params, model_state):
         """Validation forward pass; overridden by DistriOptimizer for the

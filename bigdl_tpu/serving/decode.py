@@ -44,7 +44,9 @@ import threading
 import time
 from typing import List, Optional, Sequence
 
+import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation
 
 from bigdl_tpu.serving.bucketing import BucketGrid
 from bigdl_tpu.serving.engine import (
@@ -95,6 +97,7 @@ def build_decode_tick(model, **jit_kw):
 def prefill_fn(model, max_len: int, dtype=None):
     """Raw prompt prefill: fresh cache rows for a padded prompt batch
     + the next-token logits at each row's true length."""
+    import jax
     import jax.numpy as jnp
 
     dtype = dtype or jnp.float32
@@ -103,7 +106,7 @@ def prefill_fn(model, max_len: int, dtype=None):
         cache = model.init_cache(ids.shape[0], max_len, dtype)
         return model.prefill(params, state, ids, cache, lengths=lengths)
 
-    return prefill
+    return jax.named_scope("prefill")(prefill)
 
 
 def build_prefill(model, max_len: int, dtype=None, **jit_kw):
@@ -125,7 +128,7 @@ def write_slot_fn():
 
         return jax.tree_util.tree_map(upd, grid_cache, batch_cache)
 
-    return write
+    return jax.named_scope("slot_write")(write)
 
 
 def build_write_slot(**jit_kw):
@@ -185,12 +188,13 @@ def _next_tokens(logits, tokens, active, keys, temp, top_k, top_p):
     import jax
     import jax.numpy as jnp
 
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    sampled = sample_logits(logits, keys, temp, top_k, top_p)
-    nxt = jnp.where(temp > 0.0, sampled, greedy)
-    nxt = jnp.where(active, nxt, tokens)
-    split = jax.vmap(lambda k: jax.random.split(k, 2)[0])(keys)
-    keys = jnp.where(active[:, None], split, keys)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sampled = sample_logits(logits, keys, temp, top_k, top_p)
+        nxt = jnp.where(temp > 0.0, sampled, greedy)
+        nxt = jnp.where(active, nxt, tokens)
+        split = jax.vmap(lambda k: jax.random.split(k, 2)[0])(keys)
+        keys = jnp.where(active[:, None], split, keys)
     return nxt, keys
 
 
@@ -299,7 +303,7 @@ def paged_write_slot_fn():
             out[lk] = new
         return out
 
-    return write
+    return jax.named_scope("slot_write")(write)
 
 
 def build_paged_write_slot(**jit_kw):
@@ -651,11 +655,15 @@ def _host_sample(logits, req: "_DecodeRequest") -> int:
 
 
 class _Slot:
-    __slots__ = ("req", "generated")
+    __slots__ = ("req", "generated", "times")
 
-    def __init__(self, req: _DecodeRequest, first_token: int):
+    def __init__(self, req: _DecodeRequest, first_token: int,
+                 t_first: float):
         self.req = req
         self.generated = [first_token]
+        # perf_counter time of each generated token: the prefill token
+        # at host_sample, later ones at the end of their tick's wait
+        self.times = [t_first]
 
 
 _CLOSE = object()  # queue sentinel
@@ -867,7 +875,10 @@ class DecodeEngine:
             except Exception:
                 sig = None
         t0 = time.perf_counter()
-        out = thunk()
+        # which tick (ambient correlation) paid for which program
+        with self._tracer.span("compile", CAT_DECODE,
+                               args={"program": program or str(key[0])}):
+            out = thunk()
         dt = time.perf_counter() - t0
         if program is not None:
             programs.get_program_registry().register_compile(
@@ -981,31 +992,30 @@ class DecodeEngine:
                                           *self._tick_args())
         if cost is not None:
             self._tick_cost = cost
-            self.metrics.record_program_cost(cost)
 
     def _run_tick(self):
         def thunk():
-            import jax
-
-            out = self._tick(*self._tick_args())
-            cache, nxt, keys = out
+            cache, nxt, keys = self._tick(*self._tick_args())
             self._cache = cache
-            # the per-tick host sync point (writable copy: slots claimed
-            # between ticks overwrite their token in place)
-            nxt, keys = jax.device_get((nxt, keys))
+            return nxt, keys
+
+        with self._tracer.span("loop/tick_dispatch", CAT_DECODE):
+            out = self._tracked(
+                ("tick",), thunk, program="decode_tick",
+                sig_fn=lambda: programs.signature_of(
+                    {"params": self.params, "state": self.state,
+                     "cache": self._cache, "tokens": self._tokens,
+                     "active": self._active, "keys": self._keys,
+                     "temp": self._temps, "top_k": self._topks,
+                     "top_p": self._topps},
+                    donated=("cache",)),
+                cost=self._tick_cost)
+        # the per-tick host sync point (writable copy: slots claimed
+        # between ticks overwrite their token in place)
+        with self._tracer.span("loop/tick_wait", CAT_DECODE):
+            nxt, keys = jax.device_get(out)
             self._keys = np.array(keys)
             return np.array(nxt)
-
-        return self._tracked(
-            ("tick",), thunk, program="decode_tick",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self.params, "state": self.state,
-                 "cache": self._cache, "tokens": self._tokens,
-                 "active": self._active, "keys": self._keys,
-                 "temp": self._temps, "top_k": self._topks,
-                 "top_p": self._topps},
-                donated=("cache",)),
-            cost=self._tick_cost)
 
     def _run_prefill(self, ids: np.ndarray, lengths: np.ndarray):
         return self._tracked(
@@ -1116,17 +1126,16 @@ class DecodeEngine:
                 donated=("cache",)))
 
     def _run_verify(self, props):
+        """Dispatch the verify pass; returns the device ``(emitted,
+        n_emit)`` — fetching them is the round's single host sync."""
         def thunk():
-            import jax
-
             args = (self.params, self.state, self._cache)
             if self.paged:
                 args = args + (self._table(),)
             args = args + (self._tokens, props, self._active)
             cache, emitted, n_emit = self._verify(*args)
             self._cache = cache
-            # the single per-round host sync (emitted prefix + counts)
-            return jax.device_get((emitted, n_emit))
+            return emitted, n_emit
 
         return self._tracked(
             ("verify",), thunk, program="spec_verify",
@@ -1339,15 +1348,24 @@ class DecodeEngine:
     # engine loop: admit (prefill into free slots) then tick the grid
     # ------------------------------------------------------------------
     def _loop(self):
+        """One turn: drain, admit, chunk, budget, tick, retire — each a
+        top-level ``loop/*`` span, so together they tile the thread's
+        time while the tracer is on (docs/observability.md)."""
+        tr = self._tracer
         stopping = False
         while True:
-            stopping = self._drain_queue(
-                block=(not np.any(self._active) and not self._pending
-                       and self._chunking is None
-                       and not self._chunk_pending
-                       and all(st is None
-                               for st in self._slot_state)),
-                stopping=stopping)
+            if tr.poll():
+                # ambient correlation: every span of this turn carries
+                # the index of the tick the turn runs
+                set_correlation(f"tick:{self._tick_no + 1}")
+            with tr.span("loop/drain_queue", CAT_DECODE):
+                stopping = self._drain_queue(
+                    block=(not np.any(self._active) and not self._pending
+                           and self._chunking is None
+                           and not self._chunk_pending
+                           and all(st is None
+                                   for st in self._slot_state)),
+                    stopping=stopping)
             if stopping and self._discard:
                 self._fail_queued(EngineClosedError(
                     "decode engine closed"))
@@ -1359,12 +1377,16 @@ class DecodeEngine:
                             "decode engine closed"))
                         self._free(s)
                 return
-            self._admit()
-            self._chunk_step()
+            args = {}  # filled inside the span: the ring's copy
+            with tr.span("loop/admit", CAT_DECODE, args=args):
+                args["admitted"] = self._admit()
+            with tr.span("loop/chunk_step", CAT_DECODE):
+                self._chunk_step()
             if self.paged:
                 # fund (and resume) occupied slots before the tick —
                 # must run even when everything is paused
-                self._budget_pages()
+                with tr.span("loop/budget_pages", CAT_DECODE):
+                    self._budget_pages()
             if not np.any(self._active):
                 if stopping and not self._pending \
                         and self._chunking is None \
@@ -1372,28 +1394,26 @@ class DecodeEngine:
                         and all(st is None for st in self._slot_state):
                     return
                 continue
-            # ambient correlation: the decode_tick span (and any span
-            # recorded on this thread during the tick) carries the tick
-            # index on the shared timeline
             self._tick_no += 1
-            if self._tracer.enabled:
-                set_correlation(f"tick:{self._tick_no}")
             if self._spec:
                 self._spec_round()
                 continue
             t0 = time.perf_counter()
-            nxt = self._run_tick()
-            self.metrics.record_tick(time.perf_counter() - t0)
-            if self._tick_cost is not None:
-                self.metrics.record_compute(
-                    self._tick_cost.flops,
-                    self._tick_cost.bytes_accessed)
-            self._tokens = nxt
-            n_active = int(self._active.sum())
-            self.metrics.record_decode_tokens(n_active)
-            self.metrics.record_slot_occupancy(n_active / self.slots)
-            self._host_len[self._active] += 1
-            self._retire(nxt)
+            with StepTraceAnnotation("decode_tick",
+                                     step_num=self._tick_no):
+                nxt = self._run_tick()
+            now = time.perf_counter()
+            args = {}
+            with tr.span("loop/retire", CAT_DECODE, args=args):
+                self.metrics.record_tick(now - t0)
+                self._tokens = nxt
+                n_active = int(self._active.sum())
+                self.metrics.record_decode_tokens(n_active)
+                self.metrics.record_slot_occupancy(n_active / self.slots)
+                self._host_len[self._active] += 1
+                gaps = self._retire(nxt, now)
+                args["active"] = n_active
+                args["gaps_ms"] = [1e3 * g for g in gaps]
 
     def _drain_queue(self, block: bool, stopping: bool) -> bool:
         """Move queued requests into the admission deque; ``block``
@@ -1415,7 +1435,9 @@ class DecodeEngine:
         return [s for s in range(self.slots)
                 if not self._active[s] and s != reserved]
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Prefill waiting requests into free slots; returns how many
+        were bound to a slot this turn."""
         if self.prefill_chunk:
             # prompts longer than the largest declared bucket take the
             # chunked path instead of learning a one-off jumbo bucket
@@ -1430,11 +1452,11 @@ class DecodeEngine:
             self._pending = keep
         free = self._free_slots()
         if not self._pending or not free:
-            return
+            return 0
         if not self.continuous and len(free) < self.slots:
             # static run-to-completion baseline: wait for the whole
             # grid to drain before admitting the next wave
-            return
+            return 0
         now = time.perf_counter()
         taken: List[_DecodeRequest] = []
         while self._pending and len(taken) < len(free):
@@ -1468,7 +1490,8 @@ class DecodeEngine:
                 fits.append(req)
             taken = fits
         if not taken:
-            return
+            return 0
+        admitted = 0
         groups: dict = {}
         for r in taken:
             dims, _ = self.grid.choose_dims(r.prompt.shape)
@@ -1481,33 +1504,41 @@ class DecodeEngine:
                 self.xray.to_many((r.rid for r in chunk),
                                   request_xray.PHASE_PREFILL, now=t0)
                 try:
-                    self._prefill_chunk(chunk, dims, free_iter)
+                    admitted += self._prefill_chunk(chunk, dims,
+                                                    free_iter)
                 except Exception as e:  # per-request delivery
                     for r in chunk:
                         self.xray.drop(r.rid)
                         r.fut.set_exception(e)
                     continue
                 self.metrics.record_prefill(time.perf_counter() - t0)
+        return admitted
 
     def _prefill_chunk(self, chunk: List[_DecodeRequest], dims,
-                       free_iter):
+                       free_iter) -> int:
+        tr = self._tracer
         b = self.grid.choose_batch(len(chunk))
-        ids = self.grid.pad_batch([r.prompt for r in chunk], dims, b,
-                                  np.int32)
-        lengths = np.ones((b,), np.int32)
-        lengths[:len(chunk)] = [r.prompt.size for r in chunk]
-        logits, pcache = self._run_prefill(ids, lengths)
-        logits = np.asarray(logits)
+        with tr.span("prefill_dispatch", CAT_DECODE):
+            ids = self.grid.pad_batch([r.prompt for r in chunk], dims, b,
+                                      np.int32)
+            lengths = np.ones((b,), np.int32)
+            lengths[:len(chunk)] = [r.prompt.size for r in chunk]
+            logits, pcache = self._run_prefill(ids, lengths)
+        with tr.span("prefill_wait", CAT_DECODE):
+            logits = np.asarray(logits)
         dpcache = None
         if self._spec:
             _, dpcache = self._run_draft_prefill(ids, lengths)
+        admitted = 0
         for i, r in enumerate(chunk):
             self.xray.to(r.rid, request_xray.PHASE_SAMPLE)
-            tok0 = _host_sample(logits[i], r)
+            with tr.span("host_sample", CAT_DECODE, corr=f"req:{r.rid}"):
+                tok0 = _host_sample(logits[i], r)
+            t_tok = time.perf_counter()
             done = ((self.eos_id is not None and tok0 == self.eos_id)
                     or r.max_new <= 1)
             if done:
-                self._finish(r, [tok0],
+                self._finish(r, [tok0], [t_tok],
                              "eos" if (self.eos_id is not None
                                        and tok0 == self.eos_id)
                              else "length")
@@ -1522,17 +1553,23 @@ class DecodeEngine:
                 continue
             if self.paged:
                 self.metrics.record_pages(self._alloc.pages_in_use)
-            self._run_write(pcache, i, slot, batch=b)
-            if self._spec:
-                self._run_draft_write(dpcache, i, slot, batch=b)
-            self._activate(slot, r, tok0)
+            # dispatched without waiting: the write's device time is
+            # paid inside the next tick's token fetch
+            with tr.span("slot_write", CAT_DECODE, corr=f"req:{r.rid}"):
+                self._run_write(pcache, i, slot, batch=b)
+                if self._spec:
+                    self._run_draft_write(dpcache, i, slot, batch=b)
+            self._activate(slot, r, tok0, t_tok)
+            admitted += 1
+        return admitted
 
-    def _activate(self, slot: int, req: _DecodeRequest, tok0: int):
+    def _activate(self, slot: int, req: _DecodeRequest, tok0: int,
+                  t_tok: float):
         """Bind a prefilled request to its slot: token feed, sampling
         state, and the host length ledger."""
         self._tokens[slot] = tok0
         self._active[slot] = True
-        self._slot_state[slot] = _Slot(req, tok0)
+        self._slot_state[slot] = _Slot(req, tok0, t_tok)
         self._host_len[slot] = int(req.prompt.size)
         self._keys[slot] = req.key
         self._temps[slot] = req.temp
@@ -1618,10 +1655,11 @@ class DecodeEngine:
             return  # more chunks on later loop iterations
         self.xray.to(req.rid, request_xray.PHASE_SAMPLE)
         tok0 = _host_sample(last[0], req)
+        c["t_tok"] = time.perf_counter()
         if (self.eos_id is not None and tok0 == self.eos_id) \
                 or req.max_new <= 1:
             self._chunking = None
-            self._finish(req, [tok0],
+            self._finish(req, [tok0], [c["t_tok"]],
                          "eos" if (self.eos_id is not None
                                    and tok0 == self.eos_id)
                          else "length")
@@ -1644,7 +1682,7 @@ class DecodeEngine:
         self._run_write(c["staging"], 0, slot, batch=1)
         if self._spec:
             self._run_draft_write(c["dstaging"], 0, slot, batch=1)
-        self._activate(slot, req, c["tok0"])
+        self._activate(slot, req, c["tok0"], c["t_tok"])
 
     # ------------------------------------------------------------------
     # paged-pool budgeting
@@ -1728,6 +1766,7 @@ class DecodeEngine:
     # speculative rounds (replace the tick when a draft is configured)
     # ------------------------------------------------------------------
     def _spec_round(self):
+        tr = self._tracer
         t0 = time.perf_counter()
         spec_rids: Sequence[int] = ()
         if self.xray.enabled:
@@ -1737,51 +1776,61 @@ class DecodeEngine:
                          and self._slot_state[s] is not None]
             self.xray.to_many(spec_rids, request_xray.PHASE_SPEC,
                               now=t0)
-        props = self._run_propose()
-        emitted, n_emit = self._run_verify(props)
+        with StepTraceAnnotation("decode_tick", step_num=self._tick_no):
+            with tr.span("loop/tick_dispatch", CAT_DECODE):
+                out = self._run_verify(self._run_propose())
+            with tr.span("loop/tick_wait", CAT_DECODE):
+                emitted, n_emit = jax.device_get(out)
         t1 = time.perf_counter()
-        # the draft+verify round itself is the spec_verify budget; the
-        # gaps between rounds stay on the resident lane
-        self.xray.to_many(spec_rids, request_xray.PHASE_RESIDENT,
-                          now=t1)
-        self.metrics.record_tick(t1 - t0)
-        if self._tick_cost is not None:
-            self.metrics.record_compute(self._tick_cost.flops,
-                                        self._tick_cost.bytes_accessed)
-        emitted = np.asarray(emitted)
-        n_emit = np.asarray(n_emit)
-        n_active = int(self._active.sum())
-        self.metrics.record_slot_occupancy(n_active / self.slots)
-        now = time.perf_counter()
-        n_tok = 0
-        for s in range(self.slots):
-            if not self._active[s]:
-                continue
-            n = int(n_emit[s])  # accepted prefix + the bonus token >= 1
-            self.metrics.record_spec(self.draft_k, n - 1)
-            self.xray.note(self._slot_state[s].req.rid, "spec_rounds")
-            self._host_len[s] += n
-            self._tokens[s] = int(emitted[s, n - 1])
-            st = self._slot_state[s]
-            req = st.req
-            finished = None
-            for j in range(n):
-                tok = int(emitted[s, j])
-                st.generated.append(tok)
-                n_tok += 1
-                if self.eos_id is not None and tok == self.eos_id:
-                    finished = "eos"
-                    break
-                if len(st.generated) >= req.max_new:
-                    finished = "length"
-                    break
-            if finished is None and req.deadline is not None \
-                    and now > req.deadline:
-                finished = "deadline"
-            if finished is not None:
-                self._finish(req, st.generated, finished)
-                self._free(s)
-        self.metrics.record_decode_tokens(n_tok)
+        args = {}
+        with tr.span("loop/retire", CAT_DECODE, args=args):
+            self.metrics.record_tick(t1 - t0)
+            # the draft+verify round itself is the spec_verify budget;
+            # the gaps between rounds stay on the resident lane
+            self.xray.to_many(spec_rids, request_xray.PHASE_RESIDENT,
+                              now=t1)
+            emitted = np.asarray(emitted)
+            n_emit = np.asarray(n_emit)
+            n_active = int(self._active.sum())
+            self.metrics.record_slot_occupancy(n_active / self.slots)
+            n_tok = 0
+            gaps: List[float] = []
+            for s in range(self.slots):
+                if not self._active[s]:
+                    continue
+                n = int(n_emit[s])  # accepted prefix + the bonus token
+                self.metrics.record_spec(self.draft_k, n - 1)
+                self.xray.note(self._slot_state[s].req.rid,
+                               "spec_rounds")
+                self._host_len[s] += n
+                self._tokens[s] = int(emitted[s, n - 1])
+                st = self._slot_state[s]
+                req = st.req
+                finished = None
+                for j in range(n):
+                    tok = int(emitted[s, j])
+                    st.generated.append(tok)
+                    # a round's tokens all arrive with its fetch
+                    gaps.append(t1 - st.times[-1])
+                    st.times.append(t1)
+                    n_tok += 1
+                    if self.eos_id is not None and tok == self.eos_id:
+                        finished = "eos"
+                        break
+                    if len(st.generated) >= req.max_new:
+                        finished = "length"
+                        break
+                if finished is None and req.deadline is not None \
+                        and t1 > req.deadline:
+                    finished = "deadline"
+                if finished is not None:
+                    self._finish(req, st.generated, st.times, finished)
+                    self._free(s)
+            self.metrics.record_decode_tokens(n_tok)
+            for g in gaps:
+                self.metrics.record_token_gap(g)
+            args["active"] = n_active
+            args["gaps_ms"] = [1e3 * g for g in gaps]
 
     # ------------------------------------------------------------------
     # resident-bytes accounting for the HbmLedger lane
@@ -1804,37 +1853,47 @@ class DecodeEngine:
         return sum(leaf.size * leaf.dtype.itemsize
                    for leaf in jax.tree_util.tree_leaves(self._cache))
 
-    def _retire(self, nxt: np.ndarray):
-        now = time.perf_counter()
+    def _retire(self, nxt: np.ndarray, now: float) -> List[float]:
+        """Hand each active slot its token (fetched at ``now``) and
+        retire the finished; returns the slots' token gaps (seconds)."""
+        gaps = []
         for s in range(self.slots):
             if not self._active[s]:
                 continue
             st = self._slot_state[s]
             st.generated.append(int(nxt[s]))
+            gaps.append(now - st.times[-1])
+            st.times.append(now)
+            self.metrics.record_token_gap(gaps[-1])
             self.xray.note(st.req.rid, "ticks")
             req = st.req
             if self.eos_id is not None and int(nxt[s]) == self.eos_id:
-                self._finish(req, st.generated, "eos")
+                self._finish(req, st.generated, st.times, "eos")
             elif len(st.generated) >= req.max_new:
-                self._finish(req, st.generated, "length")
+                self._finish(req, st.generated, st.times, "length")
             elif req.deadline is not None and now > req.deadline:
                 # decoding already started: truncate, don't fail
-                self._finish(req, st.generated, "deadline")
+                self._finish(req, st.generated, st.times, "deadline")
             else:
                 continue
             self._free(s)
+        return gaps
 
     def _finish(self, req: _DecodeRequest, tokens: List[int],
-                reason: str):
-        self.xray.to(req.rid, request_xray.PHASE_DELIVER)
-        self.metrics.inc_finished(reason)
-        self.metrics.inc_completed()
-        self.metrics.record_latency(time.perf_counter() - req.t_submit)
-        self._tracer.instant("deliver", CAT_DECODE,
-                             corr=f"req:{req.rid}",
-                             args={"reason": reason,
-                                   "tokens": len(tokens)})
-        req.fut.set_result(np.asarray(tokens, np.int32))
+                times: List[float], reason: str):
+        with self._tracer.span("deliver", CAT_DECODE,
+                               corr=f"req:{req.rid}",
+                               args={"reason": reason,
+                                     "tokens": len(tokens)}):
+            self.xray.to(req.rid, request_xray.PHASE_DELIVER)
+            self.metrics.inc_finished(reason)
+            self.metrics.inc_completed()
+            self.metrics.record_latency(time.perf_counter()
+                                        - req.t_submit)
+            self.metrics.record_ttft(times[0] - req.t_submit)
+            # set before the result: done-callbacks may read it
+            req.fut.token_times = np.asarray(times, np.float64)
+            req.fut.set_result(np.asarray(tokens, np.int32))
         self.exemplars.offer(self.xray.close(req.rid))
 
     def _free(self, slot: int):

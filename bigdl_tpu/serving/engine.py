@@ -83,6 +83,8 @@ class ServingFuture:
         self._exc: Optional[BaseException] = None
         self._callbacks: List[Callable] = []
         self._lock = threading.Lock()
+        # DecodeEngine: perf_counter time of each returned token
+        self.token_times: Optional[Any] = None
 
     def done(self) -> bool:
         return self._ev.is_set()
@@ -194,7 +196,6 @@ class ServingEngine:
         # recompile counter is exact.
         self._jit = jax.jit(build_forward(model))
         self._seen_buckets: set = set()
-        self._bucket_costs: dict = {}  # bucket key -> ProgramCost
         self._compile_lock = threading.Lock()
 
         self._rq: "queue.Queue" = queue.Queue(maxsize=max(1, max_queue))
@@ -255,15 +256,11 @@ class ServingEngine:
             np.asarray(self._jit(self.params, self.state, x))
             dt = time.perf_counter() - t0
             # stamp this bucket's flops/bytes (re-trace only, no
-            # second compile): _run accounts them per dispatch and
-            # log_line()/snapshot() derive GF/s + MFU
+            # second compile) for the X-ray registry's program table
             cost = costmodel.stamp_jitted(
                 f"serving_forward:{batch}x"
                 + "x".join(map(str, dims)),
                 self._jit, self.params, self.state, x)
-            if cost is not None:
-                self._bucket_costs[key] = cost
-                self.metrics.record_program_cost(cost)
             # the X-ray registration emits its forensic instant before
             # record_recompile's span so the Watchdog can pair them
             programs.get_program_registry().register_compile(
@@ -282,9 +279,6 @@ class ServingEngine:
         counted."""
         key = (xp.shape[0], tuple(xp.shape[1:]))
         self._ensure_bucket(*key)
-        cost = self._bucket_costs.get(key)
-        if cost is not None:
-            self.metrics.record_compute(cost.flops, cost.bytes_accessed)
         programs.get_program_registry().record_call("serving_forward")
         return self._jit(self.params, self.state, xp)
 
@@ -443,6 +437,7 @@ class ServingEngine:
         self._fly.put(_CLOSE)
 
     def _dispatch(self, batch: List[_Request]):
+        self._tracer.poll()
         now = time.perf_counter()
         live: List[_Request] = []
         for r in batch:
@@ -467,21 +462,21 @@ class ServingEngine:
             for lo in range(0, len(rs), self.grid.max_batch):
                 chunk = rs[lo:lo + self.grid.max_batch]
                 b = self.grid.choose_batch(len(chunk))
-                t0 = time.perf_counter()
                 self.xray.to_many((r.rid for r in chunk),
-                                  request_xray.PHASE_PAD, now=t0)
+                                  request_xray.PHASE_PAD)
                 try:
-                    xp = self.grid.pad_batch([r.x for r in chunk], dims,
-                                             b, self._dtype)
-                    # enqueue-only: JAX async dispatch returns before the
-                    # device finishes; the drain thread owns the fetch
-                    y = self._run(xp)
+                    with self.metrics.time_dispatch():
+                        xp = self.grid.pad_batch([r.x for r in chunk],
+                                                 dims, b, self._dtype)
+                        # enqueue-only: JAX async dispatch returns before
+                        # the device finishes; the drain thread owns the
+                        # fetch
+                        y = self._run(xp)
                 except Exception as e:  # per-request delivery, keep serving
                     for r in chunk:
                         self.xray.drop(r.rid)
                         r.fut.set_exception(e)
                     continue
-                self.metrics.record_dispatch(time.perf_counter() - t0)
                 self.xray.to_many((r.rid for r in chunk),
                                   request_xray.PHASE_DEVICE)
                 self.metrics.record_batch(len(chunk), b)
@@ -506,15 +501,14 @@ class ServingEngine:
             if item is _CLOSE:
                 return
             y, dims, chunk = item
-            t0 = time.perf_counter()
             try:
-                ynp = np.asarray(y)  # blocks until the device finishes
+                with self.metrics.time_fetch():
+                    ynp = np.asarray(y)  # blocks until the device is done
             except Exception as e:
                 for r in chunk:
                     self.xray.drop(r.rid)
                     r.fut.set_exception(e)
                 continue
-            self.metrics.record_fetch(time.perf_counter() - t0)
             now = time.perf_counter()
             self.xray.to_many((r.rid for r in chunk),
                               request_xray.PHASE_DELIVER, now=now)

@@ -18,7 +18,6 @@ import time
 from typing import Callable, Optional
 
 from bigdl_tpu.optim.metrics import Metrics
-from bigdl_tpu.telemetry import costmodel
 
 logger = logging.getLogger("bigdl_tpu.serving")
 
@@ -31,6 +30,8 @@ FETCH = "serve_fetch"        # blocking device->host result fetch
 PREFILL = "decode_prefill"   # prompt forward + slot splice, per admit
 TICK = "decode_tick"         # one whole-grid decode step (== per token)
 SLOT_OCC = "slot_occupancy"  # active slots / grid size, per tick
+TTFT = "ttft"                # submit -> first token, per request
+TOKEN_GAP = "token_gap"      # a request's token -> its next token
 
 #: ``le`` bounds (seconds) of the request-latency Prometheus histogram
 #: exported on /metricsz — cumulative buckets a scraper can aggregate
@@ -48,12 +49,16 @@ class ServingMetrics:
         self.base.track(OCCUPANCY, window)
         self.base.track(TICK, window)
         self.base.track(SLOT_OCC, window)
+        self.base.track(TTFT, window)
+        self.base.track(TOKEN_GAP, window)
         # not intervals on the recording thread: latency spans a
         # request's whole life across threads, occupancy is a fraction —
         # they stay samples, not telemetry spans (docs/observability.md)
         self.base.no_span(LATENCY)
         self.base.no_span(OCCUPANCY)
         self.base.no_span(SLOT_OCC)
+        self.base.no_span(TTFT)
+        self.base.no_span(TOKEN_GAP)
         self._t0 = time.perf_counter()
         self._lock = threading.Lock()
         self._queue_depth = 0
@@ -63,12 +68,6 @@ class ServingMetrics:
         self._lat_buckets = [0] * (len(LATENCY_BUCKETS) + 1)
         self._lat_sum = 0.0
         self._lat_count = 0
-        # cost/MFU accounting (telemetry/costmodel): stamped program
-        # costs + flops/bytes actually dispatched since engine start
-        self._program_costs: dict = {}
-        self._flops_done = 0.0
-        self._bytes_done = 0.0
-        self._compute_devices = 1
 
     # -- recording (engine-internal) -----------------------------------
     def record_latency(self, seconds: float):
@@ -89,11 +88,11 @@ class ServingMetrics:
     def record_recompile(self, seconds: float):
         self.base.add(RECOMPILE, seconds)
 
-    def record_dispatch(self, seconds: float):
-        self.base.add(DISPATCH, seconds)
+    def time_dispatch(self):
+        return self.base.time(DISPATCH)
 
-    def record_fetch(self, seconds: float):
-        self.base.add(FETCH, seconds)
+    def time_fetch(self):
+        return self.base.time(FETCH)
 
     def inc_completed(self, n: int = 1):
         self.base.inc("completed", n)
@@ -116,6 +115,12 @@ class ServingMetrics:
 
     def record_slot_occupancy(self, frac: float):
         self.base.add(SLOT_OCC, frac)
+
+    def record_ttft(self, seconds: float):
+        self.base.add(TTFT, seconds)
+
+    def record_token_gap(self, seconds: float):
+        self.base.add(TOKEN_GAP, seconds)
 
     def inc_finished(self, reason: str, n: int = 1):
         """Count a sequence retirement by reason: eos|length|deadline."""
@@ -144,21 +149,6 @@ class ServingMetrics:
         acceptance rate is a property of the draft, not the verify)."""
         self.base.inc("spec_proposed", proposed)
         self.base.inc("spec_accepted", accepted)
-
-    # -- cost/MFU accounting (telemetry/costmodel) ---------------------
-    def record_program_cost(self, cost) -> None:
-        """Register a :class:`~bigdl_tpu.telemetry.costmodel.
-        ProgramCost` stamp for a program this engine dispatches."""
-        with self._lock:
-            self._program_costs[cost.name] = cost
-            self._compute_devices = max(self._compute_devices,
-                                        cost.n_devices)
-
-    def record_compute(self, flops: float, bytes_accessed: float):
-        """Account one dispatch of a stamped program."""
-        with self._lock:
-            self._flops_done += flops
-            self._bytes_done += bytes_accessed
 
     # -- reading -------------------------------------------------------
     @property
@@ -230,6 +220,15 @@ class ServingMetrics:
         """Mean active-slots / grid-size over the sample window."""
         return self.base.get(SLOT_OCC)
 
+    def ttft_ms(self, q: float) -> float:
+        """Time-to-first-token percentile (first token - submit)."""
+        return 1e3 * self.base.percentile(TTFT, q)
+
+    def token_gap_ms(self, q: float) -> float:
+        """Percentile of the gap between a request's consecutive
+        tokens: a tick plus whatever admission ran between them."""
+        return 1e3 * self.base.percentile(TOKEN_GAP, q)
+
     @property
     def pages_in_use(self) -> int:
         with self._lock:
@@ -249,37 +248,7 @@ class ServingMetrics:
         p = self.base.counter("spec_proposed")
         return self.base.counter("spec_accepted") / p if p else 0.0
 
-    def program_costs(self) -> dict:
-        with self._lock:
-            return dict(self._program_costs)
-
-    def gflops_per_sec(self) -> float:
-        """Dispatched model GFLOP/s since engine start (cost-model
-        flops, not hardware counters)."""
-        dt = time.perf_counter() - self._t0
-        with self._lock:
-            f = self._flops_done
-        return f / dt / 1e9 if dt > 0 else 0.0
-
-    def bytes_per_sec(self) -> float:
-        dt = time.perf_counter() - self._t0
-        with self._lock:
-            b = self._bytes_done
-        return b / dt if dt > 0 else 0.0
-
-    def mfu(self) -> Optional[float]:
-        """Model-flops-utilization over wall-clock since engine start
-        (idle time counts against it — a serving engine's honest
-        number); None on a CPU backend, which has no peak."""
-        dt = time.perf_counter() - self._t0
-        with self._lock:
-            f, n = self._flops_done, self._compute_devices
-        if dt <= 0 or not f:
-            return 0.0
-        return costmodel.mfu(f, dt, n_devices=n)
-
     def snapshot(self) -> dict:
-        mfu = self.mfu()
         return {
             "completed": self.completed,
             "rejected": self.rejected,
@@ -298,9 +267,10 @@ class ServingMetrics:
             "p95_tick_ms": round(self.tick_ms(95), 3),
             "prefill_ms": round(1e3 * self.base.get(PREFILL), 3),
             "decode_ms": round(1e3 * self.base.get(TICK), 3),
-            "mfu": None if mfu is None else round(mfu, 5),
-            "gflops_per_sec": round(self.gflops_per_sec(), 3),
-            "bytes_per_sec": round(self.bytes_per_sec(), 1),
+            "p50_ttft_ms": round(self.ttft_ms(50), 3),
+            "p95_ttft_ms": round(self.ttft_ms(95), 3),
+            "p50_token_gap_ms": round(self.token_gap_ms(50), 3),
+            "p95_token_gap_ms": round(self.token_gap_ms(95), 3),
             "pages_in_use": self.pages_in_use,
             "page_evictions": self.page_evictions,
             "spec_acceptance_rate": round(self.spec_acceptance_rate(),
@@ -325,8 +295,6 @@ class ServingMetrics:
         "expired": "Serving/Expired",
         "p50_tick_ms": "Serving/TickP50Ms",
         "p95_tick_ms": "Serving/TickP95Ms",
-        "mfu": "Serving/MFU",
-        "gflops_per_sec": "Serving/GFlopsPerSec",
         "pages_in_use": "Serving/PagesInUse",
         "page_evictions": "Serving/PageEvictions",
         "spec_acceptance_rate": "Serving/SpecAcceptanceRate",
@@ -358,7 +326,11 @@ class ServingMetrics:
             line += (f" | {s['tokens_per_sec']:.1f} tok/s | "
                      f"slots={100 * s['slot_occupancy']:.0f}% | "
                      f"tick p50={s['p50_tick_ms']:.2f}ms "
-                     f"p95={s['p95_tick_ms']:.2f}ms")
+                     f"p95={s['p95_tick_ms']:.2f}ms | "
+                     f"ttft p50={s['p50_ttft_ms']:.2f}ms "
+                     f"p95={s['p95_ttft_ms']:.2f}ms | "
+                     f"gap p50={s['p50_token_gap_ms']:.2f}ms "
+                     f"p95={s['p95_token_gap_ms']:.2f}ms")
         if s["pages_in_use"] or s["page_evictions"]:
             line += (f" | pages={s['pages_in_use']} "
                      f"evict={s['page_evictions']}")
@@ -366,10 +338,6 @@ class ServingMetrics:
             line += f" | chunks={s['prefill_chunks']}"
         if s["spec_acceptance_rate"]:
             line += f" | spec acc={100 * s['spec_acceptance_rate']:.0f}%"
-        if s["gflops_per_sec"]:
-            line += f" | {s['gflops_per_sec']:.1f} GF/s"
-        if s["mfu"]:
-            line += f" | mfu={100 * s['mfu']:.2f}%"
         return line
 
 

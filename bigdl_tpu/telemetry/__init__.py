@@ -71,7 +71,6 @@ from bigdl_tpu.telemetry.requests import (
     ExemplarReservoir,
     RequestLedger,
     assemble_request_trees,
-    request_trace_enabled,
 )
 from bigdl_tpu.telemetry.workload import (
     WorkloadRecorder,
@@ -125,7 +124,7 @@ __all__ = [
     "chrome_trace", "write_chrome_trace", "write_scalars",
     "metrics_record", "write_metrics_jsonl", "read_metrics_jsonl",
     "RequestLedger", "Attribution", "ExemplarReservoir",
-    "assemble_request_trees", "request_trace_enabled",
+    "assemble_request_trees",
     "WorkloadRecorder", "load_workload",
     "CAT_TRAIN", "CAT_DATA", "CAT_SERVE", "CAT_DECODE", "CAT_HOST",
 ]

@@ -549,7 +549,6 @@ class ClusterAggregator:
             if isinstance(snap, dict):
                 for key in ("queue_depth", "occupancy", "req_per_sec",
                             "tokens_per_sec", "p50_ms", "p99_ms",
-                            "mfu", "gflops_per_sec", "bytes_per_sec",
                             "throughput", "grad_norm", "update_ratio"):
                     if key in snap:
                         out[key] = snap[key]

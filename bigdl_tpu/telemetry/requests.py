@@ -27,9 +27,10 @@ server) explain everything about a *program*; this module explains one
   as Perfetto slices via ``/tracez`` and bundled into flight-recorder
   blackboxes.
 
-Env knobs: ``BIGDL_TPU_REQ_TRACE`` (``1``/``0`` force attribution
-on/off; unset = follow the tracer), ``BIGDL_TPU_EXEMPLARS`` (reservoir
-capacity; ``0`` disables; unset = 8, armed whenever attribution is).
+Attribution follows the tracer: on while a profiler session is live or
+after ``telemetry.enable()``.  Env knob: ``BIGDL_TPU_EXEMPLARS``
+(reservoir capacity; ``0`` disables; unset = 8, armed whenever
+attribution is).
 
 Like every telemetry layer, all of this is strictly host-side
 bookkeeping between dispatches: the graft-lint target
@@ -65,17 +66,6 @@ PHASES: Tuple[str, ...] = (
 _MAX_OPEN = 8192      # ledger safety bound on concurrently open requests
 _WINDOW = 512         # closed-attribution rolling window for summaries
 _P99_REFRESH = 16     # offers between reservoir p99 recomputations
-
-
-def request_trace_enabled(tracer: Optional[Tracer] = None) -> bool:
-    """``BIGDL_TPU_REQ_TRACE=1`` forces attribution on, ``=0`` off;
-    unset follows the global tracer (on whenever tracing is)."""
-    v = os.environ.get("BIGDL_TPU_REQ_TRACE", "")
-    if v == "0":
-        return False
-    if v not in ("", "0"):
-        return True
-    return (tracer or get_tracer()).enabled
 
 
 def exemplar_capacity() -> int:
@@ -152,15 +142,15 @@ class RequestLedger:
     Engines call :meth:`open` at submit, :meth:`to` on every lifecycle
     transition, and :meth:`close` at delivery/rejection.  Every call is
     one ``enabled`` check when the plane is off — the same discipline
-    as the tracer.  The same wall interval may be charged to several
+    as the tracer, which it follows: a request opened before the
+    tracer came on is unknown to the ledger, and requests still open
+    when it goes off are dropped.  The same wall interval may be charged to several
     concurrently resident requests (each lived through it); *within*
     one request the partition is exact.
     """
 
     def __init__(self, tracer: Optional[Tracer] = None):
         self._tracer = tracer if tracer is not None else get_tracer()
-        v = os.environ.get("BIGDL_TPU_REQ_TRACE", "")
-        self._force = None if v in ("",) else v != "0"
         self._lock = threading.Lock()
         self._open: Dict[int, _Open] = {}
         self._window: deque = deque(maxlen=_WINDOW)
@@ -169,9 +159,12 @@ class RequestLedger:
 
     @property
     def enabled(self) -> bool:
-        if self._force is not None:
-            return self._force
-        return self._tracer.enabled
+        if self._tracer.enabled:
+            return True
+        if self._open:  # the tracer went off under open requests
+            with self._lock:
+                self._open.clear()
+        return False
 
     # -- lifecycle ----------------------------------------------------
     def open(self, rid: int, now: Optional[float] = None):
@@ -293,13 +286,6 @@ class RequestLedger:
                  if v > 0]
         return (f"xray: n={s['n_closed']} dom={dom} "
                 + " ".join(parts))
-
-    def reset(self):
-        with self._lock:
-            self._open.clear()
-            self._window.clear()
-            self._dominant.clear()
-            self._n_closed = 0
 
 
 # --------------------------------------------------------------------------
@@ -524,12 +510,3 @@ class ExemplarReservoir:
                 "slowest_ms": (round(1e3 * self._kept[-1]["latency_s"],
                                      3) if self._kept else 0.0),
             }
-
-    def clear(self):
-        with self._lock:
-            self._kept.clear()
-            self._latencies.clear()
-            self._offered = 0
-            self._captured = 0
-            self._thresh = None
-            self._stale = 0

@@ -1,39 +1,32 @@
 """Structured span/event tracer (docs/observability.md).
 
-The three async subsystems — the training loop (loop thread + prefetch
-producer + checkpoint writer), the ServingEngine (dispatcher + drain
-threads), and the DecodeEngine (slot-grid loop) — each time their
-phases through :class:`~bigdl_tpu.optim.metrics.Metrics`, but the
-numbers land in per-engine islands with no shared timeline and no way
-to follow one request or one training step across threads.  This
-module is the shared timeline: a process-global, thread-safe ring
-buffer of spans that every ``Metrics`` phase timer feeds automatically
-(``Metrics`` is the span sink), plus explicit spans/instants at the
-places averages cannot explain (request lifecycle edges, checkpoint
-writes, divergence drains).
+The shared timeline of the three async subsystems (training loop +
+prefetch producer + checkpoint writer, the ServingEngine's dispatcher
+and drain threads, the DecodeEngine's slot-grid loop): a
+process-global, thread-safe ring buffer of spans that every
+:class:`~bigdl_tpu.optim.metrics.Metrics` phase timer feeds, plus
+explicit spans/instants where averages explain nothing (request
+lifecycle edges, checkpoint writes, divergence drains).
 
-Design constraints (ISSUE 5):
-
-* **Near-zero overhead when disabled** — every recording call is one
+* **One timeline** — the tracer records while a ``jax.profiler``
+  session is live (:meth:`Tracer.poll`, called once per loop turn by
+  every engine loop) or after an explicit :func:`enable`, and every
+  :meth:`Tracer.span` also enters a ``jax.profiler.TraceAnnotation``,
+  so the program's spans land on plane ``/host:CPU`` of the profiler's
+  own trace, on the device planes' clock.
+* **Near-zero overhead when off** — every recording call is one
   attribute check (``tracer.enabled``) before returning; nothing is
-  allocated, no lock is taken.  ``bench.py --telemetry-ab`` gates the
-  *enabled* overhead at < 3% of step time.
-* **Zero effect on compiled programs** — instrumentation lives strictly
-  host-side, between dispatches, never inside a traced function.  The
-  graft-lint target ``telemetry_step_parity`` asserts the async-loop
-  step's jaxpr is byte-identical with tracing on and off, and the
-  ``span_host_leak`` fixture seeds the violation (a span callback
-  smuggled into the step).
-* **Correlation IDs** — spans carry a free-form correlation string
-  (``step:42``, ``req:17``, ``tick:1024``, ``item:7``) so one logical
-  unit of work can be joined across the threads that touched it.  The
-  ambient per-thread correlation (:func:`set_correlation`) covers the
-  common case where a whole phase belongs to the current step/tick;
-  lifecycle edges that outlive a thread (a serving request's
-  enqueue -> deliver) pass ``corr`` explicitly.
+  allocated, no lock is taken.
+* **Zero effect on compiled programs** — instrumentation is host-side,
+  between dispatches, never inside a traced function (graft-lint
+  target ``telemetry_step_parity``; fixture ``span_host_leak``).
+* **Correlation IDs** — a free-form string (``step:42``, ``req:17``,
+  ``tick:1024``, ``item:7``) joins one unit of work across the threads
+  that touched it: ambient per thread (:func:`set_correlation`), or
+  passed explicitly where an edge outlives a thread.
 
-Env knobs: ``BIGDL_TPU_TRACE=1`` enables the global tracer at import,
-``BIGDL_TPU_TRACE_BUFFER`` sizes the ring (default 65536 spans).
+Env knobs: ``BIGDL_TPU_TRACE=1`` holds the global tracer on from
+import, ``BIGDL_TPU_TRACE_BUFFER`` sizes the ring (default 65536).
 """
 from __future__ import annotations
 
@@ -42,6 +35,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_CAPACITY = 65536
 
@@ -87,6 +82,9 @@ class Span:
 
 _tls = threading.local()
 
+# is a jax.profiler session live?  (module-level so tests can count calls)
+_profiler_live = TraceAnnotation.is_enabled
+
 
 def set_correlation(corr: Optional[str]):
     """Set this thread's ambient correlation ID (e.g. ``step:42``);
@@ -123,6 +121,8 @@ class Tracer:
                  enabled: bool = False):
         self.capacity = max(1, int(capacity))
         self.enabled = bool(enabled)
+        self._forced = bool(enabled)  # explicit enable(): outlives sessions
+        self._session = False         # profiler state at the last poll()
         self._buf: List[Optional[Span]] = []
         self._head = 0  # next write index once the ring is full
         self._dropped = 0
@@ -138,12 +138,24 @@ class Tracer:
                 self.capacity = max(1, int(capacity))
                 self._buf = ordered[-self.capacity:]
                 self._head = 0
-        self.enabled = True
+        self.enabled = self._forced = True
         return self
 
     def disable(self) -> "Tracer":
-        self.enabled = False
+        self.enabled = self._forced = False
         return self
+
+    def poll(self) -> bool:
+        """Follow the profiler: called once per loop turn (training
+        iteration, decode loop turn, prefetch item, serving dispatch),
+        flips ``enabled`` on at ``start_trace`` and off after
+        ``stop_trace`` unless :meth:`enable` holds it on.  Every
+        recording site keeps its one attribute check."""
+        live = _profiler_live()
+        if live != self._session:
+            self._session = live
+            self.enabled = live or self._forced
+        return self.enabled
 
     def clear(self):
         with self._lock:
@@ -177,6 +189,8 @@ class Tracer:
         attribute check — callers may invoke this unconditionally."""
         if not self.enabled:
             return
+        if self._session and not self._forced and not _profiler_live():
+            return  # the session ended under this span: not part of it
         th = threading.current_thread()
         span = Span(name, cat, t0, t1, th.ident or 0, th.name,
                     corr if corr is not None else get_correlation(),
@@ -208,17 +222,27 @@ class Tracer:
     def span(self, name: str, cat: str = CAT_HOST,
              corr: Optional[str] = None,
              args: Optional[Dict[str, Any]] = None):
-        """Context manager measuring the enclosed block.  Cheap when
-        disabled (no timestamps taken)."""
+        """Context manager measuring the enclosed block: the span goes
+        into the ring and, as a ``TraceAnnotation`` carrying ``corr``
+        and the scalar ``args`` it starts with, into a live profiler
+        trace (what the block adds to ``args`` reaches the ring only).
+        Cheap when disabled (no timestamps taken)."""
         if not self.enabled:
             yield
             return
+        if corr is None:
+            corr = get_correlation()
+        stats = {k: v for k, v in args.items()
+                 if isinstance(v, (int, float, str))} if args else {}
+        if corr is not None:
+            stats["corr"] = corr
         t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add_span(name, cat, t0, time.perf_counter(),
-                          corr=corr, args=args)
+        with TraceAnnotation(name, **stats):
+            try:
+                yield
+            finally:
+                self.add_span(name, cat, t0, time.perf_counter(),
+                              corr=corr, args=args)
 
     # -- reading -------------------------------------------------------
     def spans(self) -> List[Span]:
@@ -270,9 +294,9 @@ def disable() -> Tracer:
 def enabled(capacity: Optional[int] = None):
     """Scope global tracing to a block (restores the prior state)."""
     tr = get_tracer()
-    was = tr.enabled
+    was = tr.enabled, tr._forced
     tr.enable(capacity)
     try:
         yield tr
     finally:
-        tr.enabled = was
+        tr.enabled, tr._forced = was

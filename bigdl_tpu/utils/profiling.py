@@ -10,7 +10,8 @@ Two complementary tools:
   rank hot layers exactly like the reference's per-module timers did.
 * :class:`trace` — context manager around ``jax.profiler`` emitting an
   XPlane trace viewable in TensorBoard/XProf, the real TPU-era answer
-  to "where does the step time go" (per-op, per-fusion, HBM traffic).
+  to "where does the step time go" (per-op, per-fusion, HBM traffic);
+  the program's host spans are in it (docs/observability.md).
 """
 from __future__ import annotations
 
@@ -133,42 +134,19 @@ def format_times(rows) -> str:
 
 
 @contextlib.contextmanager
-def trace(logdir: str, host_spans: bool = True, xplane: bool = True):
+def trace(logdir: str):
     """``with profiling.trace('/tmp/tb'):`` — wraps jax.profiler; open
     the result in TensorBoard's profile plugin / xprof.
 
-    ``host_spans=True`` (default) additionally enables the
-    :mod:`bigdl_tpu.telemetry` tracer for the block and writes the
-    host-side span overlay (training-loop phases, prefetch producer,
-    checkpoint writer, serving threads — everything the XPlane's
-    device view can't see) to ``<logdir>/host_trace.json``, loadable
-    in ``ui.perfetto.dev`` next to the device trace.  ``xplane=False``
-    skips the jax.profiler capture (host overlay only)."""
-    import os as _os
-
-    tracer = enter_t = None
-    if host_spans:
-        from bigdl_tpu.telemetry import tracer as _ttr
-
-        tracer = _ttr.get_tracer()
-        was_enabled = tracer.enabled
-        tracer.enable()
-        enter_t = time.perf_counter()
-    if xplane:
-        jax.profiler.start_trace(logdir)
+    The :mod:`bigdl_tpu.telemetry` tracer follows the session, so the
+    program's own spans (training-loop phases, prefetch producer,
+    checkpoint writer, serving threads) are in the same trace, on plane
+    ``/host:CPU`` and on the device planes' clock."""
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
-        if xplane:
-            jax.profiler.stop_trace()
-        if tracer is not None:
-            from bigdl_tpu.telemetry import export as _texp
-
-            spans = [s for s in tracer.spans() if s.t1 >= enter_t]
-            _texp.write_chrome_trace(
-                _os.path.join(logdir, "host_trace.json"), tracer,
-                spans=spans)
-            tracer.enabled = was_enabled
+        jax.profiler.stop_trace()
 
 
 def annotate(name: str):

@@ -380,6 +380,7 @@ def test_decode_log_line_carries_token_metrics(engine_lm):
         eng.generate([1, 2], 4, timeout=120)
         line = eng.log_line()
     assert "tok/s" in line and "slots=" in line and "tick p50=" in line
+    assert "ttft p50=" in line and "gap p50=" in line and "p95=" in line
 
 
 # ------------------------------------------------------- acceptance A/B
@@ -636,3 +637,203 @@ def test_decode_production_arms_gates():
     # speculative: acceptance reported, no slowdown vs dense greedy
     assert rec["speculative"]["spec_acceptance_rate"] > 0.0, rec
     assert rec["spec_speedup"] >= 1.0, rec
+
+
+# ------------------------------------- one timeline (ISSUE 26 tentpole)
+@pytest.fixture
+def tracer():
+    from bigdl_tpu import telemetry
+
+    tr = telemetry.get_tracer()
+    tr.disable()
+    tr.clear()
+    yield tr
+    tr.disable()
+    tr.clear()
+
+
+def test_loop_spans_tile_the_decode_loop(tracer):
+    """Over a short run the top-level ``loop/*`` spans of the loop
+    thread do not overlap and cover >= 98% of the wall time between
+    the first and the last (a model wide enough that a tick is
+    milliseconds: the spans' own seams are microseconds)."""
+    model = _lm(vocab=256, hidden=256, heads=4, filt=1024, layers=4)
+    var = model.init(jax.random.PRNGKey(0))
+    with _engine(model, var, slots=8, max_len=256, prompt_buckets=(16,),
+                 prefill_batch_sizes=(1,), kv_layout="paged",
+                 page_size=16) as eng:
+        tracer.enable()
+        futs = [eng.submit(np.arange(1, 9), 12) for _ in range(10)]
+        for f in futs:
+            f.result(120)
+        tracer.disable()
+    spans = tracer.spans()
+    loop = sorted((s for s in spans if s.name.startswith("loop/")),
+                  key=lambda s: s.t0)
+    assert {s.name for s in loop} == {
+        "loop/drain_queue", "loop/admit", "loop/chunk_step",
+        "loop/budget_pages", "loop/tick_dispatch", "loop/tick_wait",
+        "loop/retire"}
+    assert len({s.tid for s in loop}) == 1
+    assert all(a.t1 <= b.t0 for a, b in zip(loop, loop[1:]))
+    wall = loop[-1].t1 - loop[0].t0
+    assert sum(s.duration for s in loop) >= 0.98 * wall
+    # children lie inside their parent, on the same thread
+    for child, parent in (("prefill_dispatch", "loop/admit"),
+                          ("prefill_wait", "loop/admit"),
+                          ("host_sample", "loop/admit"),
+                          ("slot_write", "loop/admit"),
+                          ("deliver", "loop/retire")):
+        kids = [s for s in spans if s.name == child]
+        assert kids, child
+        for k in kids:
+            assert any(p.name == parent and p.tid == k.tid
+                       and p.t0 <= k.t0 and k.t1 <= p.t1
+                       for p in loop), (child, parent)
+    admits = [s.args["admitted"] for s in loop if s.name == "loop/admit"]
+    assert sum(admits) == 10
+    # a turn's spans share the index of the tick it runs (the turn
+    # that was under way at enable() set none)
+    ticks = [s for s in loop if s.name == "loop/retire"]
+    corrs = {s.corr for s in loop} - {None}
+    assert all(c.startswith("tick:") for c in corrs)
+    assert len(corrs) >= len(ticks) - 1
+
+
+def test_token_times_ttft_and_gaps(engine_lm, tracer):
+    """Every future carries one perf_counter time per returned token;
+    ttft is the first of them less the submit, and the gaps_ms of the
+    ring's loop/retire spans are the differences of token_times."""
+    model, var = engine_lm
+    with _engine(model, var, slots=2) as eng:
+        eng.generate([1, 2], 2, timeout=120)  # leave warm-up behind
+        eng.metrics.base.reset()
+        tracer.enable()
+        t_before = time.perf_counter()
+        fut = eng.submit([1, 2, 3], 7)
+        t_after = time.perf_counter()
+        tokens = fut.result(120)
+        ttft_s = eng.metrics.ttft_ms(50) / 1e3  # its one sample so far
+        one = eng.submit([3], 1)  # finished by its prefill token
+        one.result(120)
+        tracer.disable()
+        gap_p50 = eng.metrics.token_gap_ms(50)
+    times = fut.token_times
+    assert times.dtype == np.float64 and times.shape == tokens.shape == (7,)
+    assert np.all(np.diff(times) >= 0)
+    assert times[0] - t_after <= ttft_s <= times[0] - t_before
+    assert one.token_times.shape == (1,)
+    gaps = [g for s in tracer.spans() if s.name == "loop/retire"
+            for g in s.args["gaps_ms"]]
+    np.testing.assert_allclose(gaps, 1e3 * np.diff(times), rtol=0,
+                               atol=1e-6)
+    assert min(gaps) <= gap_p50 <= max(gaps)
+
+
+def test_off_path_decode_loop_creates_no_span(engine_lm, tracer,
+                                              monkeypatch):
+    """No session, tracer off: the decode loop creates no Span and
+    makes at most one profiler-state check per turn."""
+    from bigdl_tpu.telemetry import tracer as tracer_mod
+
+    checks, turns, made = [], [], []
+
+    class CountedSpan(tracer_mod.Span):
+        def __init__(self, *a, **k):
+            made.append(a[0])
+            super().__init__(*a, **k)
+
+    def live():
+        checks.append(1)
+        return False
+
+    model, var = engine_lm
+    with _engine(model, var) as eng:
+        real = eng._drain_queue  # called once per loop turn
+
+        def counted(*a, **k):
+            turns.append(1)
+            return real(*a, **k)
+
+        monkeypatch.setattr(tracer_mod, "Span", CountedSpan)
+        monkeypatch.setattr(tracer_mod, "_profiler_live", live)
+        monkeypatch.setattr(eng, "_drain_queue", counted)
+        checks.clear()
+        for f in [eng.submit([1, 2, 3], 30) for _ in range(3)]:
+            f.result(120)
+        n_checks, n_turns = len(checks), len(turns)
+    assert n_turns >= 50 and n_checks <= n_turns + 1
+    assert not made and len(tracer) == 0
+
+
+def _scopes_in(jitted, *args):
+    """Name-scope components of the lowered program's locations
+    (``jit(f)/attention/...``, ``transpose(jvp(attention))/...``)."""
+    import re
+
+    text = jitted.lower(*args).as_text(debug_info=True)
+    return set(re.findall(r"[/(]([a-z_0-9]+)(?=[/)])", text)), text
+
+
+def test_device_scopes_name_the_train_step():
+    import bigdl_tpu.optim as optim
+    from bigdl_tpu.optim.optimizer import make_train_step
+
+    model = _lm()
+    var = model.init(jax.random.PRNGKey(0))
+    method = optim.Adam(1e-3)
+    step = jax.jit(make_train_step(
+        model, nn.TimeDistributedCriterion(
+            nn.ClassNLLCriterion(logits=True)),
+        {"__all__": method}, grad_clip_norm=1.0))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    scopes, text = _scopes_in(
+        step, var["params"], var["state"],
+        {"__all__": method.init_state(var["params"])},
+        jnp.asarray(1, jnp.int32), jax.random.PRNGKey(0), ids, ids,
+        [jnp.asarray(1e-3, jnp.float32)])
+    assert {"embed", "attention", "ffn", "head", "loss", "clip",
+            "optimizer"} <= scopes
+    # the backward of a scope keeps its name
+    assert "transpose(jvp(attention))" in text
+
+
+def test_device_scopes_name_the_decode_programs(engine_lm):
+    from bigdl_tpu.serving import decode
+
+    model, var = engine_lm
+    slots, page, pages = 2, 4, 8
+    cache = model.init_paged_cache(slots * pages + 1, page, slots)
+    table = np.zeros((slots, pages), np.int32)
+    tok = np.zeros((slots,), np.int32)
+    act = np.ones((slots,), bool)
+    samp = (np.zeros((slots, 2), np.uint32), np.zeros((slots,), np.float32),
+            np.zeros((slots,), np.int32), np.ones((slots,), np.float32))
+    scopes, _ = _scopes_in(decode.build_paged_tick(model), var["params"],
+                           var["state"], cache, table, tok, act, *samp)
+    assert {"embed", "paged_append", "paged_gather", "attention", "ffn",
+            "head", "sample"} <= scopes
+    # prefill and the slot write run under a top scope of their own
+    scopes, _ = _scopes_in(decode.build_prefill(model, page * pages),
+                           var["params"], var["state"],
+                           np.zeros((1, 4), np.int32),
+                           np.ones((1,), np.int32))
+    assert {"prefill", "attention", "head"} <= scopes
+    scopes, _ = _scopes_in(decode.build_paged_write_slot(), cache,
+                           table[0], model.init_cache(1, page * pages),
+                           0, 0)
+    assert "slot_write" in scopes
+
+
+def test_flash_forward_kernel_is_named():
+    import importlib
+
+    flash = importlib.import_module(
+        "bigdl_tpu.ops.pallas.flash_attention")
+    q = jnp.zeros((1, 2, 256, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash._flash_fwd_pallas(
+        q, k, v, True, 0.125, 128, 128, True))(q, q, q)
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "flash_fwd"
+

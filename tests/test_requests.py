@@ -115,24 +115,50 @@ def test_ledger_concurrent_requests_each_partition_exact():
     assert led.log_line().startswith("xray: n=2")
 
 
-def test_ledger_enable_knob_and_drop(monkeypatch):
+def test_ledger_follows_tracer_and_drop():
     tr = Tracer(capacity=16)  # disabled
     led = rx.RequestLedger(tracer=tr)
     assert not led.enabled
     led.open(1, now=0.0)
     assert led.close(1, now=1.0) is None  # dark plane: no accounting
-    monkeypatch.setenv("BIGDL_TPU_REQ_TRACE", "1")
-    forced = rx.RequestLedger(tracer=tr)
-    assert forced.enabled  # forced on even while the tracer is off
-    assert rx.request_trace_enabled(tr)
-    monkeypatch.setenv("BIGDL_TPU_REQ_TRACE", "0")
-    assert not rx.RequestLedger(tracer=tr).enabled
-    assert not rx.request_trace_enabled(tr)
+    # the tracer's state is the only switch, followed dynamically
+    tr.enable()
+    assert led.enabled
+    led.open(2, now=0.0)
+    assert led.close(1, now=2.0) is None  # opened while dark: unknown
+    tr.disable()
+    assert not led.enabled
+    # a request still open when the tracer goes off is dropped, not
+    # left in the ledger for close() to skip
+    assert led.open_count() == 0
+    tr.enable()
+    assert led.close(2, now=1.0) is None
     # drop: forget without accounting (queue_full rejections)
-    forced.open(3, now=0.0)
-    forced.drop(3)
-    assert forced.close(3, now=1.0) is None
-    assert forced.summary()["n_closed"] == 0
+    led.open(3, now=0.0)
+    led.drop(3)
+    assert led.close(3, now=1.0) is None
+    assert led.summary()["n_closed"] == 0
+
+
+def test_ledger_follows_a_profiler_session(tmp_path):
+    """No env: attribution is on for exactly the profiler session."""
+    import jax
+
+    tr = Tracer(capacity=16)
+    led = rx.RequestLedger(tracer=tr)
+    led.open(1, now=0.0)
+    assert led.open_count() == 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert tr.poll() and led.enabled
+        led.open(2, now=0.0)
+        led.open(3, now=0.0)
+        att = led.close(2, now=0.5)
+        assert att is not None and att.latency == pytest.approx(0.5)
+    finally:
+        jax.profiler.stop_trace()
+    assert not tr.poll() and not led.enabled
+    assert led.close(3, now=1.0) is None and led.open_count() == 0
 
 
 # ------------------------------------------------------- tree assembly
