@@ -213,16 +213,22 @@ class MultiHeadAttention(Module):
 
     def apply_paged(self, params, x, cache, table, active):
         """``apply_cached`` over the paged pool: scatter ``x``'s K/V
-        through the block table at each row's ``length``, gather the
-        full logical extent back, and attend under the same
-        causal-by-length mask — the math is identical to the dense
-        path, so dense-vs-paged is a byte-near parity oracle.  Writes
-        for inactive rows are redirected to the trash page; stray
-        entries past ``length`` are masked (stale-above-length)."""
+        through the block table at each row's ``length`` and attend
+        under the same causal-by-length mask — the math is that of the
+        dense path, so dense-vs-paged is a byte-near parity oracle.
+        Writes for inactive rows are redirected to the trash page;
+        stray entries past ``length`` are masked (stale-above-length).
+
+        The read side is chosen on what is seen here: one query token
+        on a float pool reads only the pages held, in place, through
+        the ``paged_attn`` kernel (TPU; inactive rows read zeros);
+        longer queries (speculative verify, prefill chunks), the int8
+        pool and every other backend gather the full logical extent
+        and run the stock attention core."""
         from bigdl_tpu.ops import paged_kv
+        from bigdl_tpu.ops.pallas import paged_attention
 
         n, tq, _ = x.shape
-        q = self._heads(x, params["wq"])
         k = self._heads(x, params["wk"])
         v = self._heads(x, params["wv"])
         page = cache["k"].shape[1]
@@ -230,6 +236,16 @@ class MultiHeadAttention(Module):
         length = cache["length"]                       # (N,)
         cache = paged_kv.paged_append(cache, table, active, k, v,
                                       page, l_max)
+        new_cache = dict(cache, length=length + tq)
+        if paged_attention.routes(x.shape, cache["k"], table,
+                                  self.num_heads):
+            with jax.named_scope("paged_attention"):
+                out = paged_attention.paged_attn(
+                    x @ params["wq"].astype(x.dtype), cache["k"],
+                    cache["v"], table, jnp.where(active, length + 1, 0),
+                    num_heads=self.num_heads)
+            return out @ params["wo"].astype(out.dtype), new_cache
+        q = self._heads(x, params["wq"])
         pos = length[:, None] + jnp.arange(tq)[None]   # (N, Tq)
         mask = (jnp.arange(l_max)[None, None, None, :]
                 <= pos[:, None, :, None])              # (N, 1, Tq, L)
@@ -240,8 +256,8 @@ class MultiHeadAttention(Module):
             # the f32 softmax stay XLA (per-row V scale has no
             # scale-epilogue analogue).  Everywhere else the gather
             # dequantizes and the stock attention core runs.
-            k_q, k_s, v_all = paged_kv.paged_gather_q(cache, table,
-                                                      page)
+            k_q, k_s, v_all = paged_kv.paged_gather_q(
+                cache, table, self.num_heads)
             scores = paged_kv.int8_scores(q, k_q, k_s, jnp.float32)
             scores = scores / math.sqrt(self.head_dim)
             scores = jnp.where(mask, scores, -1e30)
@@ -249,13 +265,12 @@ class MultiHeadAttention(Module):
             out = jnp.einsum("nhql,nhld->nhqd", probs,
                              v_all).astype(q.dtype)
         else:
-            k_all, v_all = paged_kv.paged_gather(cache, table, page,
-                                                 q.dtype)
+            k_all, v_all = paged_kv.paged_gather(
+                cache, table, self.num_heads, q.dtype)
             out = dot_product_attention(q, k_all, v_all, mask=mask,
                                         use_flash=False)
         out = out.transpose(0, 2, 1, 3).reshape(n, tq, self.hidden_size)
-        out = out @ params["wo"].astype(out.dtype)
-        return out, dict(cache, length=length + tq)
+        return out @ params["wo"].astype(out.dtype), new_cache
 
 
 # Reference exposes this as `Attention`

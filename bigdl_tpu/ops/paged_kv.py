@@ -5,8 +5,9 @@ The dense decode cache (nn/attention.py ``init_cache``) reserves
 request ever grows that long.  The paged layout breaks each layer's
 cache into fixed-size pages,
 
-    pool  {"k": (P, Q, H, D), "v": (P, Q, H, D), "length": (S,)}
-          [+ "k_scale"/"v_scale": (P, Q, H) f32 when int8-quantized]
+    pool  {"k": (P, Q, H*D), "v": (P, Q, H*D), "length": (S,)}
+          [+ "k_scale"/"v_scale": (P, Q*H8) f32 when int8-quantized;
+             H8 = H rounded up to 8]
 
 with a per-slot *block table* ``(S, M)`` int32 mapping each slot's
 logical page ``0..M-1`` to a physical page in the pool.  The table is
@@ -15,6 +16,28 @@ plain device argument — its *values* change as pages are allocated and
 freed, but its shape never does, so the one-compiled-tick discipline
 (docs/decoding.md) is preserved while retirement returns pages to the
 free list at token granularity.
+
+The shape is the layout.  The TPU stores an array by a layout it
+derives from the shape: ``(P, Q, H, D)`` with minor dims (12, 64) gets
+the page axis in the lanes (``{0,3,2,1:T(8,128)}``), into which no
+token row can be scattered, so every program touching the pool copied
+each leaf to row-major and back — four whole-pool copies a layer.  With
+the heads side by side a page is ``Q`` rows of ``H*D`` lanes, stored
+row-major in whole (8, 128) tiles: the ``(P*Q, H*D)`` view a token row
+is scattered into is a bitcast, the scatter updates a donated leaf in
+place, and a page is one contiguous run a DMA can fetch — or a slot
+write can store (:func:`write_pages`: a prefilled row goes in as whole
+pages, a sixteenth of the updates at ``Q`` = 16).  The scales
+take the same form — one row of whole lanes a page (``Q*H8``), a
+token's ``H`` scales a window of it.  The leading axis is always the
+page (serving/decode.page_reset_fn, the HbmLedger's bytes per page).
+
+Reading (nn/attention.py ``apply_paged`` chooses on what it sees): one
+query token on a float pool on the TPU goes through the ``paged_attn``
+kernel (ops/pallas/paged_attention.py), which fetches only the pages a
+slot holds; longer queries (speculative verify, prefill chunks), the
+int8 pool and other backends gather the full extent page by page
+(:func:`paged_gather`) for the stock attention core.
 
 Physical page 0 is reserved as the *trash page*: it is never allocated,
 unmapped block-table entries point at it, and writes for inactive slots
@@ -29,12 +52,10 @@ quantized K *is* the ``int8_matmul_dequant`` contract — int8 operand,
 per-output-column scale — so when shapes are Pallas-eligible on TPU the
 scores route through that kernel (and therefore through the PR-13
 autotuner's ``int8_matmul`` family); everywhere else an XLA
-dequantize-then-dot computes the identical result.  Single-token decode
-(Tq == 1) stays on XLA by design, like tools/kernel_shapes.DECODE_ATTN
-— the speculative verify pass (Tq == draft_k + 1) is the realistic
-Pallas customer, and its shapes are registered in
-tools/kernel_shapes.INT8 for the autotuner sweep and the pallas-routing
-lint rule.
+dequantize-then-dot computes the identical result.  The speculative
+verify pass (Tq == draft_k + 1) is the realistic customer, and its
+shapes are registered in tools/kernel_shapes.INT8 for the autotuner
+sweep and the pallas-routing lint rule.
 """
 from __future__ import annotations
 
@@ -78,7 +99,7 @@ def init_pool(num_pages: int, page_size: int, num_heads: int,
     in the dense cache, so retirement/length bookkeeping is layout-
     independent in the engine.
     """
-    shape = (num_pages, page_size, num_heads, head_dim)
+    shape = (num_pages, page_size, num_heads * head_dim)
     store = jnp.int8 if quantized else dtype
     pool = {
         "k": jnp.zeros(shape, store),
@@ -86,9 +107,16 @@ def init_pool(num_pages: int, page_size: int, num_heads: int,
         "length": jnp.zeros((batch,), jnp.int32),
     }
     if quantized:
-        pool["k_scale"] = jnp.zeros(shape[:3], jnp.float32)
-        pool["v_scale"] = jnp.zeros(shape[:3], jnp.float32)
+        scales = (num_pages, page_size * _scale_width(num_heads))
+        pool["k_scale"] = jnp.zeros(scales, jnp.float32)
+        pool["v_scale"] = jnp.zeros(scales, jnp.float32)
     return pool
+
+
+def _scale_width(num_heads: int) -> int:
+    """Lanes a token's ``H`` scales take in a page's scale row: ``H``
+    rounded up to 8, so a page of 16 tokens is whole 128-lane tiles."""
+    return -(-num_heads // 8) * 8
 
 
 def is_quantized(pool) -> bool:
@@ -100,7 +128,7 @@ def page_bytes(page_size: int, num_heads: int, head_dim: int,
     """Bytes one physical page costs in one layer's pool (K + V +
     scales) — the unit the HbmLedger resident lane reports in."""
     if quantized:
-        per_tok = num_heads * head_dim * 2 + num_heads * 4 * 2
+        per_tok = num_heads * head_dim * 2 + _scale_width(num_heads) * 4 * 2
     else:
         per_tok = num_heads * head_dim * 2 * jnp.dtype(dtype).itemsize
     return page_size * per_tok
@@ -123,6 +151,30 @@ def flat_positions(table, pos, active, page_size, max_len):
     return jnp.where(ok, idx, pos % page_size)            # trash page 0
 
 
+def write_pages(pool, name, table_row, vals):
+    """Write a slot's first token rows ``vals`` (T, H, D) into leaf
+    ``name`` of ``pool`` (a dict, updated in place) as whole pages
+    through the slot's block-table row (M,): ``ceil(T / Q)`` contiguous
+    page runs instead of ``T`` row updates (the TPU's scatter is a loop
+    over its updates).  Rows that pad ``T`` to whole pages lie past any
+    length the slot can have yet; unmapped entries name the trash
+    page."""
+    t, h, d = vals.shape
+    _, page, hd = pool[name].shape
+    n = -(-t // page)
+    at = table_row[:n]
+    if is_quantized(pool):
+        vals, scale = quantize_kv(vals)
+        leaf = pool[name + "_scale"]
+        width = leaf.shape[1] // page
+        scale = jnp.pad(scale, ((0, n * page - t), (0, width - h)))
+        pool[name + "_scale"] = leaf.at[at].set(
+            scale.reshape(n, page * width))
+    rows = jnp.pad(vals.reshape(t, hd), ((0, n * page - t), (0, 0)))
+    pool[name] = pool[name].at[at].set(
+        rows.reshape(n, page, hd).astype(pool[name].dtype))
+
+
 @jax.named_scope("paged_append")
 def paged_append(pool, table, active, k_new, v_new, page_size, max_len):
     """Scatter ``k_new``/``v_new`` (S, H, T, D) into the pool at each
@@ -139,59 +191,72 @@ def paged_append(pool, table, active, k_new, v_new, page_size, max_len):
         vals = new.transpose(0, 2, 1, 3).reshape(s * t, h, d)
         store = pool[name].shape
         if is_quantized(pool):
-            q, scale = quantize_kv(vals)
-            pool[name] = pool[name].reshape(-1, h, d).at[flat].set(
-                q).reshape(store)
-            pool[name + "_scale"] = pool[name + "_scale"].reshape(
-                -1, h).at[flat].set(scale).reshape(store[:3])
-        else:
-            pool[name] = pool[name].reshape(-1, h, d).at[flat].set(
-                vals.astype(pool[name].dtype)).reshape(store)
+            # a token's H scales are a window of its page's scale row
+            vals, scale = quantize_kv(vals)
+            leaf = pool[name + "_scale"]
+            width = leaf.shape[1] // page_size
+            at = jnp.stack([flat // page_size,
+                            flat % page_size * width], axis=1)
+            pool[name + "_scale"] = jax.lax.scatter(
+                leaf, at, scale, jax.lax.ScatterDimensionNumbers(
+                    update_window_dims=(1,), inserted_window_dims=(0,),
+                    scatter_dims_to_operand_dims=(0, 1)))
+        # the flat view is a bitcast of the row-major pool and the
+        # scatter updates a donated leaf's buffer in place
+        pool[name] = pool[name].reshape(-1, h * d).at[flat].set(
+            vals.reshape(s * t, h * d).astype(pool[name].dtype)
+        ).reshape(store)
     return pool
 
 
+def _gather_pages(leaf, table, page_size):
+    """A slot-major view of the pages the table names: ``leaf``
+    (P, Q, C) or (P, Q*C) -> (S, M*Q, C).  The gather moves whole
+    pages, each one contiguous run of the row-major pool."""
+    s, m = table.shape
+    return jnp.take(leaf, table, axis=0).reshape(s, m * page_size, -1)
+
+
+def _gather_heads(pool, name, table, num_heads):
+    """Leaf ``name`` gathered and split by head: ``(x (S, H, L, D) in
+    the pool's dtype, scale (S, H, L) or None)``."""
+    page = pool[name].shape[1]
+    rows = _gather_pages(pool[name], table, page)         # (S, L, H*D)
+    s, l, hd = rows.shape
+    x = rows.reshape(s, l, num_heads, hd // num_heads).transpose(
+        0, 2, 1, 3)
+    if not is_quantized(pool):
+        return x, None
+    scale = _gather_pages(pool[name + "_scale"], table, page)
+    return x, scale[:, :, :num_heads].transpose(0, 2, 1)
+
+
 @jax.named_scope("paged_gather")
-def paged_gather(pool, table, page_size, dtype):
+def paged_gather(pool, table, num_heads, dtype):
     """Gather each slot's full logical extent out of the pool:
     returns ``(k, v)`` each (S, H, M*Q, D) in ``dtype`` (dequantized
     when the pool is int8).  Entries past a slot's ``length`` come from
     unmapped/trash pages and carry garbage — callers mask by length,
     the same stale-above-length invariant the dense cache relies on."""
-    p, q, h, d = pool["k"].shape
-    s, m = table.shape
-    idx = (table[:, :, None] * page_size
-           + jnp.arange(page_size)[None, None]).reshape(s, m * q)
     out = []
     for name in ("k", "v"):
-        flat = pool[name].reshape(p * q, h, d)
-        g = jnp.take(flat, idx, axis=0)                   # (S, L, H, D)
-        if is_quantized(pool):
-            sc = jnp.take(pool[name + "_scale"].reshape(p * q, h),
-                          idx, axis=0)                    # (S, L, H)
-            g = dequantize_kv(g, sc, dtype)
-        out.append(g.astype(dtype).transpose(0, 2, 1, 3))
+        x, scale = _gather_heads(pool, name, table, num_heads)
+        out.append(x.astype(dtype) if scale is None
+                   else dequantize_kv(x, scale, dtype))
     return out[0], out[1]
 
 
 @jax.named_scope("paged_gather")
-def paged_gather_q(pool, table, page_size):
+def paged_gather_q(pool, table, num_heads):
     """Raw gather for the int8 Pallas score path: returns
     ``(k_q (S, H, L, D) int8, k_scale (S, H, L) f32, v (S, H, L, D)
     f32)`` — K stays quantized (the kernel dequantizes via its scale
     epilogue), V is dequantized for the XLA PV contraction whose
     per-contraction-row scale has no ``int8_matmul_dequant`` analogue."""
-    p, q, h, d = pool["k"].shape
-    s, m = table.shape
-    idx = (table[:, :, None] * page_size
-           + jnp.arange(page_size)[None, None]).reshape(s, m * q)
-    k_q = jnp.take(pool["k"].reshape(p * q, h, d), idx, axis=0)
-    k_s = jnp.take(pool["k_scale"].reshape(p * q, h), idx, axis=0)
-    v = dequantize_kv(
-        jnp.take(pool["v"].reshape(p * q, h, d), idx, axis=0),
-        jnp.take(pool["v_scale"].reshape(p * q, h), idx, axis=0),
-        jnp.float32)
-    return (k_q.transpose(0, 2, 1, 3), k_s.transpose(0, 2, 1),
-            v.transpose(0, 2, 1, 3))
+    k_q, k_s = _gather_heads(pool, "k", table, num_heads)
+    v = dequantize_kv(*_gather_heads(pool, "v", table, num_heads),
+                      jnp.float32)
+    return k_q, k_s, v
 
 
 # ------------------------------------------------- int8 kernel routing

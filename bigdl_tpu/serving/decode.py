@@ -272,30 +272,12 @@ def paged_write_slot_fn():
         out = {}
         for lk, pool in pool_cache.items():
             bc = batch_cache[lk]
-            t_max = bc["k"].shape[2]
-            page = pool["k"].shape[1]
-            h, d = pool["k"].shape[2], pool["k"].shape[3]
-            pos = jnp.arange(t_max)[None, :]               # (1, T)
-            idx = paged_kv.flat_positions(
-                table_row[None], pos, jnp.ones((1,), bool), page,
-                table_row.shape[0] * page).reshape(-1)     # (T,)
             new = dict(pool)
             for name in ("k", "v"):
-                r = jax.lax.dynamic_slice_in_dim(
-                    bc[name], row, 1, axis=0)              # (1,H,T,D)
-                vals = r.transpose(0, 2, 1, 3).reshape(t_max, h, d)
-                flat = new[name].reshape(-1, h, d)
-                if paged_kv.is_quantized(pool):
-                    q, scale = paged_kv.quantize_kv(vals)
-                    new[name] = flat.at[idx].set(q).reshape(
-                        pool[name].shape)
-                    sflat = new[name + "_scale"].reshape(-1, h)
-                    new[name + "_scale"] = sflat.at[idx].set(
-                        scale).reshape(pool[name + "_scale"].shape)
-                else:
-                    new[name] = flat.at[idx].set(
-                        vals.astype(flat.dtype)).reshape(
-                            pool[name].shape)
+                r = jax.lax.dynamic_index_in_dim(
+                    bc[name], row, axis=0, keepdims=False)  # (H,T,D)
+                paged_kv.write_pages(new, name, table_row,
+                                     r.transpose(1, 0, 2))
             lrow = jax.lax.dynamic_slice_in_dim(bc["length"], row, 1,
                                                 axis=0)
             new["length"] = jax.lax.dynamic_update_slice_in_dim(
@@ -993,13 +975,21 @@ class DecodeEngine:
         if cost is not None:
             self._tick_cost = cost
 
+    def _pages_held(self):
+        """``loop/tick_dispatch``'s counter: the pages the slots hold
+        (allocator, host side) — the share of the ``S * M`` extent this
+        tick's attention has to read."""
+        return {"pages_held": self._alloc.pages_in_use} \
+            if self.paged else None
+
     def _run_tick(self):
         def thunk():
             cache, nxt, keys = self._tick(*self._tick_args())
             self._cache = cache
             return nxt, keys
 
-        with self._tracer.span("loop/tick_dispatch", CAT_DECODE):
+        with self._tracer.span("loop/tick_dispatch", CAT_DECODE,
+                               args=self._pages_held()):
             out = self._tracked(
                 ("tick",), thunk, program="decode_tick",
                 sig_fn=lambda: programs.signature_of(
@@ -1777,7 +1767,8 @@ class DecodeEngine:
             self.xray.to_many(spec_rids, request_xray.PHASE_SPEC,
                               now=t0)
         with StepTraceAnnotation("decode_tick", step_num=self._tick_no):
-            with tr.span("loop/tick_dispatch", CAT_DECODE):
+            with tr.span("loop/tick_dispatch", CAT_DECODE,
+                         args=self._pages_held()):
                 out = self._run_verify(self._run_propose())
             with tr.span("loop/tick_wait", CAT_DECODE):
                 emitted, n_emit = jax.device_get(out)

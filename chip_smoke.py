@@ -495,7 +495,8 @@ def phase_kernels(sz: Sizes) -> dict:
         say(f"[kernels]   non-Pallas route: {fam} {path} {shape}")
 
     must_run = ("fused_matmul", "fused_conv3x3", "fused_conv3x3_dgrad",
-                "flash_attention", "int8_matmul")
+                "flash_attention", "int8_matmul",
+                "paged_attention")  # the serve phase's tick
     dead = [f for f in must_run if routes.get(f, {}).get("pallas", 0) == 0]
     check(not dead, f"kernels: no Pallas route taken by {dead}")
     inventory = _inventory_shapes()

@@ -54,3 +54,17 @@ def np_rng():
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running accuracy-parity runs")
+
+
+@pytest.fixture
+def interpreted_paged_attn(monkeypatch):
+    """Route ``apply_paged`` as on the TPU, with the ``paged_attn``
+    kernel run by the Pallas interpreter (this tier has no chip)."""
+    import functools
+
+    from bigdl_tpu.ops.pallas import paged_attention
+
+    monkeypatch.setenv("BIGDL_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        paged_attention, "paged_attn",
+        functools.partial(paged_attention.paged_attn, interpret=True))

@@ -700,6 +700,30 @@ def test_loop_spans_tile_the_decode_loop(tracer):
     assert len(corrs) >= len(ticks) - 1
 
 
+def test_tick_dispatch_counts_the_pages_held(engine_lm, tracer):
+    """``loop/tick_dispatch`` carries the pages the slots hold (the
+    allocator's count, host side): what a tick's attention has to read
+    of the ``slots x pages`` extent.  A dense engine has none."""
+    model, var = engine_lm
+    with _engine(model, var, kv_layout="paged", page_size=4,
+                 slots=2) as eng:
+        tracer.enable()
+        eng.generate([1, 2, 3, 4, 5, 6], 10, timeout=120)
+        tracer.disable()
+    held = [s.args["pages_held"] for s in tracer.spans()
+            if s.name == "loop/tick_dispatch"]
+    # 6 prompt tokens then 9 ticks at 4 tokens a page: 2 pages grow to 4
+    assert len(held) >= 9 and held == sorted(held)
+    assert held[0] == 2 and held[-1] == 4
+    tracer.clear()
+    with _engine(model, var, slots=2) as eng:
+        tracer.enable()
+        eng.generate([1, 2, 3], 4, timeout=120)
+        tracer.disable()
+    assert all(s.args is None for s in tracer.spans()
+               if s.name == "loop/tick_dispatch")
+
+
 def test_token_times_ttft_and_gaps(engine_lm, tracer):
     """Every future carries one perf_counter time per returned token;
     ttft is the first of them less the submit, and the gaps_ms of the
@@ -811,8 +835,11 @@ def test_device_scopes_name_the_decode_programs(engine_lm):
             np.zeros((slots,), np.int32), np.ones((slots,), np.float32))
     scopes, _ = _scopes_in(decode.build_paged_tick(model), var["params"],
                            var["state"], cache, table, tok, act, *samp)
+    # off the TPU (and for Tq > 1 or an int8 pool anywhere) attention
+    # gathers the extent; the kernel route is named below
     assert {"embed", "paged_append", "paged_gather", "attention", "ffn",
             "head", "sample"} <= scopes
+    assert "paged_attention" not in scopes
     # prefill and the slot write run under a top scope of their own
     scopes, _ = _scopes_in(decode.build_prefill(model, page * pages),
                            var["params"], var["state"],
@@ -823,6 +850,30 @@ def test_device_scopes_name_the_decode_programs(engine_lm):
                            table[0], model.init_cache(1, page * pages),
                            0, 0)
     assert "slot_write" in scopes
+
+
+def test_device_scopes_name_the_paged_attention_kernel(
+        interpreted_paged_attn):
+    """The tick as the TPU runs it (one query token, float pool, page
+    rows of whole lanes): ``paged_append`` then the ``paged_attn``
+    kernel under ``attention/paged_attention``, and no gather."""
+    from bigdl_tpu.serving import decode
+
+    model = nn.Transformer(vocab_size=32, hidden_size=128, num_heads=4,
+                           filter_size=64, num_layers=1, dropout=0.0,
+                           causal=True)
+    var = model.init(jax.random.PRNGKey(0))
+    slots, page, pages = 2, 8, 4
+    cache = model.init_paged_cache(slots * pages + 1, page, slots)
+    samp = (np.zeros((slots, 2), np.uint32), np.zeros((slots,), np.float32),
+            np.zeros((slots,), np.int32), np.ones((slots,), np.float32))
+    scopes, text = _scopes_in(
+        decode.build_paged_tick(model), var["params"], var["state"], cache,
+        np.zeros((slots, pages), np.int32), np.zeros((slots,), np.int32),
+        np.ones((slots,), bool), *samp)
+    assert {"paged_append", "paged_attention", "attention"} <= scopes
+    assert "paged_gather" not in scopes
+    assert "attention/paged_attention" in text and "paged_attn" in text
 
 
 def test_flash_forward_kernel_is_named():

@@ -20,6 +20,7 @@
   guard of the off path;
 * ``bench.telemetry_ab`` really records under every plane (counts).
 """
+import functools
 import json
 import logging
 import os
@@ -415,6 +416,38 @@ def test_program_span_readers(clean_tracer, metric, kind, ring, want):
     assert read_metric(metric, run) == pytest.approx(want)
     other = "train" if kind == "decode" else "decode"
     assert read_metric(metric, {"kind": other}) is None
+
+
+def test_paged_attn_roofline_reads_the_traced_ticks_own_pages(clean_tracer):
+    """``paged_attn_roofline.serve``: the kernel's time per call from the
+    device trace against the K/V bytes of the pages the same ticks held
+    (``loop/tick_dispatch``'s ``pages_held``); nothing where the kernel
+    or the counter is missing (the parent commit has neither)."""
+    from benchmark.run import read_metric
+
+    model = {"hidden_size": 768, "filter_size": 3072, "num_layers": 12,
+             "vocab_size": 50272}
+    run = {"kind": "decode", "config": {"model": model},
+           "traffic": {"page_size": 16},
+           "peaks": {"hbm_bytes_per_s": 819e9},
+           "trace": {"by_name": {"fusion.13": [2.0, 24]}}}
+    read = functools.partial(read_metric, "paged_attn_roofline.serve")
+    tr = clean_tracer
+    tr.enable()
+    for i, pages in enumerate((10, 30)):
+        tr.add_span("loop/tick_dispatch", "decode", i, i + 0.001,
+                    args={"pages_held": pages})
+    assert read(run) is None                     # no kernel in the trace
+    # 24 calls (2 ticks x 12 layers) of 10 us
+    run["trace"]["by_name"]["paged_attn.7 tpu_custom_call"] = [240e-6, 24]
+    # 20 pages of 16 tokens, K and V rows of 768 f32, at 819 GB/s
+    least = 20 * 16 * 2 * 768 * 4 / 819e9
+    assert read(run) == pytest.approx(100.0 * least / 10e-6)
+    tr.clear()
+    tr.add_span("loop/tick_dispatch", "decode", 0.0, 0.001)
+    assert read(run) is None                     # no counter on the span
+    assert read(dict(run, trace=None)) is None   # a --trace 0 run
+    assert read(dict(run, kind="train")) is None
 
 
 # -------------------------------------------------------------- watchdog

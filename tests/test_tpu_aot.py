@@ -63,9 +63,10 @@ def one_chip(topo):
     mp.undo()
 
 
-def _compile(fn, sharding, *structs):
+def _compile(fn, sharding, *structs, donate=()):
     """TPU-compile ``fn`` and return the compiled program's text."""
-    return jax.jit(fn, in_shardings=sharding, out_shardings=sharding) \
+    return jax.jit(fn, in_shardings=sharding, out_shardings=sharding,
+                   donate_argnums=donate) \
         .lower(*structs).compile().as_text()
 
 
@@ -127,30 +128,99 @@ def test_flash_partitions_over_dp_tp_mesh(topo, one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_tick_compiles_at_lm_width(one_chip):
-    """The paged decode tick at the LM cell's widths (depth cut to one
-    layer: a layer's program does not depend on how many follow)."""
+# the decode cell's geometry (benchmark/traffic/decode-steady.json):
+# 32 slots x 2048 tokens in pages of 16, the worst-case pool
+CELL_SLOTS, CELL_MAX_LEN, CELL_PAGE = 32, 2048, 16
+
+
+def _lm_layer_and_pool(kv_dtype=None):
+    """The LM at its cell widths, depth cut to one layer (a layer's
+    program does not depend on how many follow), and its paged pool at
+    the decode cell's geometry, as shapes."""
     import bigdl_tpu.nn as nn
-    from bigdl_tpu.serving.decode import paged_tick_fn
     from bigdl_tpu.serving.paging import default_num_pages
 
     d = LM_DEFAULTS
-    slots, max_len, page = 8, 1024, 16
     model = nn.Transformer(
         vocab_size=d["vocabSize"], hidden_size=d["hiddenSize"],
         num_heads=d["numHeads"], filter_size=d["filterSize"],
         num_layers=1, dropout=0.0, causal=True)
+    pages = default_num_pages(CELL_SLOTS, CELL_MAX_LEN, CELL_PAGE)
+    assert pages == 4097
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        pages, CELL_PAGE, CELL_SLOTS, F32, kv_dtype=kv_dtype))
+    return model, cache
+
+
+def _pool_in_place(text, cache):
+    """The compiled program reaches the donated pool where it lies: no
+    ``copy`` has a pool-shaped operand or result, and every pool leaf
+    is an aliased output."""
+    import re
+
+    leaves = jax.tree_util.tree_leaves(cache)
+    shapes = {"[" + ",".join(map(str, leaf.shape)) + "]"
+              for leaf in leaves if leaf.ndim > 1}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= \S+ copy(-start)?\(", line)
+              and any(shape in line for shape in shapes)]
+    assert copies == []
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert aliased and aliased.group(1).count("alias") == len(leaves)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_paged_decode_tick_compiles_at_lm_width(one_chip, kv_dtype):
+    """The paged decode tick at the decode cell's geometry: the append
+    scatters into the donated pool in place and, on the float pool,
+    attention is the ``paged_attn`` kernel reading it in place (the
+    int8 pool gathers)."""
+    from bigdl_tpu.ops.pallas import report
+    from bigdl_tpu.serving.decode import paged_tick_fn
+
+    model, cache = _lm_layer_and_pool(kv_dtype)
     var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-    pages = default_num_pages(slots, max_len, page)
-    cache = jax.eval_shape(
-        lambda: model.init_paged_cache(pages, page, slots, F32))
+    slots = CELL_SLOTS
+    before = report.report().get("paged_attention", {}).get("pallas", 0)
     text = _compile(
         paged_tick_fn(model), one_chip, var["params"], var["state"],
-        cache, S((slots, max_len // page), jnp.int32),
+        cache, S((slots, CELL_MAX_LEN // CELL_PAGE), jnp.int32),
         S((slots,), jnp.int32), S((slots,), jnp.bool_),
         S((slots, 2), jnp.uint32), S((slots,), F32),
-        S((slots,), jnp.int32), S((slots,), F32))
-    # the Tq=1 decode core is XLA by design (tools/kernel_shapes.py
-    # DECODE_ATTN): what is proven here is that the whole tick lowers
-    # and fits, not a Mosaic call
-    assert "fusion" in text
+        S((slots,), jnp.int32), S((slots,), F32), donate=(2,))
+    _pool_in_place(text, cache)
+    took = report.report().get("paged_attention", {}).get("pallas", 0)
+    assert took == before + (kv_dtype is None)
+    assert ("tpu_custom_call" in text) == (kv_dtype is None)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+@pytest.mark.parametrize("bucket", [64, 1024])
+def test_paged_slot_write_is_in_place_at_lm_width(one_chip, bucket,
+                                                  kv_dtype):
+    """The slot write (one prefill row into a slot's pages) at the
+    decode cell's geometry, for the smallest and largest bucket."""
+    from bigdl_tpu.serving.decode import paged_write_slot_fn
+
+    model, cache = _lm_layer_and_pool(kv_dtype)
+    batch = jax.eval_shape(lambda: model.init_cache(4, bucket, F32))
+    text = _compile(
+        paged_write_slot_fn(), one_chip, cache,
+        S((CELL_MAX_LEN // CELL_PAGE,), jnp.int32), batch,
+        S((), jnp.int32), S((), jnp.int32), donate=(0,))
+    _pool_in_place(text, cache)
+
+
+def test_paged_attn_kernel_compiles_at_cell_shape(one_chip):
+    """``paged_attn`` alone at the inventory shape (32 slots, 12 heads
+    of 64, pages of 16, 128 pages a slot)."""
+    from bigdl_tpu.ops.pallas.paged_attention import paged_attn
+
+    slots, heads, dim, page, per_slot = KS.PAGED_ATTN[0]
+    pool = S((slots * per_slot + 1, page, heads * dim), F32)
+    text = _compile(
+        lambda q, k, v, table, kv_len: paged_attn(
+            q, k, v, table, kv_len, num_heads=heads),
+        one_chip, S((slots, 1, heads * dim), F32), pool, pool,
+        S((slots, per_slot), jnp.int32), S((slots,), jnp.int32))
+    assert "tpu_custom_call" in text

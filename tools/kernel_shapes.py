@@ -26,13 +26,18 @@ MATMUL = [(BATCH * 56 * 56, 64, 64), (BATCH * 56 * 56, 64, 256),
 # plus the int8 KV-cache score shape (Tq, D, L): the speculative
 # verify's QK^T against a quantized paged pool at a 4096-token extent
 # (ops/paged_kv.int8_scores).  Tq is padded to the kernel's minimum
-# 8-row tile; single-token decode stays on XLA like DECODE_ATTN.
+# 8-row tile.
 INT8 = [(4096, 768, 3072), (4096, 3072, 768), (8, 128, 4096)]
 
 # flash attention (B, H, T, D): the long-standing smoke shape, and the
 # LM train cell's own (tools/lm_bench.py LM_DEFAULTS: batch 8, 12 heads
 # of 64 over hidden 768, seq 2048)
 FLASH = [(1, 2, 1024, 128), (8, 12, 2048, 64)]
+
+# paged decode attention (S, H, D, Q, M): the decode cell's tick — 32
+# slots, 12 heads of 64, pages of 16 tokens, 128 pages a slot
+# (ops/pallas/paged_attention.py; benchmark/traffic/decode-steady.json)
+PAGED_ATTN = [(32, 12, 64, 16, 128)]
 
 # ---------------------------------------------------------------------
 # cached-decode serving shapes (serving/decode.py, docs/decoding.md):
@@ -49,11 +54,10 @@ DECODE_PREFILL_BATCH = (1, 2, 4)
 DECODE_MODEL = dict(vocab_size=32, hidden_size=48, num_heads=4,
                     filter_size=96, num_layers=2, dropout=0.0,
                     causal=True)
-# decode-step attention shape (B=slots, H, Tq=1, Tmax).  Tq=1 cannot
-# tile the flash kernel's q block, so the decode core is routed to the
-# XLA path by design (mask-carrying dot_product_attention) — listed
-# here as documentation of that routing decision, not as a Pallas
-# inventory entry.
+# decode-step attention shape (B=slots, H, Tq=1, Tmax) of the DENSE
+# cache: Tq=1 cannot tile the flash kernel's q block, so the dense
+# decode core is the mask-carrying dot_product_attention.  The paged
+# tick's Tq=1 attention is a kernel of its own (PAGED_ATTN above).
 DECODE_ATTN = (DECODE_SLOTS, 4, 1, DECODE_MAX_LEN)
 # production-decode extensions (ISSUE 14): paged KV pool geometry,
 # chunked prefill, and the speculative draft.  DECODE_PAGES is the

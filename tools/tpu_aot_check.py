@@ -209,6 +209,16 @@ def main(argv=None):
             lambda q: flash_attention(q, q, q, causal=True),
             S((bq, hq, tq, dq), jnp.bfloat16), kernel="flash_attention")
 
+    from bigdl_tpu.ops.pallas.paged_attention import paged_attn
+
+    for sl, hh, dd, pg, per in KS.PAGED_ATTN:
+        pool = S((sl * per + 1, pg, hh * dd), jnp.float32)
+        aot(f"paged_attn {sl}x{hh}x{dd} pages {per}x{pg}",
+            lambda q, k, v, t, n, hh=hh: paged_attn(q, k, v, t, n,
+                                                    num_heads=hh),
+            S((sl, 1, hh * dd), jnp.float32), pool, pool,
+            S((sl, per), jnp.int32), S((sl,), jnp.int32))
+
     if args.step:
         failures += _step_check(sh, mark, fused=not args.unfused)
     if args.lm_step:

@@ -9,7 +9,9 @@ Prints one JSON object:
 * ``host_spans``: ``{name: [count, seconds]}`` of the host plane;
 * ``scopes``: ``{program: {scope: seconds}}`` — device time of each
   compiled program by the ``jax.named_scope`` of its operations
-  (``attention``, ``head``, ``paged_gather``...; a backward pass reads
+  (``attention``, ``head``, the decode tick's ``attention/paged_append``
+  and ``attention/paged_attention`` — ``attention/paged_gather`` where
+  the gather path runs...; a backward pass reads
   ``<scope> (backward)``), outermost operations only, with the
   heaviest operations of each scope by instruction name;
 * ``idle_gaps``: the longest stretches with no operation on the device,
