@@ -210,8 +210,8 @@ def _paged_tick_gather_leak():
         table = jax.pure_callback(          # the defect: host gather
             lambda: host_table,
             jax.ShapeDtypeStruct((2, 2), jnp.int32))
-        logits, cache = model.decode_step_paged(params, state, cache,
-                                                table, tokens, active)
+        logits, cache, _ = model.decode_step_paged(params, state, cache,
+                                                   table, tokens, active)
         return jnp.argmax(logits, -1).astype(jnp.int32), cache
 
     jaxpr = jax.make_jaxpr(tick)(

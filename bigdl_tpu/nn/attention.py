@@ -132,6 +132,18 @@ class MultiHeadAttention(Module):
     # ------------------------------------------------------------------
     # cached incremental decoding (docs/decoding.md)
     # ------------------------------------------------------------------
+    def decode_state(self) -> dict:
+        """What this layer keeps per token while decoding, as
+        ``{leaf: (heads, width)}``: the cache manager (ops/paged_kv.py,
+        serving/decode.py) allocates, writes and frees by it."""
+        if self.seq_mesh is not None:
+            raise ValueError(
+                "cached decode does not compose with seq_mesh ring "
+                "attention (single-token queries have no ring "
+                "decomposition)")
+        return {"k": (self.num_heads, self.head_dim),
+                "v": (self.num_heads, self.head_dim)}
+
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
         """Static-shape KV cache pytree for ``batch`` independent rows.
 
@@ -140,17 +152,10 @@ class MultiHeadAttention(Module):
         slot grid.  ``length`` is per-row: rows at different decode
         depths coexist in one compiled program (continuous batching).
         """
-        if self.seq_mesh is not None:
-            raise ValueError(
-                "cached decode does not compose with seq_mesh ring "
-                "attention (single-token queries have no ring "
-                "decomposition)")
-        shape = (batch, self.num_heads, max_len, self.head_dim)
-        return {
-            "k": jnp.zeros(shape, dtype),
-            "v": jnp.zeros(shape, dtype),
-            "length": jnp.zeros((batch,), jnp.int32),
-        }
+        from bigdl_tpu.ops import paged_kv
+
+        return paged_kv.init_cache(self.decode_state(), batch, max_len,
+                                   dtype)
 
     def apply_cached(self, params, x, cache):
         """Self-attention over the KV cache: append ``x``'s K/V at each
@@ -200,15 +205,10 @@ class MultiHeadAttention(Module):
         """Paged pool for this layer: fixed-size pages + a host-owned
         block table instead of ``batch`` worst-case dense rows.  Page 0
         is the reserved trash page (never allocated)."""
-        if self.seq_mesh is not None:
-            raise ValueError(
-                "cached decode does not compose with seq_mesh ring "
-                "attention (single-token queries have no ring "
-                "decomposition)")
         from bigdl_tpu.ops import paged_kv
 
-        return paged_kv.init_pool(num_pages, page_size, self.num_heads,
-                                  self.head_dim, batch, dtype,
+        return paged_kv.init_pool(num_pages, page_size,
+                                  self.decode_state(), batch, dtype,
                                   quantized=quantized)
 
     def apply_paged(self, params, x, cache, table, active):
@@ -234,8 +234,8 @@ class MultiHeadAttention(Module):
         page = cache["k"].shape[1]
         l_max = table.shape[1] * page                  # logical extent
         length = cache["length"]                       # (N,)
-        cache = paged_kv.paged_append(cache, table, active, k, v,
-                                      page, l_max)
+        cache = paged_kv.paged_append(cache, table, active,
+                                      {"k": k, "v": v}, page, l_max)
         new_cache = dict(cache, length=length + tq)
         if paged_attention.routes(x.shape, cache["k"], table,
                                   self.num_heads):
@@ -649,10 +649,11 @@ class Transformer(Container):
     def decode_step_paged(self, params, state, cache, table, ids_t,
                           active):
         """One paged decode step — ``decode_step`` through the block
-        table.  Returns ``(logits (N, V), cache)``."""
+        table.  Returns ``(logits (N, V), cache, counters)``: what the
+        model counts inside its step for a live trace (here nothing)."""
         logits, cache = self.extend_paged(params, state, cache, table,
                                           ids_t[:, None], active)
-        return logits[:, 0], cache
+        return logits[:, 0], cache, {}
 
     def generate(self, params, state, initial_ids, max_decode_length,
                  beam_size: int = 4, alpha: float = 0.6,

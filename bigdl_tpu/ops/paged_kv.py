@@ -1,6 +1,15 @@
 """Paged (and optionally int8-quantized) KV-cache array ops.
 
-The dense decode cache (nn/attention.py ``init_cache``) reserves
+A layer declares the decode state it keeps per token as
+``{leaf name: (heads, width)}`` (``decode_state()`` of the attention
+layers: ``{"k": (H, D), "v": (H, D)}`` for multi-head attention,
+``{"latent": (1, 640)}`` for latent attention), and everything here and
+in serving/decode.py allocates, writes, appends, resets and meters by
+that declaration: a dense cache leaf is ``(N, heads, T, width)``, a
+pool leaf ``(P, Q, heads*width)`` (:func:`init_cache`,
+:func:`init_pool`, :func:`state_leaves`).
+
+The dense decode cache (``init_cache``) reserves
 ``max_len`` rows per slot up front — worst-case HBM whether or not a
 request ever grows that long.  The paged layout breaks each layer's
 cache into fixed-size pages,
@@ -90,26 +99,41 @@ def dequantize_kv(q, scale, dtype):
 
 
 # ---------------------------------------------------------------- pool
-def init_pool(num_pages: int, page_size: int, num_heads: int,
-              head_dim: int, batch: int, dtype=jnp.float32,
-              quantized: bool = False):
-    """One attention layer's paged pool (page 0 = reserved trash page).
+def state_leaves(cache) -> list:
+    """The per-token leaves of one layer's cache or pool, by name (what
+    the layer declared): all but ``length`` and the int8 scales."""
+    return [k for k in cache if k != "length"
+            and not k.endswith("_scale")]
+
+
+def init_cache(leaves: dict, batch: int, max_len: int,
+               dtype=jnp.float32):
+    """One layer's dense cache for the declared ``leaves``
+    ``{name: (heads, width)}``: ``(batch, heads, max_len, width)`` each
+    and a per-row ``length``."""
+    cache = {name: jnp.zeros((batch, h, max_len, w), dtype)
+             for name, (h, w) in leaves.items()}
+    cache["length"] = jnp.zeros((batch,), jnp.int32)
+    return cache
+
+
+def init_pool(num_pages: int, page_size: int, leaves: dict, batch: int,
+              dtype=jnp.float32, quantized: bool = False):
+    """One layer's paged pool for the declared ``leaves``
+    ``{name: (heads, width)}`` (page 0 = reserved trash page).
 
     ``length`` is per *slot* (the serving grid's batch dim), exactly as
     in the dense cache, so retirement/length bookkeeping is layout-
     independent in the engine.
     """
-    shape = (num_pages, page_size, num_heads * head_dim)
     store = jnp.int8 if quantized else dtype
-    pool = {
-        "k": jnp.zeros(shape, store),
-        "v": jnp.zeros(shape, store),
-        "length": jnp.zeros((batch,), jnp.int32),
-    }
+    pool = {name: jnp.zeros((num_pages, page_size, h * w), store)
+            for name, (h, w) in leaves.items()}
+    pool["length"] = jnp.zeros((batch,), jnp.int32)
     if quantized:
-        scales = (num_pages, page_size * _scale_width(num_heads))
-        pool["k_scale"] = jnp.zeros(scales, jnp.float32)
-        pool["v_scale"] = jnp.zeros(scales, jnp.float32)
+        for name, (h, _) in leaves.items():
+            pool[name + "_scale"] = jnp.zeros(
+                (num_pages, page_size * _scale_width(h)), jnp.float32)
     return pool
 
 
@@ -120,17 +144,18 @@ def _scale_width(num_heads: int) -> int:
 
 
 def is_quantized(pool) -> bool:
-    return "k_scale" in pool
+    return any(name.endswith("_scale") for name in pool)
 
 
-def page_bytes(page_size: int, num_heads: int, head_dim: int,
-               dtype=jnp.float32, quantized: bool = False) -> int:
-    """Bytes one physical page costs in one layer's pool (K + V +
-    scales) — the unit the HbmLedger resident lane reports in."""
-    if quantized:
-        per_tok = num_heads * head_dim * 2 + _scale_width(num_heads) * 4 * 2
-    else:
-        per_tok = num_heads * head_dim * 2 * jnp.dtype(dtype).itemsize
+def page_bytes(page_size: int, leaves: dict, dtype=jnp.float32,
+               quantized: bool = False) -> int:
+    """Bytes one physical page costs in one layer's pool (every
+    declared leaf + scales) — the unit the HbmLedger resident lane
+    reports in."""
+    per_tok = 0
+    for h, w in leaves.values():
+        per_tok += h * w + _scale_width(h) * 4 if quantized \
+            else h * w * jnp.dtype(dtype).itemsize
     return page_size * per_tok
 
 
@@ -176,19 +201,21 @@ def write_pages(pool, name, table_row, vals):
 
 
 @jax.named_scope("paged_append")
-def paged_append(pool, table, active, k_new, v_new, page_size, max_len):
-    """Scatter ``k_new``/``v_new`` (S, H, T, D) into the pool at each
-    slot's current ``length``..``length + T - 1``; returns the updated
-    pool (donation-friendly: pure ``.at[].set`` on the pool leaves).
-    ``length`` itself is NOT advanced here — the model layer owns the
-    length bookkeeping so dense and paged advance identically."""
-    s, h, t, d = k_new.shape
+def paged_append(pool, table, active, new, page_size, max_len):
+    """Scatter the new token rows ``new`` ``{leaf: (S, H, T, D)}`` into
+    the pool at each slot's current ``length``..``length + T - 1``;
+    returns the updated pool (donation-friendly: pure ``.at[].set`` on
+    the pool leaves).  ``length`` itself is NOT advanced here — the
+    model layer owns the length bookkeeping so dense and paged advance
+    identically."""
+    s, _, t, _ = next(iter(new.values())).shape
     pos = pool["length"][:, None] + jnp.arange(t)[None]   # (S, T)
     idx = flat_positions(table, pos, active, page_size, max_len)
     flat = idx.reshape(s * t)
     pool = dict(pool)
-    for name, new in (("k", k_new), ("v", v_new)):
-        vals = new.transpose(0, 2, 1, 3).reshape(s * t, h, d)
+    for name, rows in new.items():
+        h, d = rows.shape[1], rows.shape[3]
+        vals = rows.transpose(0, 2, 1, 3).reshape(s * t, h, d)
         store = pool[name].shape
         if is_quantized(pool):
             # a token's H scales are a window of its page's scale row
@@ -209,7 +236,7 @@ def paged_append(pool, table, active, k_new, v_new, page_size, max_len):
     return pool
 
 
-def _gather_pages(leaf, table, page_size):
+def gather_pages(leaf, table, page_size):
     """A slot-major view of the pages the table names: ``leaf``
     (P, Q, C) or (P, Q*C) -> (S, M*Q, C).  The gather moves whole
     pages, each one contiguous run of the row-major pool."""
@@ -221,13 +248,13 @@ def _gather_heads(pool, name, table, num_heads):
     """Leaf ``name`` gathered and split by head: ``(x (S, H, L, D) in
     the pool's dtype, scale (S, H, L) or None)``."""
     page = pool[name].shape[1]
-    rows = _gather_pages(pool[name], table, page)         # (S, L, H*D)
+    rows = gather_pages(pool[name], table, page)         # (S, L, H*D)
     s, l, hd = rows.shape
     x = rows.reshape(s, l, num_heads, hd // num_heads).transpose(
         0, 2, 1, 3)
     if not is_quantized(pool):
         return x, None
-    scale = _gather_pages(pool[name + "_scale"], table, page)
+    scale = gather_pages(pool[name + "_scale"], table, page)
     return x, scale[:, :, :num_heads].transpose(0, 2, 1)
 
 

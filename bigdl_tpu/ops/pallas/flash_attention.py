@@ -324,3 +324,100 @@ def flash_attention(
         out_dim_axes=(qkv_axes,),
         single_output=True,
     )
+
+
+# ----------------------------------------------------------------------
+# causal attention of a chunk against a longer cached extent
+# ----------------------------------------------------------------------
+def _prefix_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, heads: int, bq: int, bk: int,
+                   sm_scale: float):
+    """``_attn_kernel`` with the queries at absolute positions
+    ``off + i``: key block ``kb`` is live while it starts at or before
+    the q block's last position."""
+    off = off_ref[pl.program_id(0) // heads]
+    q_idx = pl.program_id(1)
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(off + (q_idx + 1) * bq > kb * bk)
+    def _():
+        q = q_ref[:] * sm_scale
+        s = jax.lax.dot_general(
+            q, k_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (bq, bk)
+        q_pos = off + q_idx * bq + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, bk), 0)
+        k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        m = m_ref[:]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _():
+        o_ref[:] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def prefix_blocks(t: int, s: int, block_q: int = 512, block_k: int = 1024):
+    """The (bq, bk) :func:`prefix_flash_attention` tiles ``t`` queries
+    against ``s`` keys with, or nothing where no legal pair exists."""
+    bq, bk = fit_block(t, block_q), fit_block(s, block_k, multiple=8)
+    return (bq, bk) if bq and bk else None
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "blocks",
+                                             "interpret"))
+def prefix_flash_attention(q, k, v, offset, *, sm_scale: float, blocks,
+                           interpret: bool = False):
+    """A chunk of queries against the extent that holds them: ``q``
+    (B, H, T, D) at absolute positions ``offset[b] + i`` attends ``k``,
+    ``v`` (B, H, S, D) at positions ``<=`` its own (forward only).  Key
+    blocks past a q block's last position are neither computed nor
+    fetched again (their index is held at the last live block)."""
+    b, h, t, d = q.shape
+    s = k.shape[2]
+    bq, bk = blocks
+    kernel = functools.partial(_prefix_kernel, heads=h, bq=bq, bk=bk,
+                               sm_scale=sm_scale)
+
+    def kv_index(g, i, j, off):
+        return (g, jnp.minimum(j, (off[g // h] + (i + 1) * bq - 1) // bk), 0)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, t // bq, s // bk),
+            in_specs=[
+                pl.BlockSpec((None, bq, d), lambda g, i, j, off: (g, i, 0)),
+                pl.BlockSpec((None, bk, d), kv_index),
+                pl.BlockSpec((None, bk, d), kv_index),
+            ],
+            out_specs=pl.BlockSpec((None, bq, d),
+                                   lambda g, i, j, off: (g, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),   # running max
+                pltpu.VMEM((bq, 1), jnp.float32),   # running sum
+                pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_prefix",  # the device trace finds the kernel by it
+    )(offset.astype(jnp.int32), q.reshape(b * h, t, d),
+      k.reshape(b * h, s, d), v.reshape(b * h, s, d))
+    return out.reshape(b, h, t, d)

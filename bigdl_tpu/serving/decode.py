@@ -238,14 +238,17 @@ def paged_tick_fn(model):
     def tick(params, state, cache, table, tokens, active, keys, temp,
              top_k, top_p):
         old_len = {lk: c["length"] for lk, c in cache.items()}
-        logits, cache = model.decode_step_paged(params, state, cache,
-                                                table, tokens, active)
+        # what the model counts inside its step (tokens per expert
+        # held...) rides out with the tokens and reaches
+        # ``loop/tick_dispatch`` while a trace is live
+        logits, cache, counters = model.decode_step_paged(
+            params, state, cache, table, tokens, active)
         nxt, keys = _next_tokens(logits, tokens, active, keys, temp,
                                  top_k, top_p)
         cache = {lk: dict(c, length=jnp.where(active, c["length"],
                                               old_len[lk]))
                  for lk, c in cache.items()}
-        return cache, nxt, keys
+        return cache, nxt, keys, counters
 
     return tick
 
@@ -259,10 +262,11 @@ def build_paged_tick(model, **jit_kw):
 
 
 def paged_write_slot_fn():
-    """Splice one dense prefill-batch row into a slot's pages: scatter
-    the row's K/V (quantizing when the pool is int8) through the slot's
-    block-table row.  Unmapped logical pages redirect to the trash page
-    — only the pages the allocator granted are ever written."""
+    """Splice one dense prefill-batch row into a slot's pages: every
+    per-token leaf the layer declared (K and V, or a latent row;
+    quantized when the pool is int8) goes through the slot's block-table
+    row as whole pages.  Unmapped logical pages redirect to the trash
+    page — only the pages the allocator granted are ever written."""
     import jax
     import jax.numpy as jnp
 
@@ -273,7 +277,7 @@ def paged_write_slot_fn():
         for lk, pool in pool_cache.items():
             bc = batch_cache[lk]
             new = dict(pool)
-            for name in ("k", "v"):
+            for name in paged_kv.state_leaves(pool):
                 r = jax.lax.dynamic_index_in_dim(
                     bc[name], row, axis=0, keepdims=False)  # (H,T,D)
                 paged_kv.write_pages(new, name, table_row,
@@ -984,12 +988,14 @@ class DecodeEngine:
 
     def _run_tick(self):
         def thunk():
-            cache, nxt, keys = self._tick(*self._tick_args())
+            # the paged tick also hands out the model's counters
+            cache, nxt, keys, *counters = self._tick(*self._tick_args())
             self._cache = cache
-            return nxt, keys
+            return nxt, keys, counters[0] if counters else {}
 
+        args = self._pages_held()
         with self._tracer.span("loop/tick_dispatch", CAT_DECODE,
-                               args=self._pages_held()):
+                               args=args):
             out = self._tracked(
                 ("tick",), thunk, program="decode_tick",
                 sig_fn=lambda: programs.signature_of(
@@ -1003,7 +1009,13 @@ class DecodeEngine:
         # the per-tick host sync point (writable copy: slots claimed
         # between ticks overwrite their token in place)
         with self._tracer.span("loop/tick_wait", CAT_DECODE):
-            nxt, keys = jax.device_get(out)
+            nxt, keys, counters = out
+            if args is None or not self._tracer.enabled:
+                counters = {}  # nobody to read them: not fetched
+            # the model's counters come back in the tokens' own read
+            nxt, keys, counters = jax.device_get((nxt, keys, counters))
+            for name, value in counters.items():
+                args[name] = np.asarray(value).tolist()
             self._keys = np.array(keys)
             return np.array(nxt)
 
@@ -1370,8 +1382,9 @@ class DecodeEngine:
             args = {}  # filled inside the span: the ring's copy
             with tr.span("loop/admit", CAT_DECODE, args=args):
                 args["admitted"] = self._admit()
-            with tr.span("loop/chunk_step", CAT_DECODE):
-                self._chunk_step()
+            args = {}
+            with tr.span("loop/chunk_step", CAT_DECODE, args=args):
+                args["tokens"] = self._chunk_step()
             if self.paged:
                 # fund (and resume) occupied slots before the tick —
                 # must run even when everything is paused
@@ -1575,9 +1588,10 @@ class DecodeEngine:
     # chunked prefill: one bounded chunk per loop iteration, so long
     # prompts never stall the occupied slots between ticks
     # ------------------------------------------------------------------
-    def _chunk_step(self):
+    def _chunk_step(self) -> int:
+        """Run at most one chunk; returns the prompt tokens it held."""
         if not self.prefill_chunk:
-            return
+            return 0
         if self._chunking is None and self._chunk_pending:
             free = self._free_slots()
             if free:
@@ -1592,7 +1606,7 @@ class DecodeEngine:
                         f"{1e3 * (now - req.deadline):.1f}ms before "
                         "prefill",
                         attribution=self.xray.close(req.rid, now=now)))
-                    return
+                    return 0
                 self.xray.to(req.rid, request_xray.PHASE_PREFILL,
                              now=now)
                 self._chunking = {
@@ -1605,12 +1619,12 @@ class DecodeEngine:
                 }
         c = self._chunking
         if c is None:
-            return
+            return 0
         if "tok0" in c:
             # prefill finished earlier but the page pool was full: keep
             # retrying as ticks retire slots and free pages
             self._finalize_chunk(c)
-            return
+            return 0
         req = c["req"]
         now = time.perf_counter()
         if req.deadline is not None and now > req.deadline:
@@ -1622,7 +1636,7 @@ class DecodeEngine:
                 "deadline expired mid chunked prefill "
                 f"({c['offset']}/{req.prompt.size} tokens in)",
                 attribution=self.xray.close(req.rid, now=now)))
-            return
+            return 0
         t0 = time.perf_counter()
         size = self.prefill_chunk
         lo = c["offset"]
@@ -1642,7 +1656,7 @@ class DecodeEngine:
                              args={"lo": lo, "hi": hi})
         c["offset"] = hi
         if hi < req.prompt.size:
-            return  # more chunks on later loop iterations
+            return hi - lo  # more chunks on later loop iterations
         self.xray.to(req.rid, request_xray.PHASE_SAMPLE)
         tok0 = _host_sample(last[0], req)
         c["t_tok"] = time.perf_counter()
@@ -1653,9 +1667,10 @@ class DecodeEngine:
                          "eos" if (self.eos_id is not None
                                    and tok0 == self.eos_id)
                          else "length")
-            return
+            return hi - lo
         c["tok0"] = tok0
         self._finalize_chunk(c)
+        return hi - lo
 
     def _finalize_chunk(self, c: dict):
         """Splice a fully chunk-prefilled request into its reserved

@@ -28,7 +28,7 @@ def _bf16_exact(rng, shape):
 @pytest.fixture(scope="module")
 def case():
     rng = np.random.default_rng(0)
-    pool = paged_kv.init_pool(S * M + 1, Q, H, D, S)
+    pool = paged_kv.init_pool(S * M + 1, Q, {"k": (H, D), "v": (H, D)}, S)
     pool["k"] = _bf16_exact(rng, pool["k"].shape)
     pool["v"] = _bf16_exact(rng, pool["v"].shape)
     table = rng.permutation(np.arange(1, S * M + 1)).reshape(S, M)

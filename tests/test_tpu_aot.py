@@ -224,3 +224,69 @@ def test_paged_attn_kernel_compiles_at_cell_shape(one_chip):
         one_chip, S((slots, 1, heads * dim), F32), pool, pool,
         S((slots, per_slot), jnp.int32), S((slots,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_latent_moe_tick_compiles_at_published_widths(one_chip):
+    """The paged tick of the latent-attention decoder with routed
+    experts at the published widths (one routed layer; bf16; the
+    cell's 32 slots x 8192 in pages of 16): the latent pool is donated
+    and aliased with no whole-pool copy, attention is the
+    ``latent_paged_attn`` kernel reading it in place, the expert
+    products are the grouped-matmul kernel."""
+    import json
+    import os
+
+    from bigdl_tpu.nn.latent import LatentMoETransformer
+    from bigdl_tpu.ops.pallas import report
+    from bigdl_tpu.serving.decode import paged_tick_fn
+    from bigdl_tpu.serving.paging import default_num_pages
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "gigachat3.1-702b-ep16share.json")) as f:
+        cfg = json.load(f)["model"]
+    model = LatentMoETransformer(**dict(cfg, num_hidden_layers=1,
+                                        first_k_dense_replace=0))
+    slots, max_len, page = 32, 8192, 16
+    pages = default_num_pages(slots, max_len, page)
+    var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), BF16))
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        pages, page, slots, BF16))
+    assert cache["layer0"]["latent"].shape == (pages, page, 640)
+    before = report.report().get("latent_paged_attention", {}).get(
+        "pallas", 0)
+    text = _compile(
+        paged_tick_fn(model), one_chip, var["params"], var["state"],
+        cache, S((slots, max_len // page), jnp.int32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots, 2), jnp.uint32), S((slots,), F32),
+        S((slots,), jnp.int32), S((slots,), F32), donate=(2,))
+    _pool_in_place(text, cache)
+    assert report.report()["latent_paged_attention"]["pallas"] == before + 1
+    assert "latent_paged_attn" in text and "ragged-dot" in text
+
+
+def test_latent_serving_kernels_compile_at_cell_shapes(one_chip):
+    """``latent_paged_attn`` and ``flash_prefix`` alone at their
+    inventory shapes (the latent cell's tick and chunk)."""
+    from bigdl_tpu.ops.pallas.flash_attention import (
+        prefix_blocks, prefix_flash_attention)
+    from bigdl_tpu.ops.pallas.latent_attention import latent_paged_attn
+
+    slots, heads, row, value, page, per_slot = KS.LATENT_PAGED_ATTN[0]
+    text = _compile(
+        lambda q, pool, table, kv_len: latent_paged_attn(
+            q, pool, table, kv_len, value_width=value, sm_scale=0.1),
+        one_chip, S((slots, heads, row), BF16),
+        S((slots * per_slot + 1, page, row), BF16),
+        S((slots, per_slot), jnp.int32), S((slots,), jnp.int32))
+    assert "latent_paged_attn" in text
+    b, h, t, s, d = KS.FLASH_PREFIX[0]
+    blocks = prefix_blocks(t, s)
+    assert blocks == (512, 1024)
+    text = _compile(
+        lambda q, k, v, off: prefix_flash_attention(
+            q, k, v, off, sm_scale=0.1, blocks=blocks),
+        one_chip, S((b, h, t, d), BF16), S((b, h, s, d), BF16),
+        S((b, h, s, d), BF16), S((b,), jnp.int32))
+    assert "flash_prefix" in text

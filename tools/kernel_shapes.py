@@ -39,6 +39,17 @@ FLASH = [(1, 2, 1024, 128), (8, 12, 2048, 64)]
 # (ops/pallas/paged_attention.py; benchmark/traffic/decode-steady.json)
 PAGED_ATTN = [(32, 12, 64, 16, 128)]
 
+# absorbed decode attention over latent pages (S, H, C, Vw, Q, M): the
+# latent cell's tick - 32 slots, 64 heads sharing one 640-lane row (576
+# numbers), values the first 512 lanes, pages of 16, 512 pages a slot
+# (ops/pallas/latent_attention.py; benchmark/traffic/decode-longprompt.json)
+LATENT_PAGED_ATTN = [(32, 64, 640, 512, 16, 512)]
+
+# a prompt chunk against the cached extent (B, H, T, S, D): 2048
+# queries at an offset, 8192 expanded keys, head width 192
+# (ops/pallas/flash_attention.prefix_flash_attention)
+FLASH_PREFIX = [(1, 64, 2048, 8192, 192)]
+
 # ---------------------------------------------------------------------
 # cached-decode serving shapes (serving/decode.py, docs/decoding.md):
 # the slot-grid geometry shared by bench.py --decode-ab, the

@@ -219,6 +219,24 @@ def main(argv=None):
             S((sl, 1, hh * dd), jnp.float32), pool, pool,
             S((sl, per), jnp.int32), S((sl,), jnp.int32))
 
+    from bigdl_tpu.ops.pallas.flash_attention import (
+        prefix_blocks, prefix_flash_attention)
+    from bigdl_tpu.ops.pallas.latent_attention import latent_paged_attn
+
+    for sl, hh, cc, vw, pg, per in KS.LATENT_PAGED_ATTN:
+        aot(f"latent_paged_attn {sl}x{hh}x{cc} pages {per}x{pg}",
+            lambda q, p_, t, n, vw=vw: latent_paged_attn(
+                q, p_, t, n, value_width=vw, sm_scale=0.1),
+            S((sl, hh, cc), jnp.bfloat16),
+            S((sl * per + 1, pg, cc), jnp.bfloat16),
+            S((sl, per), jnp.int32), S((sl,), jnp.int32))
+    for bq, hq, tq, sq, dq in KS.FLASH_PREFIX:
+        aot(f"flash_prefix {bq}x{hq}x{tq}x{sq}x{dq}",
+            lambda q, k, off, tq=tq, sq=sq: prefix_flash_attention(
+                q, k, k, off, sm_scale=0.1, blocks=prefix_blocks(tq, sq)),
+            S((bq, hq, tq, dq), jnp.bfloat16),
+            S((bq, hq, sq, dq), jnp.bfloat16), S((bq,), jnp.int32))
+
     if args.step:
         failures += _step_check(sh, mark, fused=not args.unfused)
     if args.lm_step:

@@ -7,7 +7,8 @@ device's operations (``/device:TPU:0``), on the session's one clock.
 Prints one JSON object:
 
 * ``host_spans``: ``{name: [count, seconds]}`` of the host plane;
-* ``scopes``: ``{program: {scope: seconds}}`` — device time of each
+* ``scopes``: ``{program: {scope: seconds}}`` (the scope paths through
+  ``benchmark/trace_scopes.py``) — device time of each
   compiled program by the ``jax.named_scope`` of its operations
   (``attention``, ``head``, the decode tick's ``attention/paged_append``
   and ``attention/paged_attention`` — ``attention/paged_gather`` where
@@ -30,73 +31,7 @@ from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import trace_reduce  # noqa: E402
-
-WRAPPED = re.compile(r"^(transpose\()?(jvp\()?([^()]*)\)*$")
-
-
-def scope_of(op_name: str) -> str:
-    """``jit(step)/jit(main)/transpose(jvp(attention))/while/body/mul``
-    -> ``attention (backward)``: the name scopes below the jit frames,
-    nested ones joined by ``/``; ``-`` for an operation under none."""
-    parts = [p for p in op_name.split(";")[0].split("/") if not p.startswith(
-        ("jit(", "pjit(", "jit_", "while", "body", "cond", "closed_call",
-         "checkpoint", "remat", "custom_vjp_call", "custom_jvp_call"))]
-    scopes = []
-    for part in parts[:-1]:  # the last is the primitive itself
-        m = WRAPPED.match(part)
-        name = m.group(3) if m else part
-        if name:  # ``jvp()``: differentiated, under no scope
-            scopes.append(name + (" (backward)" if m and m.group(1)
-                                  else ""))
-    return "/".join(scopes) or "-"
-
-
-def op_names(path: str) -> dict:
-    """``{operation's event name: its op_name}`` of the first device
-    plane.  The profiler keeps an operation's ``op_name`` metadata
-    (``jit(step)/attention/dot_general``) as the stat ``tf_op`` of the
-    event's *metadata*, which ``ProfileData`` does not hand out: read
-    from the file's wire format (XSpace.planes=1; XPlane.name=2,
-    event_metadata=4, stat_metadata=5; XEventMetadata.name=2, stats=5;
-    XStat.metadata_id=1, str_value=5, ref_value=7)."""
-    from bigdl_tpu.interop import protowire as pw
-
-    if not path.endswith(".pb"):
-        return {}
-    with open(path, "rb") as f:
-        space = pw.fields(f.read())
-    for plane in pw.get_messages(space, 1):
-        if not trace_reduce.DEVICE_PLANE.match(pw.get_str(plane, 2)):
-            continue
-        stat_names = {}
-        for entry in pw.get_messages(plane, 5):
-            meta = pw.get_message(entry, 2)
-            stat_names[pw.get_int(meta, 1)] = pw.get_str(meta, 2)
-        out = {}
-        for entry in pw.get_messages(plane, 4):
-            meta = pw.get_message(entry, 2)
-            for stat in pw.get_messages(meta, 5):
-                if stat_names.get(pw.get_int(stat, 1)) == "tf_op":
-                    out[pw.get_str(meta, 2)] = pw.get_str(stat, 5) or \
-                        stat_names.get(pw.get_int(stat, 7), "")
-        return out
-    return {}
-
-
-def outermost(events, names: dict):
-    """``[(event, scope)]`` of the events not nested inside an earlier
-    one of the same line; an operation without an ``op_name`` of its
-    own (a ``while``) takes the scope of the first one nested in it."""
-    out, edge = [], -1
-    for ev in sorted(events, key=lambda e: (e.start_ns, -e.duration_ns)):
-        scope = scope_of(names[ev.name]) if names.get(ev.name) else None
-        if ev.start_ns >= edge:
-            out.append([ev, scope])
-            edge = ev.start_ns + ev.duration_ns
-        elif out[-1][1] is None:
-            out[-1][1] = scope
-    return [(ev, scope or "-") for ev, scope in out]
+from benchmark import trace_reduce, trace_scopes  # noqa: E402
 
 
 def read(path: str, top: int = 10) -> dict:
@@ -116,8 +51,8 @@ def read(path: str, top: int = 10) -> dict:
     scopes, gaps = {}, []
     if device is not None:
         lines = {ln.name: ln for ln in device.lines}
-        ops = outermost(lines[trace_reduce.OPS_LINE].events,
-                        op_names(path)) \
+        ops = trace_scopes.outermost(lines[trace_reduce.OPS_LINE].events,
+                                     trace_scopes.op_scopes(path)) \
             if trace_reduce.OPS_LINE in lines else []
         modules = sorted(
             (ev.start_ns, ev.start_ns + ev.duration_ns,
