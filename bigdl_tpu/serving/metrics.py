@@ -143,6 +143,11 @@ class ServingMetrics:
     def inc_prefill_chunks(self, n: int = 1):
         self.base.inc("prefill_chunks", n)
 
+    def inc_sampled_ticks(self, n: int = 1):
+        """A tick that ran its sampling epilogue: some active row had
+        ``temperature > 0`` (a greedy grid skips it)."""
+        self.base.inc("sampled_ticks", n)
+
     def record_spec(self, proposed: int, accepted: int):
         """One speculative round: ``proposed`` draft tokens scored,
         ``accepted`` of them kept (the bonus token is not counted —
@@ -242,6 +247,16 @@ class ServingMetrics:
     def prefill_chunks(self) -> int:
         return self.base.counter("prefill_chunks")
 
+    @property
+    def sampled_ticks(self) -> int:
+        return self.base.counter("sampled_ticks")
+
+    def sampled_tick_share(self) -> float:
+        """Ticks that ran the sampling epilogue over all ticks timed
+        (0.0 before the first tick)."""
+        n = self.base.count(TICK)
+        return self.sampled_ticks / n if n else 0.0
+
     def spec_acceptance_rate(self) -> float:
         """Accepted / proposed draft tokens since engine start (0.0
         when the engine never ran a speculative round)."""
@@ -276,6 +291,7 @@ class ServingMetrics:
             "spec_acceptance_rate": round(self.spec_acceptance_rate(),
                                           4),
             "prefill_chunks": self.prefill_chunks,
+            "sampled_tick_share": round(self.sampled_tick_share(), 4),
         }
 
     # scalar tags exported to TensorBoard (visualization satellite):
@@ -330,7 +346,8 @@ class ServingMetrics:
                      f"ttft p50={s['p50_ttft_ms']:.2f}ms "
                      f"p95={s['p95_ttft_ms']:.2f}ms | "
                      f"gap p50={s['p50_token_gap_ms']:.2f}ms "
-                     f"p95={s['p95_token_gap_ms']:.2f}ms")
+                     f"p95={s['p95_token_gap_ms']:.2f}ms | "
+                     f"sampled={100 * s['sampled_tick_share']:.0f}%")
         if s["pages_in_use"] or s["page_evictions"]:
             line += (f" | pages={s['pages_in_use']} "
                      f"evict={s['page_evictions']}")

@@ -543,6 +543,113 @@ def test_sampling_mixed_traffic_keeps_greedy_parity(engine_lm):
                 assert list(got) == _direct_greedy(model, var, p, 6)
 
 
+# ---------------------- the sampling epilogue runs only when asked for
+_GRIDS = {
+    #               temperature            active
+    "all_greedy": ((0.0, 0.0, 0.0, 0.0), (True, True, True, True)),
+    "all_sampled": ((0.7, 1.0, 1.5, 0.3), (True, True, True, True)),
+    "mixed": ((0.0, 0.9, 0.0, 1.2), (True, True, False, True)),
+    "sampled_row_inactive": ((0.0, 0.8, 0.0, 0.0),
+                             (True, False, True, True)),
+    "nothing_active": ((0.0, 0.8, 0.0, 1.1), (False,) * 4),
+}
+
+
+def _ungated_next_tokens(logits, tokens, active, keys, temp, top_k,
+                         top_p):
+    """The epilogue as it was before the branch: every row sampled,
+    then thrown away row by row.  The oracle of the gated one."""
+    from bigdl_tpu.serving.decode import sample_logits
+
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled = sample_logits(logits, keys, temp, top_k, top_p)
+    nxt = jnp.where(temp > 0.0, sampled, greedy)
+    nxt = jnp.where(active, nxt, tokens)
+    split = jax.vmap(lambda k: jax.random.split(k, 2)[0])(keys)
+    return nxt, jnp.where(active[:, None], split, keys)
+
+
+@pytest.mark.parametrize("grid", sorted(_GRIDS))
+def test_next_tokens_equal_the_ungated_epilogue(grid):
+    """Tokens and keys of every row, bit for bit, whichever branch the
+    tick takes: the branch only decides whether discarded work is
+    done."""
+    from bigdl_tpu.serving.decode import _next_tokens
+
+    temp, active = _GRIDS[grid]
+    rs = np.random.RandomState(sorted(_GRIDS).index(grid))
+    args = (rs.randn(4, 97).astype(np.float32),
+            rs.randint(0, 97, (4,)).astype(np.int32),
+            np.asarray(active),
+            rs.randint(0, 2 ** 32, (4, 2), dtype=np.uint64).astype(
+                np.uint32),
+            np.asarray(temp, np.float32),
+            np.asarray([0, 5, 0, 40], np.int32),
+            np.asarray([1.0, 0.9, 0.5, 1.0], np.float32))
+    gated, ungated = jax.jit(_next_tokens), jax.jit(_ungated_next_tokens)
+    for _ in range(3):  # a key chain: the keys feed the next tick
+        tok, keys = gated(*args)
+        want_tok, want_keys = ungated(*args)
+        np.testing.assert_array_equal(np.asarray(tok),
+                                      np.asarray(want_tok))
+        np.testing.assert_array_equal(np.asarray(keys),
+                                      np.asarray(want_keys))
+        args = args[:1] + (np.asarray(tok),) + args[2:3] + (
+            np.asarray(keys),) + args[4:]
+
+
+def _primitives(jaxpr, inside=False):
+    """``[(primitive, under a cond)]`` of a jaxpr and all it nests."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name, inside))
+        under = inside or eqn.primitive.name == "cond"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _primitives(sub, under)
+    return out
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_tick_sorts_and_draws_only_inside_its_branch(engine_lm, layout):
+    """The tick program holds one conditional, and every sort and every
+    random-bits draw of it lies inside: a greedy grid reaches neither.
+    One compiled tick as before: warm-up compiles what is declared."""
+    from bigdl_tpu.serving import decode
+
+    model, var = engine_lm
+    slots, page, pages = 2, 4, 8
+    samp = (np.zeros((slots,), np.int32), np.ones((slots,), bool),
+            np.zeros((slots, 2), np.uint32), np.zeros((slots,), np.float32),
+            np.zeros((slots,), np.int32), np.ones((slots,), np.float32))
+    if layout == "paged":
+        tick = decode.build_paged_tick(model)
+        args = (var["params"], var["state"],
+                model.init_paged_cache(slots * pages + 1, page, slots),
+                np.zeros((slots, pages), np.int32)) + samp
+    else:
+        tick = decode.build_sampling_tick(model)
+        args = (var["params"], var["state"],
+                model.init_cache(slots, page * pages)) + samp
+    prims = _primitives(jax.make_jaxpr(tick)(*args).jaxpr)
+    assert [p for p in prims if p[0] == "cond"] == [("cond", False)]
+    drawn = [p for p in prims if p[0] in ("sort", "random_bits")]
+    assert {name for name, _ in drawn} == {"sort", "random_bits"}
+    assert all(under for _, under in drawn), drawn
+    # the greedy path stays outside: the argmax and the key split
+    assert ("argmax", False) in prims and ("random_split", False) in prims
+    text = tick.lower(*args).as_text()
+    assert text.count("stablehlo.case") + text.count("stablehlo.if") == 1
+    kw = dict(kv_layout="paged", page_size=page) if layout == "paged" \
+        else {}
+    with _engine(model, var, **kw) as eng:
+        assert eng.metrics.recompiles == eng.declared_programs()
+        assert eng.warmup() == 0
+
+
 def test_speculative_decode_exact_match(engine_lm):
     """Speculative correctness property: whatever the draft proposes,
     the verify pass emits exactly the big model's greedy tokens — the
@@ -729,8 +836,86 @@ def test_tick_dispatch_counts_the_pages_held(engine_lm, tracer):
         tracer.enable()
         eng.generate([1, 2, 3], 4, timeout=120)
         tracer.disable()
-    assert all(s.args is None for s in tracer.spans()
-               if s.name == "loop/tick_dispatch")
+    ticks = [s for s in tracer.spans() if s.name == "loop/tick_dispatch"]
+    assert ticks and all(s.args == {"sampled_rows": 0} for s in ticks)
+
+
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_engine_under_a_live_profiler_session(engine_lm, tracer, tmp_path,
+                                              layout):
+    """What only a ``--trace 1`` run executes, on the CPU: the profiler
+    session turns the tracer on, ``_run_tick`` fetches the model's
+    counters into the span's ``args``, every ``loop/*`` span is a
+    ``TraceAnnotation``, and the benchmark's span readers read the
+    ring.  Tokens are those of an untraced engine; ``sampled_rows`` on
+    ``loop/tick_dispatch`` (ring and xplane stat) is zero on greedy
+    turns, positive while a sampled request decodes, and
+    ``ServingMetrics`` counts the same ticks."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    from benchmark.device import device_only
+    from benchmark.run import read_metric
+
+    model, var = engine_lm
+    kw = dict(kv_layout="paged", page_size=4) if layout == "paged" else {}
+    rs = np.random.RandomState(3)
+    greedy = [(rs.randint(0, VOCAB, (t,)), 6, {}) for t in (3, 7, 5)]
+    mixed = [(rs.randint(0, VOCAB, (t,)), 8,
+              dict(temperature=0.9, top_p=0.9, seed=40 + t) if t % 2
+              else {}) for t in (4, 5, 6, 7)]
+
+    def drive(eng, requests):
+        futs = [eng.submit(p, n, **opts) for p, n, opts in requests]
+        return [list(f.result(120)) for f in futs]
+
+    with _engine(model, var, **kw) as eng:
+        want = drive(eng, greedy), drive(eng, mixed)
+    with _engine(model, var, **kw) as eng:
+        jax.profiler.start_trace(str(tmp_path),
+                                 profiler_options=device_only())
+        try:
+            deadline = time.monotonic() + 30
+            while not tracer.enabled and time.monotonic() < deadline:
+                time.sleep(0.005)  # the idle loop polls every 5 ms
+            assert tracer.enabled
+            got_greedy = drive(eng, greedy)
+            n_greedy = len([s for s in tracer.spans()
+                            if s.name == "loop/tick_dispatch"])
+            got_mixed = drive(eng, mixed)
+        finally:
+            jax.profiler.stop_trace()
+        sampled_ticks = eng.metrics.sampled_ticks
+        share = eng.metrics.sampled_tick_share()
+        assert "sampled=" in eng.log_line()
+    assert (got_greedy, got_mixed) == want
+    ticks = [s for s in tracer.spans() if s.name == "loop/tick_dispatch"]
+    rows = [s.args["sampled_rows"] for s in ticks]
+    assert n_greedy >= 5 and rows[:n_greedy] == [0] * n_greedy
+    assert max(rows) >= 1 and 0 in rows
+    assert sum(1 for r in rows if r) == sampled_ticks > 0
+    assert 0.0 < share < 1.0
+    assert all(("pages_held" in s.args) == (layout == "paged")
+               for s in ticks)
+    # the same numbers as stats of the xplane's events
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    (plane,) = [p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU"]
+    stats = [dict(ev.stats) for ln in plane.lines for ev in ln.events
+             if ev.name == "loop/tick_dispatch"]
+    assert [st["sampled_rows"] for st in stats] == rows
+    if layout == "paged":
+        assert [st["pages_held"] for st in stats] == \
+            [s.args["pages_held"] for s in ticks]
+    # the span readers of a traced benchmark run read this ring
+    run = {"kind": "decode"}
+    for metric in ("loop_host_share.serve", "token_gap_p95_ms.serve"):
+        assert read_metric(metric, run) > 0
+    cost = read_metric("admit_cost_ms.serve", run)
+    assert cost is None or isinstance(cost, float)
 
 
 def test_token_times_ttft_and_gaps(engine_lm, tracer):

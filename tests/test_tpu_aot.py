@@ -63,11 +63,15 @@ def one_chip(topo):
     mp.undo()
 
 
+def _compiled(fn, sharding, *structs, donate=()):
+    """TPU-compile ``fn``."""
+    return jax.jit(fn, in_shardings=sharding, out_shardings=sharding,
+                   donate_argnums=donate).lower(*structs).compile()
+
+
 def _compile(fn, sharding, *structs, donate=()):
     """TPU-compile ``fn`` and return the compiled program's text."""
-    return jax.jit(fn, in_shardings=sharding, out_shardings=sharding,
-                   donate_argnums=donate) \
-        .lower(*structs).compile().as_text()
+    return _compiled(fn, sharding, *structs, donate=donate).as_text()
 
 
 def _loss(fn):
@@ -131,6 +135,7 @@ def test_flash_partitions_over_dp_tp_mesh(topo, one_chip):
 # the decode cell's geometry (benchmark/traffic/decode-steady.json):
 # 32 slots x 2048 tokens in pages of 16, the worst-case pool
 CELL_SLOTS, CELL_MAX_LEN, CELL_PAGE = 32, 2048, 16
+CELL_VOCAB = 50272  # benchmark/configs/opt-125m.json: the tick's logits
 
 
 def _lm_layer_and_pool(kv_dtype=None):
@@ -142,7 +147,7 @@ def _lm_layer_and_pool(kv_dtype=None):
 
     d = LM_DEFAULTS
     model = nn.Transformer(
-        vocab_size=d["vocabSize"], hidden_size=d["hiddenSize"],
+        vocab_size=CELL_VOCAB, hidden_size=d["hiddenSize"],
         num_heads=d["numHeads"], filter_size=d["filterSize"],
         num_layers=1, dropout=0.0, causal=True)
     pages = default_num_pages(CELL_SLOTS, CELL_MAX_LEN, CELL_PAGE)
@@ -169,12 +174,52 @@ def _pool_in_place(text, cache):
     assert aliased and aliased.group(1).count("alias") == len(leaves)
 
 
+def _sampling_is_gated(compiled, vocab, temp_mib):
+    """The compiled tick holds one ``conditional``; every sort over the
+    vocabulary lies in a computation only its branches reach (a routed
+    layer's own sorts are narrower and stay where they are), so a tick
+    whose rows are all greedy runs none.  Temporaries stay under
+    ``temp_mib``: what the ungated epilogue compiled to at the same
+    shape (PR 30) and, where the conditional's operand moved them from
+    fast memory to HBM, the logits."""
+    import re
+
+    text = compiled.as_text()
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name and line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+    conds = [line for body in bodies.values() for line in body
+             if re.search(r"\sconditional\(", line)]
+    assert len(conds) == 1
+    branches = re.search(r"branch_computations=\{([^}]*)\}", conds[0])
+    reach, todo = set(), re.findall(r"[\w.\-]+", branches.group(1))
+    while todo:
+        name = todo.pop()
+        if name in bodies and name not in reach:
+            reach.add(name)
+            todo += re.findall(r"%([\w.\-]+)", " ".join(bodies[name]))
+    wide = re.compile(r"\[%d,%d\]\S* sort\(" % (CELL_SLOTS, vocab))
+    sorts = [name for name, body in bodies.items() for line in body
+             if wide.search(line)]
+    assert sorts and set(sorts) <= reach, sorts
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= temp_mib * 2 ** 20, temp
+
+
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
 def test_paged_decode_tick_compiles_at_lm_width(one_chip, kv_dtype):
     """The paged decode tick at the decode cell's geometry: the append
     scatters into the donated pool in place and, on the float pool,
     attention is the ``paged_attn`` kernel reading it in place (the
-    int8 pool gathers)."""
+    int8 pool gathers).  The sampling work over ``f32[32,50272]`` lies
+    inside the tick's one conditional."""
     from bigdl_tpu.ops.pallas import report
     from bigdl_tpu.serving.decode import paged_tick_fn
 
@@ -182,13 +227,16 @@ def test_paged_decode_tick_compiles_at_lm_width(one_chip, kv_dtype):
     var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     slots = CELL_SLOTS
     before = report.report().get("paged_attention", {}).get("pallas", 0)
-    text = _compile(
+    compiled = _compiled(
         paged_tick_fn(model), one_chip, var["params"], var["state"],
         cache, S((slots, CELL_MAX_LEN // CELL_PAGE), jnp.int32),
         S((slots,), jnp.int32), S((slots,), jnp.bool_),
         S((slots, 2), jnp.uint32), S((slots,), F32),
         S((slots,), jnp.int32), S((slots,), F32), donate=(2,))
+    text = compiled.as_text()
     _pool_in_place(text, cache)
+    if kv_dtype is None:  # the cell's pool; ungated 13.67 MiB, now 13.54
+        _sampling_is_gated(compiled, CELL_VOCAB, 13.67)
     took = report.report().get("paged_attention", {}).get("pallas", 0)
     assert took == before + (kv_dtype is None)
     assert ("tpu_custom_call" in text) == (kv_dtype is None)
@@ -255,13 +303,16 @@ def test_latent_moe_tick_compiles_at_published_widths(one_chip):
     assert cache["layer0"]["latent"].shape == (pages, page, 640)
     before = report.report().get("latent_paged_attention", {}).get(
         "pallas", 0)
-    text = _compile(
+    compiled = _compiled(
         paged_tick_fn(model), one_chip, var["params"], var["state"],
         cache, S((slots, max_len // page), jnp.int32),
         S((slots,), jnp.int32), S((slots,), jnp.bool_),
         S((slots, 2), jnp.uint32), S((slots,), F32),
         S((slots,), jnp.int32), S((slots,), F32), donate=(2,))
+    text = compiled.as_text()
     _pool_in_place(text, cache)
+    # ungated 4.89 MiB + 2.03 of logits (32 x 16032 f32, padded)
+    _sampling_is_gated(compiled, cfg["vocab_size"], 7.0)
     assert report.report()["latent_paged_attention"]["pallas"] == before + 1
     assert "latent_paged_attn" in text and "ragged-dot" in text
 
