@@ -700,7 +700,9 @@ def telemetry_ab(train_steps: int = 240, batch: int = 64,
             pending.append((fut, slot))
         for fut, slot in pending:
             fut.result(60)
-            latencies.append(slot[1] - slot[0])
+            # the result is set before its callbacks run: a waiter can
+            # get here first on a loaded box
+            latencies.append((slot[1] or time.perf_counter()) - slot[0])
 
     req_dir = None
     req_recorded = 0
@@ -1145,9 +1147,8 @@ def decode_production_arms(model=None, variables=None,
                         rec["resident"].get(resident_name, 0))
                 peak["slots"] = max(peak["slots"],
                                     int(engine._active.sum()))
-                if engine.paged:
-                    peak["pages"] = max(peak["pages"],
-                                        engine._alloc.pages_in_use)
+                peak["pages"] = max(peak["pages"],
+                                    engine._kv.pages_in_use)
                 stop.wait(0.002)
 
         th = threading.Thread(target=sampler, daemon=True)
@@ -1186,13 +1187,13 @@ def decode_production_arms(model=None, variables=None,
         }
         if probe_rec:
             rec.update(probe_rec)
-        if engine.paged:
+        if engine.kv_layout == "paged":
             rec["peak_pages_in_use"] = peak["pages"]
-            rec["page_bytes_per_page"] = engine._page_bytes_total()
-            rec["pool_bytes"] = (engine.num_pages
-                                 * engine._page_bytes_total())
+            rec["page_bytes_per_page"] = engine._kv.page_bytes
+            rec["pool_bytes"] = (engine._kv.num_pages
+                                 * engine._kv.page_bytes)
         else:
-            rec["cache_bytes"] = engine._cache_bytes_total()
+            rec["cache_bytes"] = engine._kv.resident_bytes()
         engine.close()
         return rec
 

@@ -191,7 +191,7 @@ def _paged_tick_gather_leak():
     table as a device argument.  The pure_callback looks harmless (the
     table is tiny) but it serializes every tick on a host round-trip
     and pins the dispatch thread; the production tick threads the
-    (S, M) table in as data (serving/decode.build_paged_tick) so page
+    (S, M) table in as data (serving/decode_programs.build_paged_tick) so page
     moves never touch the program."""
     import jax
     import jax.numpy as jnp

@@ -522,7 +522,7 @@ def _debug_plane_parity():
     from bigdl_tpu.optim.metrics import Metrics
     from bigdl_tpu.optim.optim_method import SGD
     from bigdl_tpu.optim.optimizer import LocalOptimizer
-    from bigdl_tpu.serving.decode import build_decode_tick
+    from bigdl_tpu.serving.decode_programs import build_sampling_tick
     from bigdl_tpu.serving.warmup import build_forward
 
     # the live ops plane (docs/observability.md §Live ops plane) is
@@ -549,15 +549,13 @@ def _debug_plane_parity():
 
     ks = _kernel_shapes()
     dec_model = nn.Transformer(**ks.DECODE_MODEL)
-    tick = build_decode_tick(dec_model)
+    tick = build_sampling_tick(dec_model)
     dec_var = jax.eval_shape(
         lambda: dec_model.init(jax.random.PRNGKey(0)))
     cache = jax.eval_shape(
         lambda: dec_model.init_cache(ks.DECODE_SLOTS, ks.DECODE_MAX_LEN))
-    S = jax.ShapeDtypeStruct
     tick_args = (dec_var["params"], dec_var["state"], cache,
-                 S((ks.DECODE_SLOTS,), jnp.int32),
-                 S((ks.DECODE_SLOTS,), jnp.bool_))
+                 *_sampling_tick_structs(ks.DECODE_SLOTS))
 
     bare_train = jax.make_jaxpr(step)(*args)
     bare_serve = jax.make_jaxpr(fwd)(var["params"], var["state"], x)
@@ -613,7 +611,7 @@ def _request_trace_parity():
 
     import bigdl_tpu.nn as nn
     from bigdl_tpu import models, telemetry
-    from bigdl_tpu.serving.decode import build_decode_tick
+    from bigdl_tpu.serving.decode_programs import build_sampling_tick
     from bigdl_tpu.serving.warmup import build_forward
     from bigdl_tpu.telemetry import requests as request_xray
     from bigdl_tpu.telemetry import workload
@@ -633,15 +631,13 @@ def _request_trace_parity():
 
     ks = _kernel_shapes()
     dec_model = nn.Transformer(**ks.DECODE_MODEL)
-    tick = build_decode_tick(dec_model)
+    tick = build_sampling_tick(dec_model)
     dec_var = jax.eval_shape(
         lambda: dec_model.init(jax.random.PRNGKey(0)))
     cache = jax.eval_shape(
         lambda: dec_model.init_cache(ks.DECODE_SLOTS, ks.DECODE_MAX_LEN))
-    S = jax.ShapeDtypeStruct
     tick_args = (dec_var["params"], dec_var["state"], cache,
-                 S((ks.DECODE_SLOTS,), jnp.int32),
-                 S((ks.DECODE_SLOTS,), jnp.bool_))
+                 *_sampling_tick_structs(ks.DECODE_SLOTS))
 
     bare_serve = jax.make_jaxpr(fwd)(var["params"], var["state"], x)
     bare_decode = jax.make_jaxpr(tick)(*tick_args)
@@ -823,27 +819,24 @@ def _ring():
         "own builder")
 def _decode_step():
     import jax
-    import jax.numpy as jnp
 
     import bigdl_tpu.nn as nn
-    from bigdl_tpu.serving.decode import build_decode_tick
+    from bigdl_tpu.serving.decode_programs import build_sampling_tick
 
     ks = _kernel_shapes()
-    # build THROUGH serving.decode.build_decode_tick so the audited
-    # jaxpr is exactly the program every decode tick dispatches: the
-    # grid cache must stay donated (the engine rebinds it per tick —
-    # an undonated tick doubles the KV cache's HBM) and no host
+    # build THROUGH decode_programs.build_sampling_tick so the audited
+    # jaxpr is exactly the program every dense decode tick dispatches:
+    # the grid cache must stay donated (the engine rebinds it per tick
+    # — an undonated tick doubles the KV cache's HBM) and no host
     # transfer may hide inside the step (the loop's only host<-device
     # sync is the (slots,) next-token fetch, outside this program)
     model = nn.Transformer(**ks.DECODE_MODEL)
-    step = build_decode_tick(model)
+    step = build_sampling_tick(model)
     var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     cache = jax.eval_shape(
         lambda: model.init_cache(ks.DECODE_SLOTS, ks.DECODE_MAX_LEN))
-    S = jax.ShapeDtypeStruct
     args = (var["params"], var["state"], cache,
-            S((ks.DECODE_SLOTS,), jnp.int32),
-            S((ks.DECODE_SLOTS,), jnp.bool_))
+            *_sampling_tick_structs(ks.DECODE_SLOTS))
     return step_context("decode_step", step, args, _leaf_count(cache))
 
 
@@ -856,10 +849,10 @@ def _paged_decode_tick():
     import numpy as np
 
     import bigdl_tpu.nn as nn
-    from bigdl_tpu.serving.decode import build_paged_tick
+    from bigdl_tpu.serving.decode_programs import build_paged_tick
 
     ks = _kernel_shapes()
-    # build THROUGH serving.decode.build_paged_tick: the audited jaxpr
+    # build THROUGH decode_programs.build_paged_tick: the audited jaxpr
     # is the paged engine's steady-state program.  The pool must stay
     # donated (it IS the KV cache), the block-table gather must not
     # smuggle a host sync (see the paged_tick_gather_leak fixture), and
@@ -890,6 +883,18 @@ def _paged_decode_tick():
         name="paged_decode_tick", kind="train_step", jaxpr=live,
         meta={"parity_jaxpr": bare,
               "donate_expected": _leaf_count(cache)})
+
+
+def _sampling_tick_structs(slots: int):
+    """The per-slot arguments of the sampling tick after the cache:
+    tokens, active, keys, temperature, top-k, top-p."""
+    import jax
+    import jax.numpy as jnp
+
+    S = jax.ShapeDtypeStruct
+    return (S((slots,), jnp.int32), S((slots,), jnp.bool_),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.float32),
+            S((slots,), jnp.int32), S((slots,), jnp.float32))
 
 
 def _kernel_shapes():

@@ -135,7 +135,7 @@ class MultiHeadAttention(Module):
     def decode_state(self) -> dict:
         """What this layer keeps per token while decoding, as
         ``{leaf: (heads, width)}``: the cache manager (ops/paged_kv.py,
-        serving/decode.py) allocates, writes and frees by it."""
+        serving/paging.py) allocates, writes and frees by it."""
         if self.seq_mesh is not None:
             raise ValueError(
                 "cached decode does not compose with seq_mesh ring "

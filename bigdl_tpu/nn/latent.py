@@ -662,7 +662,7 @@ class LatentMoETransformer(Module):
                           active):
         """One paged decode step -> ``(logits (N, V), cache,
         counters)``: the counters ride out of the tick with its tokens
-        (serving/decode.paged_tick_fn)."""
+        (serving/decode_programs.build_paged_tick)."""
         logits, cache, counters = self._extend_paged(
             params, state, cache, table, ids_t[:, None], active)
         return logits[:, 0], cache, counters

@@ -4,9 +4,9 @@ A layer declares the decode state it keeps per token as
 ``{leaf name: (heads, width)}`` (``decode_state()`` of the attention
 layers: ``{"k": (H, D), "v": (H, D)}`` for multi-head attention,
 ``{"latent": (1, 640)}`` for latent attention), and everything here and
-in serving/decode.py allocates, writes, appends, resets and meters by
-that declaration: a dense cache leaf is ``(N, heads, T, width)``, a
-pool leaf ``(P, Q, heads*width)`` (:func:`init_cache`,
+in serving/ (decode_programs.py, paging.py) allocates, writes, appends
+and meters by that declaration: a dense cache leaf is ``(N, heads, T,
+width)``, a pool leaf ``(P, Q, heads*width)`` (:func:`init_cache`,
 :func:`init_pool`, :func:`state_leaves`).
 
 The dense decode cache (``init_cache``) reserves
@@ -39,7 +39,7 @@ write can store (:func:`write_pages`: a prefilled row goes in as whole
 pages, a sixteenth of the updates at ``Q`` = 16).  The scales
 take the same form — one row of whole lanes a page (``Q*H8``), a
 token's ``H`` scales a window of it.  The leading axis is always the
-page (serving/decode.page_reset_fn, the HbmLedger's bytes per page).
+page (the HbmLedger's bytes per page: serving/paging.PagedCache).
 
 Reading (nn/attention.py ``apply_paged`` chooses on what it sees): one
 query token on a float pool on the TPU goes through the ``paged_attn``

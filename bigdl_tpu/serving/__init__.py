@@ -8,11 +8,9 @@ dispatch, admission control, and tail-latency metrics.
 """
 
 from bigdl_tpu.serving.bucketing import Bucket, BucketGrid
-from bigdl_tpu.serving.decode import (
-    DecodeEngine,
-    build_decode_tick,
+from bigdl_tpu.serving.decode import DecodeEngine
+from bigdl_tpu.serving.decode_programs import (
     build_draft_propose,
-    build_page_reset,
     build_paged_tick,
     build_paged_write_slot,
     build_prefill,
@@ -48,10 +46,8 @@ __all__ = [
     "EngineClosedError",
     "OutOfPagesError",
     "PageAllocator",
-    "build_decode_tick",
     "build_draft_propose",
     "build_forward",
-    "build_page_reset",
     "build_paged_tick",
     "build_paged_write_slot",
     "build_prefill",

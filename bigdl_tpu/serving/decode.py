@@ -5,50 +5,66 @@ ServingEngine`: where the stateless engine amortizes dispatch across a
 batch of independent forwards, this engine amortizes *decoding* across
 a fixed grid of in-flight sequences.
 
-Design:
+Three parts, each with one job:
 
-* **Slot grid** — one static-shape KV cache pytree holds ``slots``
-  independent sequences (per-row ``length``; see
-  ``MultiHeadAttention.init_cache``).  ONE compiled decode step
-  advances every occupied slot per tick; shapes never depend on
-  occupancy, so steady-state decode never recompiles no matter how
-  requests come and go.
-* **Prefill through the BucketGrid** — prompts are padded onto the
-  declared (batch x prompt-length) grid and run through a compiled
-  prefill that returns the first generated token plus the prompt's
-  KV rows; a compiled ``write_slot`` splices those rows into the grid
-  cache (donated: the grid cache is rebound, never copied).
-* **Continuous batching** — a finished sequence (EOS / token budget /
-  deadline) retires at TOKEN granularity and frees its slot
-  immediately; the next waiting request prefills into it while the
-  other slots keep decoding.  ``continuous=False`` degrades to static
-  run-to-completion waves (admit only into an empty grid) — the
-  baseline arm of ``bench.py --decode-ab``.
-* **Deadline semantics** — a request whose deadline expires before its
-  prefill fails fast with :class:`DeadlineExceededError` (same as the
-  stateless engine); once decoding has started, an expiring deadline
-  *truncates*: the tokens generated so far are delivered as the
-  result.  Admission control (bounded queue -> ``QueueFullError``)
-  and per-request exception delivery mirror :class:`ServingEngine`.
-* **Metrics** — tokens/s, slot occupancy, prefill/decode split and
-  per-tick (== per-token) latency percentiles on
-  :class:`~bigdl_tpu.serving.metrics.ServingMetrics`, exportable to
-  TensorBoard via ``ServingMetrics.write_summary``.
+* **The scheduler** (:class:`DecodeEngine`) — the loop thread: drain the
+  queue, admit (prefill into free slots), step a chunked prefill, fund
+  the slots with room, dispatch one *round*, retire.  A round hands
+  back ``(emitted (S, K), n_emit (S,))``: the grid tick is the round
+  with K = 1, draft-propose + verify the round with K = ``draft_k`` +
+  1; one ``_retire`` takes either.  Continuous batching: a finished
+  sequence (EOS / token budget / deadline) retires at TOKEN granularity
+  and frees its slot at once; ``continuous=False`` degrades to static
+  run-to-completion waves, the baseline arm of ``bench.py --decode-ab``.
+* **A model lane** (:class:`_Lane`) — one model's parameters, its cache
+  and its compiled programs (serving/decode_programs.py).  The target
+  is one lane and a draft another; warm-up, admission and the chunk
+  step run the same calls on each.  The lane counts compiles: shapes
+  never depend on occupancy, so steady-state decode never recompiles.
+* **A cache manager** (serving/paging.py) — the K/V layout, dense rows
+  or a page pool: it builds the cache and the programs that depend on
+  the layout and answers ``reserve`` / ``release``.  The page *policy*
+  (oldest first, evict strictly younger, pause) is the scheduler's.
+
+Prompts are padded onto the declared (batch x prompt-length)
+BucketGrid and run through a compiled prefill that returns the first
+token's logits plus the prompt's rows; a compiled slot write splices
+those rows into the grid cache (donated: rebound, never copied).
+
+**Deadline semantics** — a request whose deadline expires before its
+prefill fails fast with :class:`DeadlineExceededError` (same as the
+stateless engine); once decoding has started, an expiring deadline
+*truncates*: the tokens generated so far are delivered as the result.
+Admission control (bounded queue -> ``QueueFullError``) and
+per-request exception delivery mirror :class:`ServingEngine`.
+
+**Metrics** — tokens/s, slot occupancy, prefill/decode split and
+per-tick latency percentiles on :class:`~bigdl_tpu.serving.metrics.
+ServingMetrics`, exportable to TensorBoard via
+``ServingMetrics.write_summary``.
 """
 from __future__ import annotations
 
 import collections
+import functools
+import inspect
 import itertools
 import queue
 import threading
 import time
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.profiler import StepTraceAnnotation
 
+from bigdl_tpu.serving import paging
 from bigdl_tpu.serving.bucketing import BucketGrid
+from bigdl_tpu.serving.decode_programs import (
+    build_draft_propose,
+    build_prefill,
+    build_prefill_chunk,
+)
 from bigdl_tpu.serving.engine import (
     DeadlineExceededError,
     EngineClosedError,
@@ -60,544 +76,6 @@ from bigdl_tpu.telemetry import costmodel, programs
 from bigdl_tpu.telemetry import requests as request_xray
 from bigdl_tpu.telemetry import workload
 from bigdl_tpu.telemetry.tracer import CAT_DECODE, get_tracer, set_correlation
-
-
-def decode_tick_fn(model):
-    """The raw whole-grid decode step (see :func:`build_decode_tick`).
-    ``active`` gates bookkeeping only: inactive rows still flow through
-    the compute (their outputs are ignored and their lengths frozen),
-    which is what keeps the program occupancy-independent."""
-    import jax.numpy as jnp
-
-    def tick(params, state, cache, tokens, active):
-        old_len = {lk: c["length"] for lk, c in cache.items()}
-        logits, cache = model.decode_step(params, state, cache, tokens)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(active, nxt, tokens)
-        # freeze retired rows at their final length so an idle slot's
-        # length can never walk off the end of the cache
-        cache = {lk: dict(c, length=jnp.where(active, c["length"],
-                                              old_len[lk]))
-                 for lk, c in cache.items()}
-        return cache, nxt
-
-    return tick
-
-
-def build_decode_tick(model, **jit_kw):
-    """The jitted whole-grid decode step — kept as a named top-level
-    builder so graft-lint's ``decode_step`` target audits exactly the
-    program every tick dispatches (donated cache, no host transfer,
-    static shapes)."""
-    import jax
-
-    return jax.jit(decode_tick_fn(model), donate_argnums=(2,), **jit_kw)
-
-
-def prefill_fn(model, max_len: int, dtype=None):
-    """Raw prompt prefill: fresh cache rows for a padded prompt batch
-    + the next-token logits at each row's true length."""
-    import jax
-    import jax.numpy as jnp
-
-    dtype = dtype or jnp.float32
-
-    def prefill(params, state, ids, lengths):
-        cache = model.init_cache(ids.shape[0], max_len, dtype)
-        return model.prefill(params, state, ids, cache, lengths=lengths)
-
-    return jax.named_scope("prefill")(prefill)
-
-
-def build_prefill(model, max_len: int, dtype=None, **jit_kw):
-    import jax
-
-    return jax.jit(prefill_fn(model, max_len, dtype), **jit_kw)
-
-
-def write_slot_fn():
-    """Raw slot splice: copy prefill-batch row ``row`` into grid slot
-    ``slot`` across every cache leaf."""
-    import jax
-
-    def write(grid_cache, batch_cache, row, slot):
-        def upd(g, b):
-            r = jax.lax.dynamic_slice_in_dim(b, row, 1, axis=0)
-            return jax.lax.dynamic_update_slice_in_dim(
-                g, r.astype(g.dtype), slot, axis=0)
-
-        return jax.tree_util.tree_map(upd, grid_cache, batch_cache)
-
-    return jax.named_scope("slot_write")(write)
-
-
-def build_write_slot(**jit_kw):
-    """Jitted slot splice; the grid cache is donated — admission
-    rebinds it in place of copying the whole grid."""
-    import jax
-
-    return jax.jit(write_slot_fn(), donate_argnums=(0,), **jit_kw)
-
-
-# ---------------------------------------------------------------------------
-# in-tick sampling (ISSUE 14; docs/decoding.md §Sampling)
-# ---------------------------------------------------------------------------
-def sample_logits(logits, keys, temp, top_k, top_p):
-    """Temperature / top-k / top-p sampling with fully static shapes.
-
-    ``logits`` (S, V); ``keys`` (S, 2) raw uint32 threefry keys —
-    per-slot PRNG state threaded through the slot grid as *data*, so
-    request seeds never become compile-time constants (graft-lint's
-    ``paged_decode_tick`` parity check is exactly this property);
-    ``temp``/``top_p`` (S,) f32 and ``top_k`` (S,) int32 are per-slot.
-
-    The filter runs in sorted space: rank < top_k (``top_k <= 0`` keeps
-    all V), exclusive-cumsum < top_p (``top_p >= 1`` keeps all), the
-    top-1 always kept; the draw is gumbel-argmax over the masked
-    logits, unsorted back through the argsort permutation.  Rows with
-    ``temp <= 0`` are the caller's greedy rows — it takes the exact
-    ``argmax`` instead (the parity oracle stays bit-identical), and
-    calls this only in a tick where an active row has ``temp > 0``
-    (:func:`_next_tokens`).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    v = logits.shape[-1]
-    t = jnp.maximum(temp, 1e-6)[:, None]
-    scaled = logits.astype(jnp.float32) / t
-    order = jnp.argsort(-scaled, axis=-1)                  # (S, V)
-    l_sorted = jnp.take_along_axis(scaled, order, axis=-1)
-    ranks = jnp.arange(v)[None, :]
-    k_eff = jnp.where(top_k > 0, top_k, v)[:, None]
-    keep = ranks < k_eff
-    probs = jax.nn.softmax(l_sorted, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep &= (cum - probs) < jnp.minimum(top_p, 1.0)[:, None]
-    keep = keep.at[:, 0].set(True)
-    masked = jnp.where(keep, l_sorted, -1e30)
-    gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (v,)))(keys)
-    pick = jnp.argmax(masked + gumbel, axis=-1)
-    return jnp.take_along_axis(order, pick[:, None],
-                               axis=-1)[:, 0].astype(jnp.int32)
-
-
-def _next_tokens(logits, tokens, active, keys, temp, top_k, top_p):
-    """Shared tick epilogue: greedy rows take the exact argmax, sampled
-    rows (temp > 0) the gumbel draw; inactive rows hold their token and
-    their key (reproducibility: a slot's key chain advances once per
-    tick it actually decodes).
-
-    :func:`sample_logits` runs only in a tick where some active row
-    samples (``lax.cond`` on data the tick already receives): its sort,
-    softmax, cumulative sum and ``S x V`` noise draws cost 17 of 18 ms
-    at V = 50272 and every row of a greedy grid threw them away.  A
-    tick with one such row pays for the whole grid, as before; tokens
-    and keys are the same either way."""
-    import jax
-    import jax.numpy as jnp
-
-    with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        sampled = jax.lax.cond(
-            jnp.any(active & (temp > 0.0)),
-            lambda: sample_logits(logits, keys, temp, top_k, top_p),
-            lambda: jnp.zeros_like(greedy))
-        nxt = jnp.where(temp > 0.0, sampled, greedy)
-        nxt = jnp.where(active, nxt, tokens)
-        split = jax.vmap(lambda k: jax.random.split(k, 2)[0])(keys)
-        keys = jnp.where(active[:, None], split, keys)
-    return nxt, keys
-
-
-def sampling_tick_fn(model):
-    """The whole-grid decode step with in-tick sampling — the engine's
-    default tick.  Signature grows per-slot sampling state (keys, temp,
-    top_k, top_p), all occupancy-independent (S,)-shaped device args;
-    greedy requests ride along as temp == 0 rows."""
-    import jax.numpy as jnp
-
-    def tick(params, state, cache, tokens, active, keys, temp, top_k,
-             top_p):
-        old_len = {lk: c["length"] for lk, c in cache.items()}
-        logits, cache = model.decode_step(params, state, cache, tokens)
-        nxt, keys = _next_tokens(logits, tokens, active, keys, temp,
-                                 top_k, top_p)
-        cache = {lk: dict(c, length=jnp.where(active, c["length"],
-                                              old_len[lk]))
-                 for lk, c in cache.items()}
-        return cache, nxt, keys
-
-    return tick
-
-
-def build_sampling_tick(model, **jit_kw):
-    import jax
-
-    return jax.jit(sampling_tick_fn(model), donate_argnums=(2,),
-                   **jit_kw)
-
-
-# ---------------------------------------------------------------------------
-# paged KV tick + slot write (ISSUE 14; docs/decoding.md §Paged KV)
-# ---------------------------------------------------------------------------
-def paged_tick_fn(model):
-    """The sampling tick over the paged pool: identical math with the
-    host-managed block ``table`` (S, M) as one more device argument —
-    its values change as pages move, its shape never does."""
-    import jax.numpy as jnp
-
-    def tick(params, state, cache, table, tokens, active, keys, temp,
-             top_k, top_p):
-        old_len = {lk: c["length"] for lk, c in cache.items()}
-        # what the model counts inside its step (tokens per expert
-        # held...) rides out with the tokens and reaches
-        # ``loop/tick_dispatch`` while a trace is live
-        logits, cache, counters = model.decode_step_paged(
-            params, state, cache, table, tokens, active)
-        nxt, keys = _next_tokens(logits, tokens, active, keys, temp,
-                                 top_k, top_p)
-        cache = {lk: dict(c, length=jnp.where(active, c["length"],
-                                              old_len[lk]))
-                 for lk, c in cache.items()}
-        return cache, nxt, keys, counters
-
-    return tick
-
-
-def build_paged_tick(model, **jit_kw):
-    """Jitted paged tick (donated pool) — graft-lint's
-    ``paged_decode_tick`` target audits exactly this program."""
-    import jax
-
-    return jax.jit(paged_tick_fn(model), donate_argnums=(2,), **jit_kw)
-
-
-def paged_write_slot_fn():
-    """Splice one dense prefill-batch row into a slot's pages: every
-    per-token leaf the layer declared (K and V, or a latent row;
-    quantized when the pool is int8) goes through the slot's block-table
-    row as whole pages.  Unmapped logical pages redirect to the trash
-    page — only the pages the allocator granted are ever written."""
-    import jax
-    import jax.numpy as jnp
-
-    from bigdl_tpu.ops import paged_kv
-
-    def write(pool_cache, table_row, batch_cache, row, slot):
-        out = {}
-        for lk, pool in pool_cache.items():
-            bc = batch_cache[lk]
-            new = dict(pool)
-            for name in paged_kv.state_leaves(pool):
-                r = jax.lax.dynamic_index_in_dim(
-                    bc[name], row, axis=0, keepdims=False)  # (H,T,D)
-                paged_kv.write_pages(new, name, table_row,
-                                     r.transpose(1, 0, 2))
-            lrow = jax.lax.dynamic_slice_in_dim(bc["length"], row, 1,
-                                                axis=0)
-            new["length"] = jax.lax.dynamic_update_slice_in_dim(
-                pool["length"], lrow.astype(jnp.int32), slot, axis=0)
-            out[lk] = new
-        return out
-
-    return jax.named_scope("slot_write")(write)
-
-
-def build_paged_write_slot(**jit_kw):
-    import jax
-
-    return jax.jit(paged_write_slot_fn(), donate_argnums=(0,), **jit_kw)
-
-
-def page_reset_fn():
-    """Zero a batch of physical pages (the page-free program).  Purely
-    hygienic — the stale-above-length invariant already makes freed
-    bytes unreachable — and therefore off by default
-    (``BIGDL_TPU_PAGE_ZERO=1``); page ids of 0 re-zero the trash page,
-    so a short free list pads with 0."""
-    import jax.numpy as jnp
-
-    def reset(pool_cache, pages):
-        out = {}
-        for lk, pool in pool_cache.items():
-            new = dict(pool)
-            for name, leaf in pool.items():
-                if name == "length":
-                    continue
-                z = jnp.zeros((pages.shape[0],) + leaf.shape[1:],
-                              leaf.dtype)
-                new[name] = leaf.at[pages].set(z)
-            out[lk] = new
-        return out
-
-    return reset
-
-
-def build_page_reset(**jit_kw):
-    import jax
-
-    return jax.jit(page_reset_fn(), donate_argnums=(0,), **jit_kw)
-
-
-# ---------------------------------------------------------------------------
-# chunked prefill (ISSUE 14; docs/decoding.md §Chunked prefill)
-# ---------------------------------------------------------------------------
-def prefill_chunk_fn(model):
-    """One bounded prompt chunk through a batch-1 staging cache:
-    ``model.extend`` appends at the staging cache's current length, so
-    the same compiled program serves the first chunk (fresh cache) and
-    every later one — a long prompt costs N dispatches of this program
-    interleaved with grid ticks instead of one giant stalling prefill.
-    ``advance`` (1,) is the chunk's true token count (the final chunk
-    is padded); returns the last *valid* position's logits — only the
-    final chunk's matter (they seed token 0)."""
-    import jax.numpy as jnp
-
-    def chunk(params, state, cache, ids, advance):
-        logits, cache = model.extend(params, state, cache, ids,
-                                     advance=advance)
-        last = jnp.take_along_axis(
-            logits,
-            (jnp.maximum(advance, 1) - 1)[:, None, None].astype(
-                jnp.int32), axis=1)[:, 0]
-        return last, cache
-
-    return chunk
-
-
-def build_prefill_chunk(model, **jit_kw):
-    import jax
-
-    return jax.jit(prefill_chunk_fn(model), donate_argnums=(2,),
-                   **jit_kw)
-
-
-# ---------------------------------------------------------------------------
-# speculative decoding (ISSUE 14; docs/decoding.md §Speculative)
-# ---------------------------------------------------------------------------
-def draft_propose_fn(draft_model, k: int):
-    """k greedy draft steps in ONE compiled program (a ``lax.scan`` of
-    ``decode_step`` — one dispatch + one host sync per round instead of
-    k).  The scan runs k+1 steps so the cache also ingests the last
-    proposal (needed when the verify accepts the whole draft); the
-    extra step's output is discarded.
-
-    Draft lengths are *set* from the host-tracked truth first: a verify
-    rollback shortens the target cache, and syncing here self-heals the
-    draft to the same prefix (entries above it are stale-above-length).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def propose(params, state, dcache, tokens, lengths, active):
-        dcache = {lk: dict(c, length=lengths)
-                  for lk, c in dcache.items()}
-
-        def body(carry, _):
-            cache, tok = carry
-            logits, cache = draft_model.decode_step(params, state,
-                                                    cache, tok)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, tok)
-            return (cache, nxt), nxt
-
-        (dcache, _), outs = jax.lax.scan(body, (dcache, tokens), None,
-                                         length=k + 1)
-        proposals = jnp.moveaxis(outs[:k], 0, 1)           # (S, k)
-        dcache = {lk: dict(c, length=jnp.where(active, c["length"],
-                                               lengths))
-                  for lk, c in dcache.items()}
-        return dcache, proposals
-
-    return propose
-
-
-def build_draft_propose(draft_model, k: int, **jit_kw):
-    import jax
-
-    return jax.jit(draft_propose_fn(draft_model, k),
-                   donate_argnums=(2,), **jit_kw)
-
-
-def spec_verify_fn(model, k: int, paged: bool = False):
-    """One big-model pass over ``[t_last, d_0..d_{k-1}]`` (S, k+1):
-    ``b = argmax`` of every position's logits, the accepted prefix is
-    the longest run of drafts matching ``b``, and the emitted tokens
-    ``b[:, :n_acc + 1]`` are ALWAYS the big model's own argmaxes — the
-    speculative arm is exact-match with the plain greedy tick by
-    construction.  Cache lengths roll back in-graph to
-    ``old + n_emit``; rejected-draft rows above are stale-above-length.
-    """
-    import jax.numpy as jnp
-
-    def verify(params, state, cache, tokens, draft, active):
-        old_len = {lk: c["length"] for lk, c in cache.items()}
-        x = jnp.concatenate([tokens[:, None], draft], axis=1)
-        logits, cache = model.extend(params, state, cache, x)
-        b = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        acc = jnp.cumprod((b[:, :k] == draft).astype(jnp.int32), axis=1)
-        n_emit = jnp.where(active, acc.sum(axis=1) + 1, 0).astype(
-            jnp.int32)
-        cache = {lk: dict(c, length=old_len[lk] + n_emit)
-                 for lk, c in cache.items()}
-        emitted = jnp.where(active[:, None], b, tokens[:, None])
-        return cache, emitted, n_emit
-
-    def verify_paged(params, state, cache, table, tokens, draft,
-                     active):
-        old_len = {lk: c["length"] for lk, c in cache.items()}
-        x = jnp.concatenate([tokens[:, None], draft], axis=1)
-        logits, cache = model.extend_paged(params, state, cache, table,
-                                           x, active)
-        b = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        acc = jnp.cumprod((b[:, :k] == draft).astype(jnp.int32), axis=1)
-        n_emit = jnp.where(active, acc.sum(axis=1) + 1, 0).astype(
-            jnp.int32)
-        cache = {lk: dict(c, length=old_len[lk] + n_emit)
-                 for lk, c in cache.items()}
-        emitted = jnp.where(active[:, None], b, tokens[:, None])
-        return cache, emitted, n_emit
-
-    return verify_paged if paged else verify
-
-
-def build_spec_verify(model, k: int, paged: bool = False, **jit_kw):
-    import jax
-
-    return jax.jit(spec_verify_fn(model, k, paged=paged),
-                   donate_argnums=(2,), **jit_kw)
-
-
-def deviceless_decode_check(model, *, slots: int = 8, max_len: int = 160,
-                            prompt_buckets: Sequence[int] = (8, 16, 32),
-                            prefill_batch_sizes: Sequence[int] = (1, 4, 8),
-                            dtype=None, topology: str = "v5e:1x1",
-                            log=None,
-                            page_size: Optional[int] = None,
-                            num_pages: Optional[int] = None,
-                            kv_dtype=None,
-                            prefill_chunk: Optional[int] = None,
-                            draft_model=None,
-                            draft_k: int = 3) -> int:
-    """Compile every program the decode engine dispatches — the grid
-    tick (greedy and sampling), each declared prefill bucket, and the
-    slot writes — against a deviceless TPU topology (the
-    tools/tpu_aot_check.py machinery), so a decode rollout is
-    Mosaic-lowering-proven before any chip window
-    (``tools/serving_aot_check.py --decode``).  ``page_size`` adds the
-    paged tick + paged slot write + page reset (``kv_dtype='int8'``
-    compiles the quantized pool variant too), ``prefill_chunk`` the
-    chunked-prefill program, and ``draft_model`` the speculative
-    propose/verify pair.  Returns the failure count; ``log`` receives
-    one line per program."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    dtype = dtype or jnp.float32
-    log = log or (lambda s: None)
-    topo = topologies.get_topology_desc(
-        topology_name=topology, platform="tpu",
-        chips_per_host_bounds=[1, 1, 1])
-    mesh = Mesh(np.array(topo.devices), ("d",))
-    sh = NamedSharding(mesh, P())
-    var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(lambda: model.init_cache(slots, max_len,
-                                                    dtype))
-    S = jax.ShapeDtypeStruct
-    failures = 0
-
-    def try_compile(tag, jitted, *args):
-        nonlocal failures
-        try:
-            jitted.lower(*args).compile()
-            log(f"{tag}: OK")
-        except Exception as e:
-            failures += 1
-            log(f"{tag}: FAIL {str(e)[:200]}")
-
-    shard = dict(in_shardings=sh, out_shardings=sh)
-    tok = S((slots,), jnp.int32)
-    act = S((slots,), jnp.bool_)
-    samp = (S((slots, 2), jnp.uint32), S((slots,), jnp.float32),
-            S((slots,), jnp.int32), S((slots,), jnp.float32))
-    try_compile("decode tick", build_decode_tick(model, **shard),
-                var["params"], var["state"], cache, tok, act)
-    try_compile("sampling tick", build_sampling_tick(model, **shard),
-                var["params"], var["state"], cache, tok, act, *samp)
-    pf = build_prefill(model, max_len, dtype, **shard)
-    grid = BucketGrid([(int(t),) for t in prompt_buckets],
-                      prefill_batch_sizes, pad_value=0)
-    for bucket in grid.declared_buckets():
-        try_compile(f"prefill {bucket.batch}x{bucket.dims[0]}", pf,
-                    var["params"], var["state"],
-                    S((bucket.batch,) + bucket.dims, jnp.int32),
-                    S((bucket.batch,), jnp.int32))
-    wr = build_write_slot(**shard)
-    for b in grid.batch_sizes:
-        bcache = jax.eval_shape(lambda b=b: model.init_cache(b, max_len,
-                                                             dtype))
-        try_compile(f"write_slot batch={b}", wr, cache, bcache,
-                    S((), jnp.int32), S((), jnp.int32))
-    if page_size:
-        from bigdl_tpu.serving import paging
-
-        n_pages = num_pages or paging.default_num_pages(
-            slots, max_len, page_size)
-        m = -(-max_len // page_size)
-        table = S((slots, m), jnp.int32)
-        trow = S((m,), jnp.int32)
-        variants = [("fp", None)]
-        if kv_dtype:
-            variants.append((str(kv_dtype), kv_dtype))
-        for tag, kvd in variants:
-            pcache = jax.eval_shape(
-                lambda kvd=kvd: model.init_paged_cache(
-                    n_pages, page_size, slots, dtype, kv_dtype=kvd))
-            try_compile(f"paged tick [{tag}]",
-                        build_paged_tick(model, **shard),
-                        var["params"], var["state"], pcache, table,
-                        tok, act, *samp)
-            pwr = build_paged_write_slot(**shard)
-            for b in grid.batch_sizes:
-                bcache = jax.eval_shape(
-                    lambda b=b: model.init_cache(b, max_len, dtype))
-                try_compile(f"paged write_slot batch={b} [{tag}]", pwr,
-                            pcache, trow, bcache, S((), jnp.int32),
-                            S((), jnp.int32))
-            try_compile(f"page reset [{tag}]",
-                        build_page_reset(**shard), pcache,
-                        S((m,), jnp.int32))
-            if draft_model is not None:
-                try_compile(
-                    f"spec verify paged k={draft_k} [{tag}]",
-                    build_spec_verify(model, draft_k, paged=True,
-                                      **shard),
-                    var["params"], var["state"], pcache, table, tok,
-                    S((slots, draft_k), jnp.int32), act)
-    if prefill_chunk:
-        staging = jax.eval_shape(lambda: model.init_cache(1, max_len,
-                                                          dtype))
-        try_compile(f"prefill chunk C={prefill_chunk}",
-                    build_prefill_chunk(model, **shard),
-                    var["params"], var["state"], staging,
-                    S((1, prefill_chunk), jnp.int32), S((1,), jnp.int32))
-    if draft_model is not None:
-        dvar = jax.eval_shape(
-            lambda: draft_model.init(jax.random.PRNGKey(0)))
-        dcache = jax.eval_shape(
-            lambda: draft_model.init_cache(slots, max_len, dtype))
-        try_compile(f"draft propose k={draft_k}",
-                    build_draft_propose(draft_model, draft_k, **shard),
-                    dvar["params"], dvar["state"], dcache, tok,
-                    S((slots,), jnp.int32), act)
-        try_compile(f"spec verify k={draft_k}",
-                    build_spec_verify(model, draft_k, **shard),
-                    var["params"], var["state"], cache, tok,
-                    S((slots, draft_k), jnp.int32), act)
-    return failures
 
 
 class _DecodeRequest:
@@ -667,6 +145,153 @@ class _Slot:
 _CLOSE = object()  # queue sentinel
 
 
+
+
+class _Lane:
+    """One model on the slot grid: its parameters, its cache and its
+    compiled programs.  The lane keeps the compiled-program keys (the
+    recompile counter lives here): parameters, state and dtype are
+    fixed, so its key set is exactly jit's cache key set and the count
+    is exact."""
+
+    def __init__(self, prefix: str, model, variables: dict, kv,
+                 max_len: int, chunked: bool, metrics: ServingMetrics,
+                 tracer):
+        import jax.numpy as jnp
+
+        self.prefix = prefix  # names its programs in the X-ray registry
+        self.model = model
+        self.params = variables["params"]
+        self.state = variables["state"]
+        self.dtype = self.params["embed"]["weight"].dtype \
+            if "embed" in self.params else jnp.float32
+        self.kv = kv
+        self.max_len = max_len
+        self.cache = kv.init_cache(model, self.dtype)
+        self.programs = {
+            "prefill": build_prefill(model, max_len, self.dtype),
+            "write": kv.build_write()}
+        if chunked:
+            self.programs["chunk"] = build_prefill_chunk(model)
+        self.warming = False  # declared-grid compiles skip forensics
+        self._metrics = metrics
+        self._tracer = tracer
+        self._seen: set = set()
+
+    def drop(self):
+        """Let go of everything that lives on the device."""
+        self.params = self.state = self.cache = None
+        self.programs = {}
+
+    def call(self, name: str, program: str, *args, key: tuple = (),
+             cost=None):
+        """Run the compiled ``name`` on ``args``; first sight of
+        ``(name,) + key`` is counted (and timed) as a compile and
+        registered with the X-ray registry as ``program``.  Nothing is
+        fingerprinted on a seen key."""
+        jitted = self.programs[name]
+        key = (name,) + key
+        if key in self._seen:
+            programs.get_program_registry().record_call(program)
+            return jitted(*args)
+        # before the call (ticks and writes donate their cache), and
+        # registered before ``record_recompile`` so the forensic instant
+        # precedes the recompile span the Watchdog pairs it with
+        sig = self._signature(jitted, args)
+        t0 = time.perf_counter()
+        # which tick (ambient correlation) paid for which program
+        with self._tracer.span("compile", CAT_DECODE,
+                               args={"program": program}):
+            out = jitted(*args)
+        dt = time.perf_counter() - t0
+        programs.get_program_registry().register_compile(
+            program, sig, compile_s=dt, cost=cost, expected=self.warming)
+        self._metrics.record_recompile(dt)
+        self._seen.add(key)
+        return out
+
+    @staticmethod
+    def _signature(jitted, args):
+        """Argument names from the program's own parameters, donation
+        from its trace; None when either cannot be had (forensics are
+        optional)."""
+        try:
+            names = list(inspect.signature(jitted).parameters)
+            info = jitted.trace(*args).args_info[0]
+            donated = [n for n, a in zip(names, info)
+                       if any(leaf.donated
+                              for leaf in jax.tree_util.tree_leaves(a))]
+            return programs.signature_of(dict(zip(names, args)),
+                                         donated=donated)
+        except Exception:
+            return None
+
+    def declare(self, grid: BucketGrid, chunk: Optional[int]):
+        """This lane's programs as ``(key, thunk)``: a prefill per
+        declared bucket, a slot write per declared batch size, the
+        chunk program and, when 1 is not a declared batch, the batch-1
+        write of its staging cache.  ``held`` carries a prefilled batch
+        to the write that follows it, and nothing longer than that (a
+        batch of ``max_len`` rows is hundreds of MB at a cell's size)."""
+        def prefill(held, ids, lengths, keep):
+            pcache = self.prefill(ids, lengths)[1]
+            if keep:
+                held[self.prefix, len(ids)] = pcache
+
+        def chunked(held, ids, adv, keep):
+            staging = self.chunk(self.staging(), ids, adv)[1]
+            if keep:
+                held[self.prefix, 1] = staging
+
+        def write(held, batch):
+            self.write(held.pop((self.prefix, batch)), 0, 0, batch=batch)
+
+        progs, batches = [], set()
+
+        def declare(key, fill, batch, **args):
+            """``fill`` and, for a batch size not seen yet, its write."""
+            new = batch not in batches
+            batches.add(batch)
+            progs.append(((self.prefix,) + key,
+                          functools.partial(fill, keep=new, **args)))
+            if new:
+                progs.append(((self.prefix, "write", batch),
+                              functools.partial(write, batch=batch)))
+
+        for bucket in grid.declared_buckets():
+            ids = np.zeros((bucket.batch,) + bucket.dims, np.int32)
+            declare(("prefill",) + ids.shape, prefill, bucket.batch,
+                    ids=ids, lengths=np.ones((bucket.batch,), np.int32))
+        if chunk:
+            declare(("chunk",), chunked, 1,
+                    ids=np.zeros((1, chunk), np.int32),
+                    adv=np.ones((1,), np.int32))
+        return progs
+
+    # -------------------------------------- the calls every lane makes
+    def prefill(self, ids: np.ndarray, lengths: np.ndarray):
+        return self.call("prefill", f"{self.prefix}_prefill", self.params,
+                         self.state, ids, lengths, key=(ids.shape,))
+
+    def write(self, pcache, row: int, slot: int, batch: int):
+        """Splice row ``row`` of a prefilled batch into ``slot``; the
+        compiled shape depends only on the batch bucket (prompt length
+        never survives into cache shapes)."""
+        self.cache = self.call(
+            "write", f"{self.prefix}_write_slot", self.cache,
+            *self.kv.write_extra(slot), pcache, row, slot, key=(batch,))
+
+    def staging(self):
+        """A fresh batch-1 cache for a chunked prefill."""
+        return self.model.init_cache(1, self.max_len, self.dtype)
+
+    def chunk(self, staging, ids: np.ndarray, adv: np.ndarray):
+        last, staging = self.call(
+            "chunk", f"{self.prefix}_prefill_chunk", self.params,
+            self.state, staging, ids, adv)
+        return np.asarray(last), staging
+
+
 class DecodeEngine:
     """KV-cached incremental decoding with continuous batching.
 
@@ -680,16 +305,17 @@ class DecodeEngine:
     ``model.generate``, which threads the same cache.
 
     ``kv_layout="paged"`` swaps the dense per-slot cache for the paged
-    pool of ops/paged_kv.py (``page_size``/``num_pages``; retirement
-    frees pages back to a host-side :class:`~bigdl_tpu.serving.paging.
-    PageAllocator`), and ``kv_dtype="int8"`` stores the pool quantized.
-    ``prefill_chunk=C`` feeds prompts longer than the largest declared
-    bucket through a batch-1 chunked prefill, ``C`` tokens per loop
-    iteration, instead of stalling the tick.  ``draft=(draft_model,
-    draft_variables)`` turns on speculative decoding: each round the
-    draft proposes ``draft_k`` tokens and one verify pass of the big
-    model accepts the longest matching prefix (greedy-only; emitted
-    tokens are exactly the big model's argmaxes).
+    pool of ops/paged_kv.py (``page_size``, default 16 tokens;
+    ``num_pages``, default every slot at ``max_len``; retirement frees
+    pages back to the host-side allocator), and ``kv_dtype="int8"``
+    stores the pool quantized.  ``prefill_chunk=C`` feeds prompts longer
+    than the largest declared bucket through a batch-1 chunked prefill,
+    ``C`` tokens per loop iteration, instead of stalling the tick.
+    ``draft=(draft_model, draft_variables)`` turns on speculative
+    decoding: each round the draft proposes ``draft_k`` (default 3)
+    tokens and one verify pass of the big model accepts the longest
+    matching prefix (greedy-only; emitted tokens are exactly the big
+    model's argmaxes).
     """
 
     def __init__(self, model, variables: dict, *,
@@ -712,13 +338,6 @@ class DecodeEngine:
                  prefill_chunk: Optional[int] = None,
                  draft: Optional[tuple] = None,
                  draft_k: Optional[int] = None):
-        import jax.numpy as jnp
-
-        from bigdl_tpu.serving import paging as _paging
-
-        self.model = model
-        self.params = variables["params"]
-        self.state = variables["state"]
         self.slots = int(slots)
         self.max_len = int(max_len)
         self.eos_id = eos_id
@@ -728,80 +347,59 @@ class DecodeEngine:
         self.grid = BucketGrid([(int(t),) for t in prompt_buckets],
                                prefill_batch_sizes, pad_value=0)
         self._largest_bucket = max(int(t) for t in prompt_buckets)
+        self.prefill_chunk = int(prefill_chunk) if prefill_chunk \
+            else None
+        self._tracer = get_tracer()
 
-        self._dtype = self.params["embed"]["weight"].dtype \
-            if "embed" in self.params else jnp.float32
         if kv_layout not in ("dense", "paged"):
             raise ValueError(f"kv_layout must be 'dense' or 'paged', "
                              f"got {kv_layout!r}")
-        self.kv_layout = kv_layout
-        self.paged = kv_layout == "paged"
-        if kv_dtype is not None and not self.paged:
+        if kv_dtype is not None and kv_layout != "paged":
             raise ValueError("kv_dtype requires kv_layout='paged'")
-        self._spec = draft is not None
+        self.kv_layout = kv_layout
         self.draft_k = 0
-        if self._spec:
-            self.draft_k = int(draft_k if draft_k is not None
-                               else _paging.draft_k_default())
+        if draft is not None:
+            self.draft_k = int(draft_k if draft_k is not None else 3)
             if self.draft_k < 1:
                 raise ValueError(f"draft_k must be >= 1, got "
                                  f"{self.draft_k}")
-
-        if self.paged:
-            self.page_size = int(page_size if page_size is not None
-                                 else _paging.page_size_default())
-            self.num_pages = int(
-                num_pages if num_pages is not None
-                else _paging.default_num_pages(self.slots, self.max_len,
-                                               self.page_size))
-            self.kv_dtype = kv_dtype if kv_dtype is not None \
-                else _paging.kv_dtype_default()
-            self._page_zero = _paging.page_zero_enabled()
-            self._alloc = _paging.PageAllocator(
-                self.num_pages, self.page_size, self.slots, self.max_len)
-            self._cache = model.init_paged_cache(
-                self.num_pages, self.page_size, self.slots, self._dtype,
-                kv_dtype=self.kv_dtype)
-            self._tick = build_paged_tick(model)
-            self._write = build_paged_write_slot()
-            self._reset = build_page_reset() if self._page_zero else None
+        if kv_layout == "paged":
+            page_size = int(page_size if page_size is not None else 16)
+            self._kv = paging.PagedCache(
+                self.slots, self.max_len, page_size,
+                int(num_pages if num_pages is not None
+                    else paging.default_num_pages(self.slots,
+                                                  self.max_len,
+                                                  page_size)),
+                kv_dtype, gauge=self.metrics.record_pages)
         else:
-            self.page_size = None
-            self.num_pages = 0
-            self.kv_dtype = None
-            self._page_zero = False
-            self._alloc = None
-            self._cache = model.init_cache(self.slots, self.max_len,
-                                           self._dtype)
-            self._tick = build_sampling_tick(model)
-            self._write = build_write_slot()
-        self._prefill = build_prefill(model, self.max_len, self._dtype)
-        self.prefill_chunk = int(prefill_chunk) if prefill_chunk \
-            else None
-        if self.prefill_chunk:
-            self._chunk_prog = build_prefill_chunk(model)
-        if self._spec:
-            dmodel, dvars = draft
-            self._draft_model = dmodel
-            self._draft_params = dvars["params"]
-            self._draft_state = dvars["state"]
-            self._ddtype = self._draft_params["embed"]["weight"].dtype \
-                if "embed" in self._draft_params else jnp.float32
+            self._kv = paging.DenseCache(self.slots, self.max_len)
+
+        def lane(prefix, model, variables, kv):
+            return _Lane(prefix, model, variables, kv, self.max_len,
+                         bool(self.prefill_chunk), self.metrics,
+                         self._tracer)
+
+        self._target = lane("decode", model, variables, self._kv)
+        self._target.programs["tick"] = self._kv.build_tick(model)
+        self._draft: Optional[_Lane] = None
+        self._round = self._tick_round
+        self._round_note = "ticks"  # the X-ray's count of a row's rounds
+        if draft is not None:
             # the draft's cache stays dense: it is small by construction
             # and its lengths self-heal from the host ledger each round
-            self._dcache = dmodel.init_cache(self.slots, self.max_len,
-                                             self._ddtype)
-            self._propose = build_draft_propose(dmodel, self.draft_k)
-            self._verify = build_spec_verify(model, self.draft_k,
-                                             paged=self.paged)
-            self._draft_prefill = build_prefill(dmodel, self.max_len,
-                                                self._ddtype)
-            self._draft_write = build_write_slot()
-            if self.prefill_chunk:
-                self._draft_chunk_prog = build_prefill_chunk(dmodel)
-        self._seen: set = set()  # our compiled-program keys (recompiles)
+            self._draft = lane("draft", draft[0], draft[1],
+                               paging.DenseCache(self.slots,
+                                                 self.max_len))
+            self._draft.programs["propose"] = build_draft_propose(
+                draft[0], self.draft_k)
+            self._target.programs["verify"] = self._kv.build_verify(
+                model, self.draft_k)
+            self._round = self._spec_round
+            self._round_note = "spec_rounds"
+        self._lanes = [ln for ln in (self._target, self._draft) if ln]
+        self._declared = self._declare()
         self._tick_cost = None  # ProgramCost, stamped before first tick
-        self._warming = False  # declared-grid compiles skip forensics
 
         self._tokens = np.zeros((self.slots,), np.int32)
         self._active = np.zeros((self.slots,), bool)
@@ -819,7 +417,6 @@ class DecodeEngine:
         self._chunk_pending: "collections.deque[_DecodeRequest]" = \
             collections.deque()
 
-        self._tracer = get_tracer()
         self._rids = itertools.count()
         self._tick_no = 0
         # request X-ray: exact per-request budget + p99 tail exemplars
@@ -846,326 +443,145 @@ class DecodeEngine:
             self.start()
 
     # ------------------------------------------------------------------
-    # compiled-program cache (the recompile counter lives here)
+    # the programs: one declaration, which warm-up runs
     # ------------------------------------------------------------------
     @property
     def recompiles(self) -> int:
         return self.metrics.recompiles
 
-    def _tracked(self, key, thunk, program=None, sig_fn=None, cost=None):
-        """Run ``thunk``; first sight of ``key`` is counted (and timed)
-        as a compile.  Params/state/dtype are fixed, so our key set is
-        exactly jit's cache key set and the counter is exact.
-
-        ``program``/``sig_fn`` feed the X-ray registry: the signature
-        must be fingerprinted *before* the thunk runs (ticks/writes
-        donate the cache buffers), and registration happens before
-        ``record_recompile`` so the forensic instant precedes the
-        recompile span the Watchdog pairs it with."""
-        if key in self._seen:
-            if program is not None:
-                programs.get_program_registry().record_call(program)
-            return thunk()
-        sig = None
-        if program is not None and sig_fn is not None:
-            try:
-                sig = sig_fn()
-            except Exception:
-                sig = None
-        t0 = time.perf_counter()
-        # which tick (ambient correlation) paid for which program
-        with self._tracer.span("compile", CAT_DECODE,
-                               args={"program": program or str(key[0])}):
-            out = thunk()
-        dt = time.perf_counter() - t0
-        if program is not None:
-            programs.get_program_registry().register_compile(
-                program, sig, compile_s=dt, cost=cost,
-                expected=self._warming)
-        self.metrics.record_recompile(dt)
-        self._seen.add(key)
-        return out
+    def _declare(self) -> List[Tuple[tuple, Callable[[dict], None]]]:
+        """Every program this engine compiles, in an order that can
+        run, as ``(key, thunk)``: the round's (the tick, or propose +
+        verify), then each lane's (:meth:`_Lane.declare`).  A thunk
+        takes the dict in which earlier thunks left what later ones
+        consume."""
+        if self._draft is None:
+            progs = [(("tick",), lambda held: self._run_tick())]
+        else:
+            progs = [(("propose",), lambda held: held.update(
+                         props=self._run_propose())),
+                     (("verify",), lambda held: self._run_verify(
+                         held.pop("props")))]
+        for lane in self._lanes:
+            progs += lane.declare(self.grid, self.prefill_chunk)
+        return progs
 
     def declared_programs(self) -> int:
-        """How many compiles a full warmup performs.  Base grid: one
-        prefill per declared (batch, prompt) bucket plus one slot write
-        per declared batch size; speculative engines compile a draft
-        prefill/write mirror of the grid and replace the tick with the
-        propose + verify pair; chunked prefill adds the chunk program
-        (and a batch-1 write when 1 is not a declared batch); paged
-        engines with page zeroing add the reset."""
-        grid = (len(self.grid.declared_buckets())
-                + len(self.grid.batch_sizes))
-        n = grid + (2 if self._spec else 1)
-        if self._spec:
-            n += grid
-        if self.prefill_chunk:
-            n += 2 if self._spec else 1
-            if 1 not in self.grid.batch_sizes:
-                n += 2 if self._spec else 1
-        if self.paged and self._page_zero:
-            n += 1
-        return n
+        """How many compiles a full warm-up performs."""
+        return len(self._declared)
 
     def warmup(self) -> int:
-        """Pre-compile every declared program (tick or propose/verify
-        pair, every prefill bucket, the slot writes, and the chunk/
-        reset variants when configured) so no request ever waits on
-        XLA; returns how many compiles ran (0 on a re-warm).  All
-        warmup executions are safe by the stale-above-length invariant:
-        caches are zero, ``active`` is all-False, and paged writes land
-        on the trash page."""
+        """Pre-compile every declared program so no request ever waits
+        on XLA; returns how many compiles ran (0 on a re-warm).  All
+        warm-up executions are safe by the stale-above-length
+        invariant: caches are zero, ``active`` is all-False, and paged
+        writes land on the trash page."""
         before = self.metrics.recompiles
-        self._warming = True
+        for lane in self._lanes:
+            lane.warming = True
         try:
             self._stamp_tick()
-            if self._spec:
-                props = self._run_propose()
-                self._run_verify(props)
-            else:
-                self._run_tick()
-            for bucket in self.grid.declared_buckets():
-                ids = np.zeros((bucket.batch,) + bucket.dims, np.int32)
-                lengths = np.ones((bucket.batch,), np.int32)
-                _, pcache = self._run_prefill(ids, lengths)
-                # the write's shape signature depends only on the batch
-                # bucket (prompt length never survives into cache
-                # shapes)
-                self._run_write(pcache, 0, 0, batch=bucket.batch)
-                if self._spec:
-                    _, dpcache = self._run_draft_prefill(ids, lengths)
-                    self._run_draft_write(dpcache, 0, 0,
-                                          batch=bucket.batch)
-            if self.prefill_chunk:
-                ids = np.zeros((1, self.prefill_chunk), np.int32)
-                adv = np.ones((1,), np.int32)
-                staging = self.model.init_cache(1, self.max_len,
-                                                self._dtype)
-                _, staging = self._run_chunk(staging, ids, adv)
-                if 1 not in self.grid.batch_sizes:
-                    self._run_write(staging, 0, 0, batch=1)
-                if self._spec:
-                    dstaging = self._draft_model.init_cache(
-                        1, self.max_len, self._ddtype)
-                    _, dstaging = self._run_draft_chunk(dstaging, ids,
-                                                        adv)
-                    if 1 not in self.grid.batch_sizes:
-                        self._run_draft_write(dstaging, 0, 0, batch=1)
-            if self.paged and self._page_zero:
-                self._run_page_reset([])
+            held: dict = {}
+            for _, thunk in self._declared:
+                thunk(held)
         finally:
-            self._warming = False
+            for lane in self._lanes:
+                lane.warming = False
         return self.metrics.recompiles - before
 
-    def _table(self) -> np.ndarray:
-        """The allocator's block table, passed into paged programs as a
-        plain device argument each call (values change, shape never)."""
-        return self._alloc.table
-
     def _tick_args(self):
-        base = (self.params, self.state, self._cache)
-        if self.paged:
-            base = base + (self._table(),)
-        return base + (self._tokens, self._active, self._keys,
-                       self._temps, self._topks, self._topps)
+        t = self._target
+        return (t.params, t.state, t.cache) + self._kv.tick_extra() + (
+            self._tokens, self._active, self._keys, self._temps,
+            self._topks, self._topps)
+
+    def _verify_args(self, props):
+        t = self._target
+        return (t.params, t.state, t.cache) + self._kv.tick_extra() + (
+            self._tokens, props, self._active)
 
     def _stamp_tick(self):
-        """Stamp the grid tick's flops/bytes (re-trace only).  Must run
-        while ``self._cache`` buffers are live — before a tick donates
-        them — so stamping happens at warmup/start, never in the loop.
+        """Stamp the round's flops/bytes (re-trace only).  Must run
+        while the cache buffers are live — before a round donates them
+        — so stamping happens at warmup/start, never in the loop.
         Speculative engines stamp the verify pass — the program that
         touches the full cache each round."""
         if self._tick_cost is not None:
             return
-        if self._spec:
-            draft = np.zeros((self.slots, self.draft_k), np.int32)
-            args = (self.params, self.state, self._cache)
-            if self.paged:
-                args = args + (self._table(),)
-            args = args + (self._tokens, draft, self._active)
-            cost = costmodel.stamp_jitted("spec_verify", self._verify,
-                                          *args)
+        if self._draft is None:
+            cost = costmodel.stamp_jitted(
+                "decode_tick", self._target.programs["tick"],
+                *self._tick_args())
         else:
-            cost = costmodel.stamp_jitted("decode_tick", self._tick,
-                                          *self._tick_args())
+            cost = costmodel.stamp_jitted(
+                "spec_verify", self._target.programs["verify"],
+                *self._verify_args(np.zeros((self.slots, self.draft_k),
+                                            np.int32)))
         if cost is not None:
             self._tick_cost = cost
 
-    def _pages_held(self):
-        """``loop/tick_dispatch``'s counter: the pages the slots hold
-        (allocator, host side) — the share of the ``S * M`` extent this
-        tick's attention has to read."""
-        return {"pages_held": self._alloc.pages_in_use} \
-            if self.paged else None
-
     def _run_tick(self):
-        def thunk():
-            # the paged tick also hands out the model's counters
-            cache, nxt, keys, *counters = self._tick(*self._tick_args())
-            self._cache = cache
-            return nxt, keys, counters[0] if counters else {}
-
+        """Dispatch the grid tick and wait for it; returns the ``(S,)``
+        next tokens (inactive rows hold theirs)."""
         # the tick's own predicate, known here without asking the
         # device: the rows whose sampling epilogue this tick runs
         sampled_rows = int(((self._temps > 0) & self._active).sum())
         if sampled_rows:
             self.metrics.inc_sampled_ticks()
-        args = dict(self._pages_held() or {}, sampled_rows=sampled_rows)
+        args = dict(self._kv.span_args() or {}, sampled_rows=sampled_rows)
+        t = self._target
         with self._tracer.span("loop/tick_dispatch", CAT_DECODE,
                                args=args):
-            out = self._tracked(
-                ("tick",), thunk, program="decode_tick",
-                sig_fn=lambda: programs.signature_of(
-                    {"params": self.params, "state": self.state,
-                     "cache": self._cache, "tokens": self._tokens,
-                     "active": self._active, "keys": self._keys,
-                     "temp": self._temps, "top_k": self._topks,
-                     "top_p": self._topps},
-                    donated=("cache",)),
+            # the paged tick also hands out the model's counters
+            t.cache, nxt, keys, *counters = t.call(
+                "tick", "decode_tick", *self._tick_args(),
                 cost=self._tick_cost)
         # the per-tick host sync point (writable copy: slots claimed
         # between ticks overwrite their token in place)
         with self._tracer.span("loop/tick_wait", CAT_DECODE):
-            nxt, keys, counters = out
-            if not self._tracer.enabled:
-                counters = {}  # nobody to read them: not fetched
-            # the model's counters come back in the tokens' own read
+            # the model's counters come back in the tokens' own read,
+            # and only while somebody reads them
+            counters = counters[0] if counters and self._tracer.enabled \
+                else {}
             nxt, keys, counters = jax.device_get((nxt, keys, counters))
             for name, value in counters.items():
                 args[name] = np.asarray(value).tolist()
             self._keys = np.array(keys)
             return np.array(nxt)
 
-    def _run_prefill(self, ids: np.ndarray, lengths: np.ndarray):
-        return self._tracked(
-            ("prefill", ids.shape),
-            lambda: self._prefill(self.params, self.state, ids, lengths),
-            program="decode_prefill",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self.params, "state": self.state,
-                 "ids": ids, "lengths": lengths}))
-
-    def _run_write(self, pcache, row: int, slot: int, batch: int):
-        if self.paged:
-            def thunk():
-                self._cache = self._write(
-                    self._cache, self._alloc.table[slot], pcache, row,
-                    slot)
-        else:
-            def thunk():
-                self._cache = self._write(self._cache, pcache, row, slot)
-
-        return self._tracked(
-            ("write", batch), thunk, program="decode_write_slot",
-            sig_fn=lambda: programs.signature_of(
-                {"cache": self._cache, "prefill_cache": pcache},
-                static={"batch": batch, "layout": self.kv_layout},
-                donated=("cache",)))
-
-    # -------------------------------------------------- paged/spec/chunk
-    def _run_page_reset(self, pages):
-        """Zero freed physical pages (hygiene knob, fixed arg shape:
-        the page-id vector is padded with trash-page zeros)."""
-        arr = np.zeros((self._alloc.pages_per_slot,), np.int32)
-        ids = np.asarray(pages, np.int32)[:arr.size]
-        arr[:ids.size] = ids
-
-        def thunk():
-            self._cache = self._reset(self._cache, arr)
-
-        return self._tracked(
-            ("page_reset",), thunk, program="page_reset",
-            sig_fn=lambda: programs.signature_of(
-                {"cache": self._cache, "pages": arr},
-                donated=("cache",)))
-
-    def _run_chunk(self, staging, ids: np.ndarray, adv: np.ndarray):
-        def thunk():
-            last, cache = self._chunk_prog(self.params, self.state,
-                                           staging, ids, adv)
-            return np.asarray(last), cache
-
-        return self._tracked(
-            ("chunk",), thunk, program="decode_prefill_chunk",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self.params, "state": self.state,
-                 "cache": staging, "ids": ids, "advance": adv},
-                donated=("cache",)))
-
-    def _run_draft_prefill(self, ids: np.ndarray, lengths: np.ndarray):
-        return self._tracked(
-            ("dprefill", ids.shape),
-            lambda: self._draft_prefill(self._draft_params,
-                                        self._draft_state, ids, lengths),
-            program="draft_prefill",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self._draft_params, "ids": ids,
-                 "lengths": lengths}))
-
-    def _run_draft_write(self, dpcache, row: int, slot: int, batch: int):
-        def thunk():
-            self._dcache = self._draft_write(self._dcache, dpcache, row,
-                                             slot)
-
-        return self._tracked(
-            ("dwrite", batch), thunk, program="draft_write_slot",
-            sig_fn=lambda: programs.signature_of(
-                {"cache": self._dcache, "prefill_cache": dpcache},
-                static={"batch": batch}, donated=("cache",)))
-
-    def _run_draft_chunk(self, dstaging, ids: np.ndarray,
-                         adv: np.ndarray):
-        def thunk():
-            last, cache = self._draft_chunk_prog(
-                self._draft_params, self._draft_state, dstaging, ids,
-                adv)
-            return np.asarray(last), cache
-
-        return self._tracked(
-            ("dchunk",), thunk, program="draft_prefill_chunk",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self._draft_params, "cache": dstaging,
-                 "ids": ids, "advance": adv},
-                donated=("cache",)))
-
     def _run_propose(self):
-        def thunk():
-            dcache, props = self._propose(
-                self._draft_params, self._draft_state, self._dcache,
-                self._tokens, self._host_len, self._active)
-            self._dcache = dcache
-            return props  # stays on device: the verify consumes it
-
-        return self._tracked(
-            ("propose",), thunk, program="draft_propose",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self._draft_params, "cache": self._dcache,
-                 "tokens": self._tokens, "lengths": self._host_len,
-                 "active": self._active},
-                donated=("cache",)))
+        d = self._draft
+        d.cache, props = d.call(
+            "propose", "draft_propose", d.params, d.state, d.cache,
+            self._tokens, self._host_len, self._active)
+        return props  # stays on device: the verify consumes it
 
     def _run_verify(self, props):
         """Dispatch the verify pass; returns the device ``(emitted,
         n_emit)`` — fetching them is the round's single host sync."""
-        def thunk():
-            args = (self.params, self.state, self._cache)
-            if self.paged:
-                args = args + (self._table(),)
-            args = args + (self._tokens, props, self._active)
-            cache, emitted, n_emit = self._verify(*args)
-            self._cache = cache
-            return emitted, n_emit
-
-        return self._tracked(
-            ("verify",), thunk, program="spec_verify",
-            sig_fn=lambda: programs.signature_of(
-                {"params": self.params, "state": self.state,
-                 "cache": self._cache, "tokens": self._tokens,
-                 "active": self._active},
-                static={"draft_k": self.draft_k,
-                        "layout": self.kv_layout},
-                donated=("cache",)),
+        t = self._target
+        t.cache, emitted, n_emit = t.call(
+            "verify", "spec_verify", *self._verify_args(props),
             cost=self._tick_cost)
+        return emitted, n_emit
+
+    # ------------------------------------------------------------------
+    # rounds: what one loop turn dispatches.  Each hands back
+    # ``(emitted (S, K), n_emit (S,))`` on the host
+    # ------------------------------------------------------------------
+    def _tick_round(self):
+        """K = 1: every active row emits the tick's one token."""
+        return self._run_tick()[:, None], self._active
+
+    def _spec_round(self):
+        """K = ``draft_k`` + 1: the accepted prefix of the draft's
+        proposals and the big model's bonus token."""
+        with self._tracer.span("loop/tick_dispatch", CAT_DECODE,
+                               args=self._kv.span_args()):
+            out = self._run_verify(self._run_propose())
+        with self._tracer.span("loop/tick_wait", CAT_DECODE):
+            emitted, n_emit = jax.device_get(out)
+        return np.asarray(emitted), np.asarray(n_emit)
 
     # ------------------------------------------------------------------
     # client API
@@ -1197,7 +613,7 @@ class DecodeEngine:
         temperature = float(temperature)
         top_k = int(top_k)
         top_p = float(top_p)
-        if temperature > 0.0 and self._spec:
+        if temperature > 0.0 and self._draft is not None:
             raise ValueError(
                 "speculative decoding is greedy-only: the verify pass "
                 "accepts draft tokens by argmax match, which sampling "
@@ -1206,23 +622,15 @@ class DecodeEngine:
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         # speculative rounds may write up to draft_k tokens past the
         # last emitted position before rollback — reserve the slack
-        slack = self.draft_k if self._spec else 0
-        if prompt.size + max_new_tokens - 1 + slack > self.max_len:
+        slack = self.draft_k
+        worst = int(prompt.size) + max_new_tokens - 1 + slack
+        if worst > self.max_len:
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new_tokens "
                 f"({max_new_tokens}) - 1"
                 + (f" + draft_k ({slack})" if slack else "")
                 + f" exceeds the cache max_len ({self.max_len})")
-        if self.paged:
-            from bigdl_tpu.serving.paging import OutOfPagesError
-            worst = int(prompt.size) + max_new_tokens - 1 + slack
-            pages = min(-(-worst // self.page_size),
-                        self._alloc.pages_per_slot)
-            if pages > self.num_pages - 1:
-                raise OutOfPagesError(
-                    f"request needs {pages} pages at its longest but "
-                    f"the pool only has {self.num_pages - 1} usable "
-                    f"pages of {self.page_size} tokens")
+        self._kv.check_servable(worst)
         fut = ServingFuture()
         now = time.perf_counter()
         dl = deadline_ms if deadline_ms is not None \
@@ -1294,27 +702,22 @@ class DecodeEngine:
                 flight.add_metrics("decode", lambda: self.metrics)
                 flight.add_blob("exemplars-decode",
                                 self.exemplars.as_blob)
-            # HbmLedger resident lane: the paged engine reports bytes
+            # HbmLedger resident lane: a paged cache reports bytes
             # proportional to pages actually in use — the readout that
-            # retirement frees memory — while the dense engine reports
-            # its fixed worst-case reservation for comparison
-            ledger = programs.get_hbm_ledger()
-            if self.paged:
-                per_page = self._page_bytes_total()
-                self._resident_name = "decode_kv_pages"
-                ledger.add_resident(
-                    self._resident_name,
-                    lambda: self._alloc.pages_in_use * per_page)
-            else:
-                total = self._cache_bytes_total()
-                self._resident_name = "decode_kv_cache"
-                ledger.add_resident(self._resident_name, lambda: total)
+            # retirement frees memory — a dense one its fixed
+            # worst-case reservation
+            self._resident_name = self._kv.resident_name
+            programs.get_hbm_ledger().add_resident(
+                self._resident_name, self._kv.resident_bytes)
 
     def close(self, drain: bool = True, timeout: float = 60.0):
         """Stop accepting requests and shut down.  ``drain=True``
         (default) decodes everything already queued/in flight to
         completion first; ``drain=False`` fails undelivered requests
-        with :class:`EngineClosedError`.  Idempotent."""
+        with :class:`EngineClosedError`.  Once the loop thread has
+        ended the lanes let go of parameters, caches and compiled
+        programs, so device memory is free when this returns and not
+        when a collector gets to the engine.  Idempotent."""
         with self._close_lock:
             already = self._closed
             self._closed = True
@@ -1328,12 +731,17 @@ class DecodeEngine:
             programs.get_hbm_ledger().remove_resident(name)
         self._periodic.close()
         self._discard = not drain
-        if not self._started:
+        if self._started:
+            self._rq.put(_CLOSE)
+            self._loop_thread.join(timeout)
+            if self._loop_thread.is_alive():
+                return  # still decoding: the lanes are the loop's
+        else:
             self._fail_queued(EngineClosedError(
                 "decode engine closed before start"))
-            return
-        self._rq.put(_CLOSE)
-        self._loop_thread.join(timeout)
+        self._chunking = None
+        for lane in self._lanes:
+            lane.drop()
 
     def _fail_queued(self, exc):
         while True:
@@ -1344,14 +752,11 @@ class DecodeEngine:
             if req is not _CLOSE:
                 self.xray.drop(req.rid)
                 req.fut.set_exception(exc)
-        while self._pending:
-            req = self._pending.popleft()
-            self.xray.drop(req.rid)
-            req.fut.set_exception(exc)
-        while self._chunk_pending:
-            req = self._chunk_pending.popleft()
-            self.xray.drop(req.rid)
-            req.fut.set_exception(exc)
+        for waiting in (self._pending, self._chunk_pending):
+            while waiting:
+                req = waiting.popleft()
+                self.xray.drop(req.rid)
+                req.fut.set_exception(exc)
         if self._chunking is not None:
             self.xray.drop(self._chunking["req"].rid)
             self._chunking["req"].fut.set_exception(exc)
@@ -1364,10 +769,15 @@ class DecodeEngine:
         self.close()
 
     # ------------------------------------------------------------------
-    # engine loop: admit (prefill into free slots) then tick the grid
+    # engine loop: admit (prefill into free slots) then run a round
     # ------------------------------------------------------------------
+    def _idle(self) -> bool:
+        return (not np.any(self._active) and not self._pending
+                and self._chunking is None and not self._chunk_pending
+                and all(st is None for st in self._slot_state))
+
     def _loop(self):
-        """One turn: drain, admit, chunk, budget, tick, retire — each a
+        """One turn: drain, admit, chunk, budget, round, retire — each a
         top-level ``loop/*`` span, so together they tile the thread's
         time while the tracer is on (docs/observability.md)."""
         tr = self._tracer
@@ -1378,13 +788,8 @@ class DecodeEngine:
                 # the index of the tick the turn runs
                 set_correlation(f"tick:{self._tick_no + 1}")
             with tr.span("loop/drain_queue", CAT_DECODE):
-                stopping = self._drain_queue(
-                    block=(not np.any(self._active) and not self._pending
-                           and self._chunking is None
-                           and not self._chunk_pending
-                           and all(st is None
-                                   for st in self._slot_state)),
-                    stopping=stopping)
+                stopping = self._drain_queue(block=self._idle(),
+                                             stopping=stopping)
             if stopping and self._discard:
                 self._fail_queued(EngineClosedError(
                     "decode engine closed"))
@@ -1402,36 +807,41 @@ class DecodeEngine:
             args = {}
             with tr.span("loop/chunk_step", CAT_DECODE, args=args):
                 args["tokens"] = self._chunk_step()
-            if self.paged:
-                # fund (and resume) occupied slots before the tick —
-                # must run even when everything is paused
-                with tr.span("loop/budget_pages", CAT_DECODE):
-                    self._budget_pages()
+            # fund (and resume) occupied slots before the round — must
+            # run even when everything is paused
+            with tr.span("loop/budget_pages", CAT_DECODE):
+                self._budget_pages()
             if not np.any(self._active):
-                if stopping and not self._pending \
-                        and self._chunking is None \
-                        and not self._chunk_pending \
-                        and all(st is None for st in self._slot_state):
+                if stopping and self._idle():
                     return
                 continue
             self._tick_no += 1
-            if self._spec:
-                self._spec_round()
-                continue
             t0 = time.perf_counter()
+            spec_rids: Sequence[int] = ()
+            if self._draft is not None and self.xray.enabled:
+                # the draft + verify round itself is the spec_verify
+                # budget; the gaps between rounds stay on the resident
+                # lane
+                spec_rids = [self._slot_state[s].req.rid
+                             for s in range(self.slots)
+                             if self._active[s]
+                             and self._slot_state[s] is not None]
+                self.xray.to_many(spec_rids, request_xray.PHASE_SPEC,
+                                  now=t0)
             with StepTraceAnnotation("decode_tick",
                                      step_num=self._tick_no):
-                nxt = self._run_tick()
+                emitted, n_emit = self._round()
             now = time.perf_counter()
             args = {}
             with tr.span("loop/retire", CAT_DECODE, args=args):
                 self.metrics.record_tick(now - t0)
-                self._tokens = nxt
+                if spec_rids:
+                    self.xray.to_many(spec_rids,
+                                      request_xray.PHASE_RESIDENT, now=now)
                 n_active = int(self._active.sum())
-                self.metrics.record_decode_tokens(n_active)
                 self.metrics.record_slot_occupancy(n_active / self.slots)
-                self._host_len[self._active] += 1
-                gaps = self._retire(nxt, now)
+                gaps = self._retire(emitted, n_emit, now)
+                self.metrics.record_decode_tokens(len(gaps))
                 args["active"] = n_active
                 args["gaps_ms"] = [1e3 * g for g in gaps]
 
@@ -1454,6 +864,20 @@ class DecodeEngine:
         reserved = self._chunking["slot"] if self._chunking else -1
         return [s for s in range(self.slots)
                 if not self._active[s] and s != reserved]
+
+    def _expired(self, req: _DecodeRequest, now: float,
+                 where: str) -> bool:
+        """Fail ``req`` fast when its deadline passed before decoding
+        started (nothing of it is in the grid cache yet)."""
+        if req.deadline is None or now <= req.deadline:
+            return False
+        self.metrics.inc_expired()
+        self._tracer.instant("deadline_reject", CAT_DECODE,
+                             corr=f"req:{req.rid}")
+        req.fut.set_exception(DeadlineExceededError(
+            f"deadline expired {1e3 * (now - req.deadline):.1f}ms "
+            f"{where}", attribution=self.xray.close(req.rid, now=now)))
+        return True
 
     def _admit(self) -> int:
         """Prefill waiting requests into free slots; returns how many
@@ -1481,36 +905,20 @@ class DecodeEngine:
         taken: List[_DecodeRequest] = []
         while self._pending and len(taken) < len(free):
             req = self._pending.popleft()
-            if req.deadline is not None and now > req.deadline:
-                self.metrics.inc_expired()
-                self._tracer.instant("deadline_reject", CAT_DECODE,
-                                     corr=f"req:{req.rid}")
-                req.fut.set_exception(DeadlineExceededError(
-                    f"deadline expired "
-                    f"{1e3 * (now - req.deadline):.1f}ms before "
-                    "prefill",
-                    attribution=self.xray.close(req.rid, now=now)))
-                continue
-            taken.append(req)
-        if self.paged and taken:
-            # admission never evicts (an evicted request re-queues and
-            # could evict its evictor right back — livelock): requests
-            # whose prompt does not fit the current free list wait
-            # until retirement frees pages
-            fits: List[_DecodeRequest] = []
-            free_pages = self._alloc.pages_free
-            for i, req in enumerate(taken):
-                need = min(-(-(int(req.prompt.size) + self._page_slack())
-                             // self.page_size),
-                           self._alloc.pages_per_slot)
-                if need > free_pages:
-                    self._pending.extendleft(reversed(taken[i:]))
-                    break
-                free_pages -= need
-                fits.append(req)
-            taken = fits
-        if not taken:
-            return 0
+            if not self._expired(req, now, "before prefill"):
+                taken.append(req)
+        # admission never evicts (an evicted request re-queues and
+        # could evict its evictor right back — livelock): a request
+        # whose prompt the cache manager has no room for waits, and
+        # those behind it, until retirement frees some
+        room = self._kv.pages_free
+        for i, req in enumerate(taken):
+            room -= self._kv.pages_for(int(req.prompt.size)
+                                       + self._page_slack())
+            if room < 0:
+                self._pending.extendleft(reversed(taken[i:]))
+                del taken[i:]
+                break
         admitted = 0
         groups: dict = {}
         for r in taken:
@@ -1543,45 +951,45 @@ class DecodeEngine:
                                       np.int32)
             lengths = np.ones((b,), np.int32)
             lengths[:len(chunk)] = [r.prompt.size for r in chunk]
-            logits, pcache = self._run_prefill(ids, lengths)
+            logits, pcache = self._target.prefill(ids, lengths)
         with tr.span("prefill_wait", CAT_DECODE):
             logits = np.asarray(logits)
-        dpcache = None
-        if self._spec:
-            _, dpcache = self._run_draft_prefill(ids, lengths)
+        # one prefilled batch per lane, the target's first
+        pcaches = [pcache] + [lane.prefill(ids, lengths)[1]
+                              for lane in self._lanes[1:]]
         admitted = 0
         for i, r in enumerate(chunk):
             self.xray.to(r.rid, request_xray.PHASE_SAMPLE)
             with tr.span("host_sample", CAT_DECODE, corr=f"req:{r.rid}"):
                 tok0 = _host_sample(logits[i], r)
             t_tok = time.perf_counter()
-            done = ((self.eos_id is not None and tok0 == self.eos_id)
-                    or r.max_new <= 1)
-            if done:
-                self._finish(r, [tok0], [t_tok],
-                             "eos" if (self.eos_id is not None
-                                       and tok0 == self.eos_id)
-                             else "length")
+            if self._done_at_first(r, tok0, t_tok):
                 continue
             slot = next(free_iter)
-            if self.paged and not self._alloc.ensure(
-                    slot, int(r.prompt.size) + self._page_slack()):
-                # admission pre-filter reserved these pages; losing the
-                # race is unexpected but recoverable — wait, don't evict
+            if not self._kv.reserve(slot, int(r.prompt.size)
+                                    + self._page_slack()):
+                # admission counted this room in; losing it is
+                # unexpected but recoverable — wait, don't evict
                 self.xray.to(r.rid, request_xray.PHASE_PAGE_STALL)
                 self._pending.appendleft(r)
                 continue
-            if self.paged:
-                self.metrics.record_pages(self._alloc.pages_in_use)
             # dispatched without waiting: the write's device time is
             # paid inside the next tick's token fetch
             with tr.span("slot_write", CAT_DECODE, corr=f"req:{r.rid}"):
-                self._run_write(pcache, i, slot, batch=b)
-                if self._spec:
-                    self._run_draft_write(dpcache, i, slot, batch=b)
+                for lane, pc in zip(self._lanes, pcaches):
+                    lane.write(pc, i, slot, batch=b)
             self._activate(slot, r, tok0, t_tok)
             admitted += 1
         return admitted
+
+    def _done_at_first(self, req: _DecodeRequest, tok0: int,
+                       t_tok: float) -> bool:
+        """Deliver a request its prefill token already finishes."""
+        eos = self.eos_id is not None and tok0 == self.eos_id
+        if not eos and req.max_new > 1:
+            return False
+        self._finish(req, [tok0], [t_tok], "eos" if eos else "length")
+        return True
 
     def _activate(self, slot: int, req: _DecodeRequest, tok0: int,
                   t_tok: float):
@@ -1614,26 +1022,13 @@ class DecodeEngine:
             if free:
                 req = self._chunk_pending.popleft()
                 now = time.perf_counter()
-                if req.deadline is not None and now > req.deadline:
-                    self.metrics.inc_expired()
-                    self._tracer.instant("deadline_reject", CAT_DECODE,
-                                         corr=f"req:{req.rid}")
-                    req.fut.set_exception(DeadlineExceededError(
-                        f"deadline expired "
-                        f"{1e3 * (now - req.deadline):.1f}ms before "
-                        "prefill",
-                        attribution=self.xray.close(req.rid, now=now)))
+                if self._expired(req, now, "before prefill"):
                     return 0
                 self.xray.to(req.rid, request_xray.PHASE_PREFILL,
                              now=now)
                 self._chunking = {
                     "req": req, "slot": free[0], "offset": 0,
-                    "staging": self.model.init_cache(
-                        1, self.max_len, self._dtype),
-                    "dstaging": self._draft_model.init_cache(
-                        1, self.max_len, self._ddtype)
-                    if self._spec else None,
-                }
+                    "staging": [lane.staging() for lane in self._lanes]}
         c = self._chunking
         if c is None:
             return 0
@@ -1643,16 +1038,10 @@ class DecodeEngine:
             self._finalize_chunk(c)
             return 0
         req = c["req"]
-        now = time.perf_counter()
-        if req.deadline is not None and now > req.deadline:
-            # nothing reached the grid cache yet: fail fast, slot stays
-            # clean
-            self._chunking = None
-            self.metrics.inc_expired()
-            req.fut.set_exception(DeadlineExceededError(
-                "deadline expired mid chunked prefill "
-                f"({c['offset']}/{req.prompt.size} tokens in)",
-                attribution=self.xray.close(req.rid, now=now)))
+        if self._expired(req, time.perf_counter(),
+                         f"into a chunked prefill ({c['offset']}/"
+                         f"{req.prompt.size} tokens in)"):
+            self._chunking = None  # the slot stays clean
             return 0
         t0 = time.perf_counter()
         size = self.prefill_chunk
@@ -1661,10 +1050,10 @@ class DecodeEngine:
         ids = np.zeros((1, size), np.int32)
         ids[0, :hi - lo] = req.prompt[lo:hi]
         adv = np.array([hi - lo], np.int32)
-        last, c["staging"] = self._run_chunk(c["staging"], ids, adv)
-        if self._spec:
-            _, c["dstaging"] = self._run_draft_chunk(c["dstaging"], ids,
-                                                     adv)
+        lasts = []
+        for i, lane in enumerate(self._lanes):
+            last, c["staging"][i] = lane.chunk(c["staging"][i], ids, adv)
+            lasts.append(last)
         self.metrics.inc_prefill_chunks()
         self.xray.note(req.rid, "prefill_chunks")
         self.metrics.record_prefill(time.perf_counter() - t0)
@@ -1675,15 +1064,10 @@ class DecodeEngine:
         if hi < req.prompt.size:
             return hi - lo  # more chunks on later loop iterations
         self.xray.to(req.rid, request_xray.PHASE_SAMPLE)
-        tok0 = _host_sample(last[0], req)
+        tok0 = _host_sample(lasts[0][0], req)
         c["t_tok"] = time.perf_counter()
-        if (self.eos_id is not None and tok0 == self.eos_id) \
-                or req.max_new <= 1:
+        if self._done_at_first(req, tok0, c["t_tok"]):
             self._chunking = None
-            self._finish(req, [tok0], [c["t_tok"]],
-                         "eos" if (self.eos_id is not None
-                                   and tok0 == self.eos_id)
-                         else "length")
             return hi - lo
         c["tok0"] = tok0
         self._finalize_chunk(c)
@@ -1691,36 +1075,33 @@ class DecodeEngine:
 
     def _finalize_chunk(self, c: dict):
         """Splice a fully chunk-prefilled request into its reserved
-        slot — deferred while the page pool is full (admission never
-        evicts; see :meth:`_ensure_pages`)."""
+        slot — deferred while the cache manager has no room (admission
+        never evicts; see :meth:`_ensure_pages`)."""
         req, slot = c["req"], c["slot"]
-        if self.paged and not self._alloc.ensure(
-                slot, int(req.prompt.size) + self._page_slack()):
+        if not self._kv.reserve(slot, int(req.prompt.size)
+                                + self._page_slack()):
             self.xray.to(req.rid, request_xray.PHASE_PAGE_STALL)
             return  # retry next loop iteration
-        if self.paged:
-            self.metrics.record_pages(self._alloc.pages_in_use)
         self._chunking = None
-        self._run_write(c["staging"], 0, slot, batch=1)
-        if self._spec:
-            self._run_draft_write(c["dstaging"], 0, slot, batch=1)
+        for lane, staging in zip(self._lanes, c["staging"]):
+            lane.write(staging, 0, slot, batch=1)
         self._activate(slot, req, c["tok0"], c["t_tok"])
 
     # ------------------------------------------------------------------
-    # paged-pool budgeting
+    # the page policy, against the cache manager's reserve / release
     # ------------------------------------------------------------------
     def _page_slack(self) -> int:
         """Tokens a slot may write beyond its current valid length in
         one round: the next tick's token, plus the speculative write-
         ahead window."""
-        return 1 + (self.draft_k if self._spec else 0)
+        return 1 + self.draft_k
 
     def _budget_pages(self):
-        """Before each tick, fund every occupied slot with pages for
+        """Before each round, fund every occupied slot with room for
         the tokens this round can write — oldest request first.  A slot
-        the free list cannot fund may evict strictly *younger* requests
-        (they re-queue and re-decode deterministically); with no
-        younger donor it is *paused* — deactivated but keeping its
+        the cache manager cannot fund may evict strictly *younger*
+        requests (they re-queue and re-decode deterministically); with
+        no younger donor it is *paused* — deactivated but keeping its
         pages and generated state — and resumes once retirement frees
         pages.  The oldest occupied slot can always be funded (submit
         guarantees every request fits an empty pool), so at least one
@@ -1750,12 +1131,12 @@ class DecodeEngine:
                 self._active[s] = False
 
     def _ensure_pages(self, slot: int, tokens: int) -> bool:
-        """Grow ``slot`` to cover ``tokens``; when the free list runs
-        short, evict the youngest occupied slot whose request is newer
+        """Grow ``slot`` to cover ``tokens``; when the manager has no
+        room, evict the youngest occupied slot whose request is newer
         than this slot's.  Returns False when no such donor exists."""
         me = self._slot_state[slot].req.rid \
             if self._slot_state[slot] is not None else -1
-        while not self._alloc.ensure(slot, tokens):
+        while not self._kv.reserve(slot, tokens):
             victim, rid = None, me
             for s in range(self.slots):
                 if s == slot or self._slot_state[s] is None:
@@ -1766,7 +1147,6 @@ class DecodeEngine:
             if victim is None:
                 return False
             self._evict(victim)
-        self.metrics.record_pages(self._alloc.pages_in_use)
         return True
 
     def _evict(self, victim: int):
@@ -1774,7 +1154,7 @@ class DecodeEngine:
         self.metrics.inc_page_evictions()
         self._tracer.instant("page_evict", CAT_DECODE,
                              args={"slot": victim,
-                                   "pages": self._alloc.owned(victim)})
+                                   "pages": self._kv.owned(victim)})
         if st is not None:
             # deterministic restart: greedy/seeded sampling re-decodes
             # to the same tokens, so eviction costs latency, not output
@@ -1785,121 +1165,47 @@ class DecodeEngine:
         self._free(victim)
 
     # ------------------------------------------------------------------
-    # speculative rounds (replace the tick when a draft is configured)
+    # retirement: one path for every round
     # ------------------------------------------------------------------
-    def _spec_round(self):
-        tr = self._tracer
-        t0 = time.perf_counter()
-        spec_rids: Sequence[int] = ()
-        if self.xray.enabled:
-            spec_rids = [self._slot_state[s].req.rid
-                         for s in range(self.slots)
-                         if self._active[s]
-                         and self._slot_state[s] is not None]
-            self.xray.to_many(spec_rids, request_xray.PHASE_SPEC,
-                              now=t0)
-        with StepTraceAnnotation("decode_tick", step_num=self._tick_no):
-            with tr.span("loop/tick_dispatch", CAT_DECODE,
-                         args=self._pages_held()):
-                out = self._run_verify(self._run_propose())
-            with tr.span("loop/tick_wait", CAT_DECODE):
-                emitted, n_emit = jax.device_get(out)
-        t1 = time.perf_counter()
-        args = {}
-        with tr.span("loop/retire", CAT_DECODE, args=args):
-            self.metrics.record_tick(t1 - t0)
-            # the draft+verify round itself is the spec_verify budget;
-            # the gaps between rounds stay on the resident lane
-            self.xray.to_many(spec_rids, request_xray.PHASE_RESIDENT,
-                              now=t1)
-            emitted = np.asarray(emitted)
-            n_emit = np.asarray(n_emit)
-            n_active = int(self._active.sum())
-            self.metrics.record_slot_occupancy(n_active / self.slots)
-            n_tok = 0
-            gaps: List[float] = []
-            for s in range(self.slots):
-                if not self._active[s]:
-                    continue
-                n = int(n_emit[s])  # accepted prefix + the bonus token
-                self.metrics.record_spec(self.draft_k, n - 1)
-                self.xray.note(self._slot_state[s].req.rid,
-                               "spec_rounds")
-                self._host_len[s] += n
-                self._tokens[s] = int(emitted[s, n - 1])
-                st = self._slot_state[s]
-                req = st.req
-                finished = None
-                for j in range(n):
-                    tok = int(emitted[s, j])
-                    st.generated.append(tok)
-                    # a round's tokens all arrive with its fetch
-                    gaps.append(t1 - st.times[-1])
-                    st.times.append(t1)
-                    n_tok += 1
-                    if self.eos_id is not None and tok == self.eos_id:
-                        finished = "eos"
-                        break
-                    if len(st.generated) >= req.max_new:
-                        finished = "length"
-                        break
-                if finished is None and req.deadline is not None \
-                        and t1 > req.deadline:
-                    finished = "deadline"
-                if finished is not None:
-                    self._finish(req, st.generated, st.times, finished)
-                    self._free(s)
-            self.metrics.record_decode_tokens(n_tok)
-            for g in gaps:
-                self.metrics.record_token_gap(g)
-            args["active"] = n_active
-            args["gaps_ms"] = [1e3 * g for g in gaps]
-
-    # ------------------------------------------------------------------
-    # resident-bytes accounting for the HbmLedger lane
-    # ------------------------------------------------------------------
-    def _page_bytes_total(self) -> int:
-        """Bytes one physical page costs across every layer's pool
-        (K + V + scales)."""
-        total = 0
-        for pool in self._cache.values():
-            for name, leaf in pool.items():
-                if name == "length":
-                    continue
-                total += int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
-        return total
-
-    def _cache_bytes_total(self) -> int:
-        """The dense cache's fixed worst-case reservation."""
-        import jax
-
-        return sum(leaf.size * leaf.dtype.itemsize
-                   for leaf in jax.tree_util.tree_leaves(self._cache))
-
-    def _retire(self, nxt: np.ndarray, now: float) -> List[float]:
-        """Hand each active slot its token (fetched at ``now``) and
-        retire the finished; returns the slots' token gaps (seconds)."""
-        gaps = []
-        for s in range(self.slots):
-            if not self._active[s]:
+    def _retire(self, emitted: np.ndarray, n_emit: np.ndarray,
+                now: float) -> List[float]:
+        """Hand row ``s`` the first ``n_emit[s]`` tokens of
+        ``emitted[s]`` (all fetched at ``now``: a round's tokens arrive
+        with its one read) and retire the finished; a row that emitted
+        nothing is not touched.  Tokens past an end of sequence or the
+        request's budget are dropped.  Returns the token gaps
+        (seconds), in slot order."""
+        proposed = emitted.shape[1] - 1  # what a draft put forward
+        gaps: List[float] = []
+        for s, n in enumerate(n_emit.tolist()):
+            if not n:
                 continue
             st = self._slot_state[s]
-            st.generated.append(int(nxt[s]))
-            gaps.append(now - st.times[-1])
-            st.times.append(now)
-            self.metrics.record_token_gap(gaps[-1])
-            self.xray.note(st.req.rid, "ticks")
             req = st.req
-            if self.eos_id is not None and int(nxt[s]) == self.eos_id:
-                self._finish(req, st.generated, st.times, "eos")
-            elif len(st.generated) >= req.max_new:
-                self._finish(req, st.generated, st.times, "length")
-            elif req.deadline is not None and now > req.deadline:
+            if proposed:
+                self.metrics.record_spec(proposed, n - 1)
+            self._host_len[s] += n
+            self._tokens[s] = emitted[s, n - 1]
+            reason = None
+            for tok in emitted[s, :n].tolist():
+                st.generated.append(tok)
+                gaps.append(now - st.times[-1])
+                st.times.append(now)
+                self.metrics.record_token_gap(gaps[-1])
+                if self.eos_id is not None and tok == self.eos_id:
+                    reason = "eos"
+                    break
+                if len(st.generated) >= req.max_new:
+                    reason = "length"
+                    break
+            self.xray.note(req.rid, self._round_note)
+            if reason is None and req.deadline is not None \
+                    and now > req.deadline:
                 # decoding already started: truncate, don't fail
-                self._finish(req, st.generated, st.times, "deadline")
-            else:
-                continue
-            self._free(s)
+                reason = "deadline"
+            if reason is not None:
+                self._finish(req, st.generated, st.times, reason)
+                self._free(s)
         return gaps
 
     def _finish(self, req: _DecodeRequest, tokens: List[int],
@@ -1926,11 +1232,7 @@ class DecodeEngine:
         self._topks[slot] = 0
         self._topps[slot] = 1.0
         self._host_len[slot] = 0
-        if self.paged:
-            freed = self._alloc.release(slot)
-            if freed and self._page_zero:
-                self._run_page_reset(freed)
-            self.metrics.record_pages(self._alloc.pages_in_use)
+        self._kv.release(slot)
         self._tracer.instant("slot_free", CAT_DECODE,
                              args={"slot": slot})
 

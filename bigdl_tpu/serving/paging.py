@@ -1,45 +1,30 @@
-"""Host-side page bookkeeping for the paged KV cache.
+"""The decode engine's cache managers and the host-side page bookkeeping.
+
+A cache manager owns the *layout* of the K/V state: it builds the cache
+and the programs that depend on the layout (tick, slot write, verify),
+supplies the extra argument those programs take (the block table), and
+answers the scheduler's questions about room (``reserve`` / ``release``
+/ ``pages_for`` / ``pages_free``), bytes (``resident_bytes``, the
+HbmLedger lane) and what a trace should see (``span_args``).  The
+scheduler's page *policy* (serving/decode.py: oldest first, evict
+younger, pause) runs against this interface and never asks which
+layout it has: on :class:`DenseCache` ``reserve`` is always true.
 
 The compiled tick only ever sees a block table (an (S, M) int32 device
 argument) and the page pool (docs/decoding.md §Paged KV cache;
 ops/paged_kv.py for the array ops).  Everything stateful — the free
-list, which slot owns which physical page, eviction — lives here on
-the host, in plain Python, under the engine loop's single thread.
-
-Knobs (docs/observability.md):
-
-* ``BIGDL_TPU_KV_PAGE``  — tokens per page (default 16);
-* ``BIGDL_TPU_KV_DTYPE`` — ``int8`` quantizes the pool (default: the
-  model compute dtype);
-* ``BIGDL_TPU_DRAFT_K``  — speculative draft length (default 3);
-* ``BIGDL_TPU_PAGE_ZERO`` — 1 zeroes pages on free through the
-  compiled ``page_reset`` program (hygiene for debugging; correctness
-  never needs it — the stale-above-length invariant masks old bytes).
+list, which slot owns which physical page — lives in
+:class:`PageAllocator`, in plain Python, under the engine loop's single
+thread.
 """
 from __future__ import annotations
 
-import os
 from collections import deque
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 import numpy as np
 
-
-def page_size_default() -> int:
-    return int(os.environ.get("BIGDL_TPU_KV_PAGE", "16"))
-
-
-def kv_dtype_default() -> Optional[str]:
-    v = os.environ.get("BIGDL_TPU_KV_DTYPE", "").strip().lower()
-    return v or None
-
-
-def draft_k_default() -> int:
-    return int(os.environ.get("BIGDL_TPU_DRAFT_K", "3"))
-
-
-def page_zero_enabled() -> bool:
-    return os.environ.get("BIGDL_TPU_PAGE_ZERO", "0") == "1"
+from bigdl_tpu.serving import decode_programs
 
 
 def default_num_pages(slots: int, max_len: int, page_size: int) -> int:
@@ -87,11 +72,14 @@ class PageAllocator:
         return len(self._owned[slot])
 
     # ------------------------------------------------------- allocation
+    def pages_for(self, tokens: int) -> int:
+        """How many pages hold ``tokens`` of one slot."""
+        return min(-(-max(tokens, 0) // self.page_size),
+                   self.pages_per_slot)
+
     def needed(self, slot: int, tokens: int) -> int:
         """How many new pages ``slot`` needs to hold ``tokens``."""
-        want = min(-(-max(tokens, 0) // self.page_size),
-                   self.pages_per_slot)
-        return max(0, want - len(self._owned[slot]))
+        return max(0, self.pages_for(tokens) - len(self._owned[slot]))
 
     def ensure(self, slot: int, tokens: int) -> bool:
         """Grow ``slot``'s mapping to cover ``tokens`` logical tokens.
@@ -107,11 +95,152 @@ class PageAllocator:
             own.append(phys)
         return True
 
-    def release(self, slot: int) -> List[int]:
-        """Free every page ``slot`` owns (retirement / eviction);
-        returns the freed physical page ids (for optional zeroing)."""
-        freed = self._owned[slot]
+    def release(self, slot: int):
+        """Free every page ``slot`` owns (retirement / eviction)."""
+        self._free.extend(self._owned[slot])
         self._owned[slot] = []
         self.table[slot, :] = 0
-        self._free.extend(freed)
-        return freed
+
+
+class DenseCache:
+    """One ``max_len`` row per slot, reserved whole: nothing to budget,
+    so every request for room is granted."""
+
+    resident_name = "decode_kv_cache"
+    pages_in_use = 0
+    pages_free = 0
+
+    def __init__(self, slots: int, max_len: int):
+        self.slots = int(slots)
+        self.max_len = int(max_len)
+        self._bytes = 0
+
+    # ----------------------------------------------- cache and programs
+    def init_cache(self, model, dtype):
+        import jax
+
+        cache = model.init_cache(self.slots, self.max_len, dtype)
+        self._bytes = sum(leaf.size * leaf.dtype.itemsize
+                          for leaf in jax.tree_util.tree_leaves(cache))
+        return cache
+
+    def build_tick(self, model):
+        return decode_programs.build_sampling_tick(model)
+
+    def build_write(self):
+        return decode_programs.build_write_slot()
+
+    def build_verify(self, model, k: int):
+        return decode_programs.build_spec_verify(model, k)
+
+    def tick_extra(self) -> tuple:
+        """What the tick and the verify take after the cache."""
+        return ()
+
+    def write_extra(self, slot: int) -> tuple:
+        """What the slot write takes after the cache."""
+        return ()
+
+    # ------------------------------------------------------------- room
+    def check_servable(self, tokens: int):
+        """Raise when a request of ``tokens`` could never be held."""
+
+    def pages_for(self, tokens: int) -> int:
+        return 0
+
+    def owned(self, slot: int) -> int:
+        return 0
+
+    def reserve(self, slot: int, tokens: int) -> bool:
+        return True
+
+    def release(self, slot: int):
+        pass
+
+    # ---------------------------------------------------------- readouts
+    def resident_bytes(self) -> int:
+        """The fixed worst-case reservation."""
+        return self._bytes
+
+    def span_args(self) -> Optional[dict]:
+        return None
+
+
+class PagedCache(PageAllocator):
+    """The page pool of ops/paged_kv.py: a slot holds the pages its
+    tokens need and retirement hands them back.  ``gauge`` is told the
+    pages in use whenever they change."""
+
+    resident_name = "decode_kv_pages"
+
+    def __init__(self, slots: int, max_len: int, page_size: int,
+                 num_pages: int, kv_dtype=None,
+                 gauge: Callable[[int], None] = lambda n: None):
+        super().__init__(num_pages, page_size, slots, max_len)
+        self.kv_dtype = kv_dtype
+        self.page_bytes = 0
+        self._gauge = gauge
+
+    # ----------------------------------------------- cache and programs
+    def init_cache(self, model, dtype):
+        cache = model.init_paged_cache(self.num_pages, self.page_size,
+                                       self.slots, dtype,
+                                       kv_dtype=self.kv_dtype)
+        # bytes one physical page costs across every layer's pool
+        # (K + V + scales)
+        self.page_bytes = sum(
+            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
+            for pool in cache.values()
+            for name, leaf in pool.items() if name != "length")
+        return cache
+
+    def build_tick(self, model):
+        return decode_programs.build_paged_tick(model)
+
+    def build_write(self):
+        return decode_programs.build_paged_write_slot()
+
+    def build_verify(self, model, k: int):
+        return decode_programs.build_spec_verify(model, k, paged=True)
+
+    def tick_extra(self) -> tuple:
+        """The block table, a plain device argument each call (values
+        change, shape never)."""
+        return (self.table,)
+
+    def write_extra(self, slot: int) -> tuple:
+        return (self.table[slot],)
+
+    # ------------------------------------------------------------- room
+    def check_servable(self, tokens: int):
+        pages = self.pages_for(tokens)
+        if pages > self.num_pages - 1:
+            raise OutOfPagesError(
+                f"request needs {pages} pages at its longest but the "
+                f"pool only has {self.num_pages - 1} usable pages of "
+                f"{self.page_size} tokens")
+
+    def reserve(self, slot: int, tokens: int) -> bool:
+        """Grow ``slot`` to hold ``tokens``; False (nothing changed)
+        when the free list is short."""
+        free = self.pages_free
+        if not self.ensure(slot, tokens):
+            return False
+        if self.pages_free != free:
+            self._gauge(self.pages_in_use)
+        return True
+
+    def release(self, slot: int):
+        super().release(slot)
+        self._gauge(self.pages_in_use)
+
+    # ---------------------------------------------------------- readouts
+    def resident_bytes(self) -> int:
+        """Bytes of the pages actually held — the readout that
+        retirement frees memory."""
+        return self.pages_in_use * self.page_bytes
+
+    def span_args(self) -> Optional[dict]:
+        """``loop/tick_dispatch``'s counter: the share of the ``S * M``
+        extent this tick's attention has to read."""
+        return {"pages_held": self.pages_in_use}

@@ -342,10 +342,10 @@ def test_engine_declares_and_compiles_its_programs(engine):
     # tick, prefill 1x8 and 2x8, write 1 and 2, the chunk
     assert engine.declared_programs() == 6
     assert engine.recompiles == 6
-    pool = engine._cache["layer0"]
+    pool = engine._target.cache["layer0"]
     assert sorted(pool) == ["latent", "length"]
     assert pool["latent"].shape[1:] == (4, 128)    # 16 + 4 in whole lanes
-    assert engine._page_bytes_total() == 3 * 4 * 128 * 4
+    assert engine._kv.page_bytes == 3 * 4 * 128 * 4
 
 
 @pytest.mark.parametrize("prompt_len,steps", [(5, 6), (8, 4), (19, 5),
@@ -416,7 +416,7 @@ def test_opt_tokens_unchanged_through_the_generalised_page_code():
     with DecodeEngine(model, var, slots=2, max_len=32, prompt_buckets=[8],
                       prefill_batch_sizes=[1], kv_layout="paged",
                       page_size=4) as eng:
-        assert sorted(eng._cache["layer0"]) == ["k", "length", "v"]
+        assert sorted(eng._target.cache["layer0"]) == ["k", "length", "v"]
         got = eng.generate(prompt, 6, timeout=120)
     ids = list(prompt)
     for _ in range(6):

@@ -69,6 +69,12 @@ def _compiled(fn, sharding, *structs, donate=()):
                    donate_argnums=donate).lower(*structs).compile()
 
 
+def _on(sharding):
+    """The engine's own builders (``serving/decode_programs.py``) placed
+    on ``sharding``: what they donate is part of what is compiled."""
+    return dict(in_shardings=sharding, out_shardings=sharding)
+
+
 def _compile(fn, sharding, *structs, donate=()):
     """TPU-compile ``fn`` and return the compiled program's text."""
     return _compiled(fn, sharding, *structs, donate=donate).as_text()
@@ -221,18 +227,18 @@ def test_paged_decode_tick_compiles_at_lm_width(one_chip, kv_dtype):
     int8 pool gathers).  The sampling work over ``f32[32,50272]`` lies
     inside the tick's one conditional."""
     from bigdl_tpu.ops.pallas import report
-    from bigdl_tpu.serving.decode import paged_tick_fn
+    from bigdl_tpu.serving.decode_programs import build_paged_tick
 
     model, cache = _lm_layer_and_pool(kv_dtype)
     var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     slots = CELL_SLOTS
     before = report.report().get("paged_attention", {}).get("pallas", 0)
-    compiled = _compiled(
-        paged_tick_fn(model), one_chip, var["params"], var["state"],
+    compiled = build_paged_tick(model, **_on(one_chip)).lower(
+        var["params"], var["state"],
         cache, S((slots, CELL_MAX_LEN // CELL_PAGE), jnp.int32),
         S((slots,), jnp.int32), S((slots,), jnp.bool_),
         S((slots, 2), jnp.uint32), S((slots,), F32),
-        S((slots,), jnp.int32), S((slots,), F32), donate=(2,))
+        S((slots,), jnp.int32), S((slots,), F32)).compile()
     text = compiled.as_text()
     _pool_in_place(text, cache)
     if kv_dtype is None:  # the cell's pool; ungated 13.67 MiB, now 13.54
@@ -248,14 +254,13 @@ def test_paged_slot_write_is_in_place_at_lm_width(one_chip, bucket,
                                                   kv_dtype):
     """The slot write (one prefill row into a slot's pages) at the
     decode cell's geometry, for the smallest and largest bucket."""
-    from bigdl_tpu.serving.decode import paged_write_slot_fn
+    from bigdl_tpu.serving.decode_programs import build_paged_write_slot
 
     model, cache = _lm_layer_and_pool(kv_dtype)
     batch = jax.eval_shape(lambda: model.init_cache(4, bucket, F32))
-    text = _compile(
-        paged_write_slot_fn(), one_chip, cache,
-        S((CELL_MAX_LEN // CELL_PAGE,), jnp.int32), batch,
-        S((), jnp.int32), S((), jnp.int32), donate=(0,))
+    text = build_paged_write_slot(**_on(one_chip)).lower(
+        cache, S((CELL_MAX_LEN // CELL_PAGE,), jnp.int32), batch,
+        S((), jnp.int32), S((), jnp.int32)).compile().as_text()
     _pool_in_place(text, cache)
 
 
@@ -286,7 +291,7 @@ def test_latent_moe_tick_compiles_at_published_widths(one_chip):
 
     from bigdl_tpu.nn.latent import LatentMoETransformer
     from bigdl_tpu.ops.pallas import report
-    from bigdl_tpu.serving.decode import paged_tick_fn
+    from bigdl_tpu.serving.decode_programs import build_paged_tick
     from bigdl_tpu.serving.paging import default_num_pages
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -303,12 +308,12 @@ def test_latent_moe_tick_compiles_at_published_widths(one_chip):
     assert cache["layer0"]["latent"].shape == (pages, page, 640)
     before = report.report().get("latent_paged_attention", {}).get(
         "pallas", 0)
-    compiled = _compiled(
-        paged_tick_fn(model), one_chip, var["params"], var["state"],
+    compiled = build_paged_tick(model, **_on(one_chip)).lower(
+        var["params"], var["state"],
         cache, S((slots, max_len // page), jnp.int32),
         S((slots,), jnp.int32), S((slots,), jnp.bool_),
         S((slots, 2), jnp.uint32), S((slots,), F32),
-        S((slots,), jnp.int32), S((slots,), F32), donate=(2,))
+        S((slots,), jnp.int32), S((slots,), F32)).compile()
     text = compiled.as_text()
     _pool_in_place(text, cache)
     # ungated 4.89 MiB + 2.03 of logits (32 x 16032 f32, padded)
