@@ -12,7 +12,10 @@ Three parts, each with one job:
   the slots with room, dispatch one *round*, retire.  A round hands
   back ``(emitted (S, K), n_emit (S,))``: the grid tick is the round
   with K = 1, draft-propose + verify the round with K = ``draft_k`` +
-  1; one ``_retire`` takes either.  Continuous batching: a finished
+  1; one ``_retire`` takes either.  The tick round keeps one tick in
+  flight: tick n+1 is enqueued from tick n's device outputs before
+  tick n's tokens are read (docs/decoding.md §One tick in flight).
+  Continuous batching: a finished
   sequence (EOS / token budget / deadline) retires at TOKEN granularity
   and frees its slot at once; ``continuous=False`` degrades to static
   run-to-completion waves, the baseline arm of ``bench.py --decode-ab``.
@@ -52,7 +55,8 @@ import itertools
 import queue
 import threading
 import time
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import (Callable, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import numpy as np
@@ -142,8 +146,21 @@ class _Slot:
         self.times = [t_first]
 
 
-_CLOSE = object()  # queue sentinel
+class _Flight(NamedTuple):
+    """A tick enqueued and not yet read: its device outputs, the rows
+    it ran (``mask``) with the :class:`_Slot` each was dispatched for
+    (``owners``), and the ``args`` of its own ``loop/tick_dispatch``
+    span, which the read fills with the model's counters."""
 
+    nxt: jax.Array
+    keys: jax.Array
+    counters: dict
+    mask: np.ndarray
+    owners: List[Optional[_Slot]]
+    args: dict
+
+
+_CLOSE = object()  # queue sentinel
 
 
 
@@ -410,9 +427,24 @@ class DecodeEngine:
         self._temps = np.zeros((self.slots,), np.float32)
         self._topks = np.zeros((self.slots,), np.int32)
         self._topps = np.ones((self.slots,), np.float32)
-        # host mirror of each slot's valid cache extent (prompt +
-        # generated - 1): drives page budgeting and draft-length resync
+        # host mirror of each slot's cache extent, counting what the
+        # rounds dispatched so far have written (prompt + generated - 1
+        # once they are read): drives page budgeting and draft-length
+        # resync.  The round advances it, not the retirement
         self._host_len = np.zeros((self.slots,), np.int32)
+        # the extent at which a slot's budget ends (prompt + max_new -
+        # 1): a budget end is known by count, ``_host_len`` reaching
+        # it, a tick before its token is read
+        self._limit = np.zeros((self.slots,), np.int32)
+        # the tick pipeline (depth one): the tick enqueued and unread,
+        # the rows of the tick last read that still held their slot,
+        # the rows whose token and key the host wrote since a tick last
+        # took both from the mirrors, and the host-decided tick inputs
+        # as last uploaded, ``name -> (host copy, device array)``
+        self._flight: Optional[_Flight] = None
+        self._served = np.zeros((self.slots,), bool)
+        self._host_rows = np.zeros((self.slots,), bool)
+        self._uploads: dict = {}
         self._chunking: Optional[dict] = None
         self._chunk_pending: "collections.deque[_DecodeRequest]" = \
             collections.deque()
@@ -456,7 +488,7 @@ class DecodeEngine:
         takes the dict in which earlier thunks left what later ones
         consume."""
         if self._draft is None:
-            progs = [(("tick",), lambda held: self._run_tick())]
+            progs = [(("tick",), lambda held: self._warm_tick())]
         else:
             progs = [(("propose",), lambda held: held.update(
                          props=self._run_propose())),
@@ -489,11 +521,33 @@ class DecodeEngine:
                 lane.warming = False
         return self.metrics.recompiles - before
 
-    def _tick_args(self):
+    def _sent(self, name: str, host: np.ndarray):
+        """The device copy of a host-decided tick input, uploaded again
+        only when the host's value differs from what was last sent."""
+        last = self._uploads.get(name)
+        if last is None or not np.array_equal(last[0], host):
+            # the copy goes up, not the mirror: the host writes its
+            # mirrors in place while the tick is in flight
+            snap = host.copy()
+            last = self._uploads[name] = (snap, jax.device_put(snap))
+        return last[1]
+
+    def _tick_args(self, mask: np.ndarray,
+                   prev: Optional[_Flight] = None):
+        """The tick's arguments for the rows of ``mask``: tokens and
+        keys are ``prev``'s device outputs, or the host mirrors when
+        nothing is in flight; the rest is what the host decides, kept
+        on the device between the turns that change it."""
         t = self._target
-        return (t.params, t.state, t.cache) + self._kv.tick_extra() + (
-            self._tokens, self._active, self._keys, self._temps,
-            self._topks, self._topps)
+        tokens, keys = (self._tokens.copy(), self._keys.copy()) \
+            if prev is None else (prev.nxt, prev.keys)
+        extra = tuple(self._sent(f"extra{i}", x)
+                      for i, x in enumerate(self._kv.tick_extra()))
+        return (t.params, t.state, t.cache) + extra + (
+            tokens, self._sent("active", mask), keys,
+            self._sent("temps", self._temps),
+            self._sent("topks", self._topks),
+            self._sent("topps", self._topps))
 
     def _verify_args(self, props):
         t = self._target
@@ -511,7 +565,7 @@ class DecodeEngine:
         if self._draft is None:
             cost = costmodel.stamp_jitted(
                 "decode_tick", self._target.programs["tick"],
-                *self._tick_args())
+                *self._tick_args(self._active))
         else:
             cost = costmodel.stamp_jitted(
                 "spec_verify", self._target.programs["verify"],
@@ -520,34 +574,105 @@ class DecodeEngine:
         if cost is not None:
             self._tick_cost = cost
 
-    def _run_tick(self):
-        """Dispatch the grid tick and wait for it; returns the ``(S,)``
-        next tokens (inactive rows hold theirs)."""
+    def _dispatch_tick(self, mask: np.ndarray,
+                       prev: Optional[_Flight]) -> _Flight:
+        """Enqueue the grid tick for the rows of ``mask`` and return it
+        unread.  ``prev`` is the tick still unread whose device outputs
+        feed this one (None: the host mirrors do)."""
         # the tick's own predicate, known here without asking the
         # device: the rows whose sampling epilogue this tick runs
-        sampled_rows = int(((self._temps > 0) & self._active).sum())
-        if sampled_rows:
-            self.metrics.inc_sampled_ticks()
-        args = dict(self._kv.span_args() or {}, sampled_rows=sampled_rows)
+        sampled_rows = int(((self._temps > 0) & mask).sum())
+        args = dict(self._kv.span_args() or {}, sampled_rows=sampled_rows,
+                    in_flight=int(prev is not None))
+        if prev is None:
+            self._host_rows[:] = False  # the mirrors go in whole
         t = self._target
         with self._tracer.span("loop/tick_dispatch", CAT_DECODE,
                                args=args):
+            # the model's counters are kept only while somebody reads
+            # them, decided here: a session can open or close before
+            # this tick is read, and they belong on this tick's span
+            lit = self._tracer.enabled
             # the paged tick also hands out the model's counters
             t.cache, nxt, keys, *counters = t.call(
-                "tick", "decode_tick", *self._tick_args(),
+                "tick", "decode_tick", *self._tick_args(mask, prev),
                 cost=self._tick_cost)
-        # the per-tick host sync point (writable copy: slots claimed
-        # between ticks overwrite their token in place)
+        # the tick writes each row's token at its extent: known by
+        # count, so the next turn funds and masks without the read
+        self._host_len[mask] += 1
+        return _Flight(nxt, keys, counters[0] if counters and lit else {},
+                       mask, list(self._slot_state), args)
+
+    def _read_tick(self, flight: _Flight, whole: bool
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+        """Wait for ``flight``'s ``(S,)`` tokens (the per-tick host sync
+        point); returns them and the rows they are for: a token belongs
+        to the ``_Slot`` it was dispatched for, and a slot that was
+        evicted, truncated or freed since drops it.  ``whole`` (the
+        pipeline drains) also fetches the keys and makes the host
+        mirrors whole again, but for the rows the host itself wrote."""
         with self._tracer.span("loop/tick_wait", CAT_DECODE):
-            # the model's counters come back in the tokens' own read,
-            # and only while somebody reads them
-            counters = counters[0] if counters and self._tracer.enabled \
-                else {}
-            nxt, keys, counters = jax.device_get((nxt, keys, counters))
+            # the model's counters come back in the tokens' own read
+            nxt, keys, counters = jax.device_get(
+                (flight.nxt, flight.keys if whole else None,
+                 flight.counters))
             for name, value in counters.items():
-                args[name] = np.asarray(value).tolist()
-            self._keys = np.array(keys)
-            return np.array(nxt)
+                flight.args[name] = np.asarray(value).tolist()
+            if whole:
+                device = ~self._host_rows
+                self._tokens[device] = nxt[device]
+                self._keys[device] = keys[device]
+            served = flight.mask.copy()
+            for s in np.flatnonzero(served):
+                served[s] = self._slot_state[s] is flight.owners[s]
+        if served.any():
+            # a tick the loop times: what it was is counted on the same
+            # event, so the shares of the ticks timed stay within 0-1
+            if flight.args["sampled_rows"]:
+                self.metrics.inc_sampled_ticks()
+            if flight.args["in_flight"]:
+                self.metrics.inc_overlapped_ticks()
+        return nxt, served
+
+    def _rows_due(self) -> np.ndarray:
+        """The rows the next tick runs: active, with a token of their
+        budget still to dispatch (a budget end is known by count, so no
+        token is computed past one)."""
+        return self._active & (self._host_len < self._limit)
+
+    def _warm_tick(self):
+        """Run the tick as the loop will: once from the host mirrors
+        and once from a tick's device outputs with the inputs already
+        uploaded, so the first overlapped call of a run finds its
+        executable.  No row is active: nothing is written or served."""
+        idle = np.zeros((self.slots,), bool)
+        first = self._dispatch_tick(idle, None)
+        self._read_tick(self._dispatch_tick(idle, first), whole=True)
+
+    def _run_tick(self):
+        """One turn of the tick pipeline, whose depth is one: enqueue
+        the next tick from the outputs of the one in flight, *then*
+        read that one; returns its ``(S,)`` tokens, for the rows of
+        ``_served`` (the others hold what the device or the host had).
+        Two turns read first, so that the host mirrors are whole: one
+        after an admission wrote a token and a key into them, which
+        then dispatches from them, and one that has no row left to
+        dispatch.  A turn that finds nothing in flight only enqueues
+        and serves nothing."""
+        prev = self._flight
+        mask = self._rows_due()
+        if prev is not None and (self._host_rows.any() or not mask.any()):
+            self._flight = None
+            nxt, self._served = self._read_tick(prev, whole=True)
+            if mask.any():
+                self._flight = self._dispatch_tick(mask, None)
+            return nxt
+        self._flight = self._dispatch_tick(mask, prev)
+        if prev is None:
+            self._served = np.zeros((self.slots,), bool)
+            return self._tokens.copy()
+        nxt, self._served = self._read_tick(prev, whole=False)
+        return nxt
 
     def _run_propose(self):
         d = self._draft
@@ -570,8 +695,9 @@ class DecodeEngine:
     # ``(emitted (S, K), n_emit (S,))`` on the host
     # ------------------------------------------------------------------
     def _tick_round(self):
-        """K = 1: every active row emits the tick's one token."""
-        return self._run_tick()[:, None], self._active
+        """K = 1: every row the tick read was dispatched for, and that
+        still holds its slot, emits the tick's one token."""
+        return self._run_tick()[:, None], self._served
 
     def _spec_round(self):
         """K = ``draft_k`` + 1: the accepted prefix of the draft's
@@ -581,6 +707,7 @@ class DecodeEngine:
             out = self._run_verify(self._run_propose())
         with self._tracer.span("loop/tick_wait", CAT_DECODE):
             emitted, n_emit = jax.device_get(out)
+        self._host_len += n_emit  # what the verify accepted stays
         return np.asarray(emitted), np.asarray(n_emit)
 
     # ------------------------------------------------------------------
@@ -740,6 +867,8 @@ class DecodeEngine:
             self._fail_queued(EngineClosedError(
                 "decode engine closed before start"))
         self._chunking = None
+        self._flight = None
+        self._uploads = {}
         for lane in self._lanes:
             lane.drop()
 
@@ -772,7 +901,8 @@ class DecodeEngine:
     # engine loop: admit (prefill into free slots) then run a round
     # ------------------------------------------------------------------
     def _idle(self) -> bool:
-        return (not np.any(self._active) and not self._pending
+        return (not np.any(self._active) and self._flight is None
+                and not self._pending
                 and self._chunking is None and not self._chunk_pending
                 and all(st is None for st in self._slot_state))
 
@@ -811,7 +941,8 @@ class DecodeEngine:
             # run even when everything is paused
             with tr.span("loop/budget_pages", CAT_DECODE):
                 self._budget_pages()
-            if not np.any(self._active):
+            if self._flight is None and not np.any(self._rows_due()):
+                # nothing to enqueue and nothing to read
                 if stopping and self._idle():
                     return
                 continue
@@ -834,12 +965,16 @@ class DecodeEngine:
             now = time.perf_counter()
             args = {}
             with tr.span("loop/retire", CAT_DECODE, args=args):
-                self.metrics.record_tick(now - t0)
                 if spec_rids:
                     self.xray.to_many(spec_rids,
                                       request_xray.PHASE_RESIDENT, now=now)
-                n_active = int(self._active.sum())
-                self.metrics.record_slot_occupancy(n_active / self.slots)
+                # the rows the round served: those of the tick it read,
+                # none on the turn that only fills the pipeline
+                n_active = int(np.count_nonzero(n_emit))
+                if n_active:
+                    self.metrics.record_tick(now - t0)
+                    self.metrics.record_slot_occupancy(
+                        n_active / self.slots)
                 gaps = self._retire(emitted, n_emit, now)
                 self.metrics.record_decode_tokens(len(gaps))
                 args["active"] = n_active
@@ -996,10 +1131,14 @@ class DecodeEngine:
         """Bind a prefilled request to its slot: token feed, sampling
         state, and the host length ledger."""
         self._tokens[slot] = tok0
+        self._keys[slot] = req.key
+        # the next tick takes both from the mirrors, once the tick in
+        # flight has been read into them
+        self._host_rows[slot] = True
         self._active[slot] = True
         self._slot_state[slot] = _Slot(req, tok0, t_tok)
         self._host_len[slot] = int(req.prompt.size)
-        self._keys[slot] = req.key
+        self._limit[slot] = int(req.prompt.size) + req.max_new - 1
         self._temps[slot] = req.temp
         self._topks[slot] = req.top_k
         self._topps[slot] = req.top_p
@@ -1106,9 +1245,11 @@ class DecodeEngine:
         pages.  The oldest occupied slot can always be funded (submit
         guarantees every request fits an empty pool), so at least one
         slot always progresses: no evict/re-admit livelock."""
+        # a slot whose whole budget is dispatched writes nothing more
         order = sorted(
             (s for s in range(self.slots)
-             if self._slot_state[s] is not None),
+             if self._slot_state[s] is not None
+             and self._host_len[s] < self._limit[s]),
             key=lambda s: self._slot_state[s].req.rid)
         for s in order:
             st = self._slot_state[s]
@@ -1139,7 +1280,10 @@ class DecodeEngine:
         while not self._kv.reserve(slot, tokens):
             victim, rid = None, me
             for s in range(self.slots):
-                if s == slot or self._slot_state[s] is None:
+                # a row whose last token is in flight frees its pages
+                # at the next read: waiting a turn beats re-decoding it
+                if s == slot or self._slot_state[s] is None \
+                        or self._host_len[s] >= self._limit[s]:
                     continue
                 r = self._slot_state[s].req.rid
                 if r > rid:
@@ -1184,7 +1328,6 @@ class DecodeEngine:
             req = st.req
             if proposed:
                 self.metrics.record_spec(proposed, n - 1)
-            self._host_len[s] += n
             self._tokens[s] = emitted[s, n - 1]
             reason = None
             for tok in emitted[s, :n].tolist():
@@ -1232,6 +1375,7 @@ class DecodeEngine:
         self._topks[slot] = 0
         self._topps[slot] = 1.0
         self._host_len[slot] = 0
+        self._limit[slot] = 0
         self._kv.release(slot)
         self._tracer.instant("slot_free", CAT_DECODE,
                              args={"slot": slot})

@@ -145,8 +145,15 @@ class ServingMetrics:
 
     def inc_sampled_ticks(self, n: int = 1):
         """A tick that ran its sampling epilogue: some active row had
-        ``temperature > 0`` (a greedy grid skips it)."""
+        ``temperature > 0`` (a greedy grid skips it).  Counted where
+        the tick is timed, at the read that serves a row."""
         self.base.inc("sampled_ticks", n)
+
+    def inc_overlapped_ticks(self, n: int = 1):
+        """A tick enqueued while its predecessor's tokens were still
+        unread: the host's turn ran beside the device's.  Counted
+        where the tick is timed, at the read that serves a row."""
+        self.base.inc("overlapped_ticks", n)
 
     def record_spec(self, proposed: int, accepted: int):
         """One speculative round: ``proposed`` draft tokens scored,
@@ -257,6 +264,16 @@ class ServingMetrics:
         n = self.base.count(TICK)
         return self.sampled_ticks / n if n else 0.0
 
+    @property
+    def overlapped_ticks(self) -> int:
+        return self.base.counter("overlapped_ticks")
+
+    def overlapped_tick_share(self) -> float:
+        """Ticks enqueued with their predecessor unread over all ticks
+        timed (0.0 before the first tick)."""
+        n = self.base.count(TICK)
+        return self.overlapped_ticks / n if n else 0.0
+
     def spec_acceptance_rate(self) -> float:
         """Accepted / proposed draft tokens since engine start (0.0
         when the engine never ran a speculative round)."""
@@ -292,6 +309,8 @@ class ServingMetrics:
                                           4),
             "prefill_chunks": self.prefill_chunks,
             "sampled_tick_share": round(self.sampled_tick_share(), 4),
+            "overlapped_tick_share": round(self.overlapped_tick_share(),
+                                           4),
         }
 
     # scalar tags exported to TensorBoard (visualization satellite):
@@ -347,7 +366,8 @@ class ServingMetrics:
                      f"p95={s['p95_ttft_ms']:.2f}ms | "
                      f"gap p50={s['p50_token_gap_ms']:.2f}ms "
                      f"p95={s['p95_token_gap_ms']:.2f}ms | "
-                     f"sampled={100 * s['sampled_tick_share']:.0f}%")
+                     f"sampled={100 * s['sampled_tick_share']:.0f}% | "
+                     f"overlap={100 * s['overlapped_tick_share']:.0f}%")
         if s["pages_in_use"] or s["page_evictions"]:
             line += (f" | pages={s['pages_in_use']} "
                      f"evict={s['page_evictions']}")

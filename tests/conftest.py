@@ -57,6 +57,40 @@ def pytest_configure(config):
 
 
 @pytest.fixture
+def read_each_tick_first(monkeypatch):
+    """Call it to make overlapping impossible in every ``DecodeEngine``:
+    a turn enqueues its tick from the host mirrors and reads it before
+    it returns, as the decode loop did before it kept a tick in flight
+    (a tick found in flight is read first, and that is the turn).  The
+    oracle for the overlapped loop, and the synchronous dense arm a
+    speculative round is held against."""
+    from bigdl_tpu.serving.decode import DecodeEngine
+
+    def run_tick(self):
+        flight, self._flight = self._flight, None
+        if flight is None:
+            flight = self._dispatch_tick(self._rows_due(), None)
+        nxt, self._served = self._read_tick(flight, whole=True)
+        return nxt
+
+    return lambda: monkeypatch.setattr(DecodeEngine, "_run_tick", run_tick)
+
+
+@pytest.fixture
+def until():
+    """``until(done)``: poll ``done()`` for at most 30 s, then assert."""
+    import time
+
+    def wait(done):
+        deadline = time.monotonic() + 30
+        while not done() and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert done()
+
+    return wait
+
+
+@pytest.fixture
 def interpreted_paged_attn(monkeypatch):
     """Route ``apply_paged`` as on the TPU, with the ``paged_attn``
     kernel run by the Pallas interpreter (this tier has no chip)."""

@@ -481,7 +481,10 @@ def test_decode_cache_growth_files_forensic_naming_axis():
     e2 = DecodeEngine(model, var, slots=2, max_len=24,
                       prompt_buckets=(4,), prefill_batch_sizes=(1,),
                       eos_id=None, warmup=False, start=False)
-    e2._run_tick()  # steady state: _warming is False
+    # steady state (_warming is False); a first turn of the tick round:
+    # it compiles and enqueues the tick, nothing was in flight to read
+    e2._run_tick()
+    assert e2._flight is not None and not e2._served.any()
     e2.close()
     forensics = [f for f in registry.forensic_records()
                  if f["program"] == "decode_tick"]

@@ -14,6 +14,7 @@ tentpole; docs/decoding.md):
   beats static run-to-completion batching, zero steady-state
   recompiles.
 """
+import contextlib
 import time
 
 import jax
@@ -785,11 +786,15 @@ def test_retire_takes_a_round(engine_lm, monkeypatch, k):
     # the row that emitted nothing is as it was, garbage and all
     assert not eng._active[3] and eng._slot_state[3].req is reqs[3]
     assert eng._slot_state[3].generated == [1] and eng._tokens[3] == 1
-    # the row that goes on: fed its last token, its extent grown
+    # the row that goes on: fed its last token.  Its extent is the
+    # round's to advance (the tick as it is enqueued, a verify by what
+    # it accepted: test_a_token_belongs_to_the_slot_it_was_dispatched_for,
+    # test_speculative_decode_exact_match), so a retirement leaves it
+    # and zeroes a freed slot's
     assert eng._active[4] and eng._slot_state[4].generated == want[3]
     assert eng._tokens[4] == want[3][-1]
-    assert eng._host_len[4] == 3 + len(want[3]) - 1
-    assert list(eng._host_len[:4]) == [0, 0, 0, 3]
+    assert list(eng._host_len) == [0, 0, 0, 3, 3]
+    assert list(eng._limit) == [0, 0, 0, 66, 66]
     # K - 1 tokens a row were a draft's, n_emit - 1 of them accepted
     assert eng.metrics.spec_acceptance_rate() == pytest.approx(
         (3 + 3 + 3 + 1) / (4 * 3) if k > 1 else 0.0)
@@ -821,7 +826,8 @@ class _Room:
 
 
 @pytest.mark.parametrize("case", ["pause", "evict_younger",
-                                  "oldest_always_funded"])
+                                  "oldest_always_funded",
+                                  "spares_a_finishing_row"])
 def test_page_policy_on_a_fake_cache_manager(engine_lm, case):
     """Oldest first, evict strictly younger, pause when there is none,
     never starve the oldest: the policy alone, against a manager whose
@@ -830,9 +836,24 @@ def test_page_policy_on_a_fake_cache_manager(engine_lm, case):
     eng = _still(model, var, slots=3)
     # every slot holds its 3 prompt tokens and needs a 4th this round
     rids = {"pause": [0, 1], "evict_younger": [5, 2],
-            "oldest_always_funded": [1, 0, 2]}[case]
+            "oldest_always_funded": [1, 0, 2],
+            "spares_a_finishing_row": [5, 2]}[case]
     reqs = [_bind(eng, s, rid) for s, rid in enumerate(rids)]
-    if case == "pause":
+    if case == "spares_a_finishing_row":
+        # the younger row's whole budget is dispatched (its last token
+        # is in flight): it is neither funded nor evicted, the older
+        # waits the one turn until the read frees the younger's pages
+        eng._kv = room = _Room(0, {0: 3, 1: 3})
+        eng._limit[0] = eng._host_len[0]
+        eng._budget_pages()
+        assert not room.released and eng._slot_state[0].req is reqs[0]
+        assert list(eng._active[:2]) == [True, False]
+        assert room.held == {0: 3, 1: 3} and not eng._pending
+        eng._free(0)         # the read retired it
+        eng._budget_pages()
+        assert eng._active[1] and room.held == {1: 4}
+        assert eng.metrics.page_evictions == 0
+    elif case == "pause":
         eng._kv = room = _Room(1, {0: 3, 1: 3})
         eng._budget_pages()
         # the older is funded; the younger has no one younger to evict
@@ -864,6 +885,329 @@ def test_page_policy_on_a_fake_cache_manager(engine_lm, case):
         assert eng._active[1] and room.held == {1: 4}
         assert [r.rid for r in eng._pending] == [1, 2]
         assert eng.metrics.page_evictions == 2
+    eng.close()
+
+
+# ------------------------------------- one tick in flight (ISSUE 32)
+_LAYOUTS = {"dense": {}, "paged": dict(kv_layout="paged", page_size=4)}
+
+
+@contextlib.contextmanager
+def _compiles():
+    """The backend compile requests made inside the block: the event
+    ``benchmark/device.CompileCount`` counts (a cache hit fires it
+    too).  Around an engine's traffic only: an oracle compiles its own
+    programs."""
+    seen = []
+
+    def on(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+
+
+def _drive(eng, requests):
+    futs = [eng.submit(p, n, **opts) for p, n, opts in requests]
+    return [list(f.result(120)) for f in futs]
+
+
+def _counts(eng):
+    """Ticks timed, ticks overlapped, tokens decoded, ended by budget:
+    counted since the engine started (take a difference)."""
+    m = eng.metrics
+    return np.array([m.base.count("decode_tick"), m.overlapped_ticks,
+                     m.decoded_tokens, m.finished("length")])
+
+
+def _row_ticks(eng, monkeypatch):
+    """Count the rows of every tick ``eng`` enqueues from here on."""
+    rows = []
+    real = eng._dispatch_tick
+    monkeypatch.setattr(eng, "_dispatch_tick", lambda mask, prev: (
+        rows.append(int(mask.sum())), real(mask, prev))[1])
+    return rows
+
+
+@pytest.fixture(params=sorted(_LAYOUTS))
+def piped(request, engine_lm):
+    """A running engine, of either layout."""
+    model, var = engine_lm
+    with _engine(model, var, slots=3, max_len=48,
+                 **_LAYOUTS[request.param]) as eng:
+        yield eng
+
+
+def _grids():
+    rs = np.random.RandomState(32)
+    sampled = lambda i: dict(temperature=0.9, top_k=8, top_p=0.9,
+                             seed=70 + i)
+    prompts = [rs.randint(0, VOCAB, (t,)) for t in (3, 7, 5, 8, 4, 6)]
+    n_news = [9, 14, 6, 11, 17, 8]
+    return {
+        "greedy": [(p, n, {}) for p, n in zip(prompts, n_news)],
+        "sampled": [(p, n, sampled(i))
+                    for i, (p, n) in enumerate(zip(prompts, n_news))],
+        "mixed": [(p, n, sampled(i) if i % 2 else {})
+                  for i, (p, n) in enumerate(zip(prompts, n_news))],
+    }
+
+
+def test_a_tick_in_flight_serves_the_tokens_of_the_loop_without(
+        piped, engine_lm, read_each_tick_first):
+    """Greedy, seeded sampled and mixed grids through six requests over
+    three slots (admissions, retirements and, paged, page crossings
+    while ticks are in flight): every request's tokens are those of the
+    same engine with overlapping made impossible, bit for bit, and the
+    greedy ones the uncached forward's.  The first traffic after
+    warm-up, overlapped calls among it, compiles nothing."""
+    model, var = engine_lm
+    grids = _grids()
+    declared = piped.declared_programs()
+    before = _counts(piped)
+    with _compiles() as seen:
+        got = {name: _drive(piped, reqs) for name, reqs in grids.items()}
+    ticks, overlapped, _, _ = _counts(piped) - before
+    assert not seen and piped.recompiles == declared
+    assert overlapped > 0.5 * ticks
+    overlapped = piped.metrics.overlapped_ticks
+    read_each_tick_first()
+    want = {name: _drive(piped, reqs) for name, reqs in grids.items()}
+    assert piped.metrics.overlapped_ticks == overlapped  # none since
+    assert got == want
+    for (p, n, opts), toks in zip(grids["greedy"] + grids["mixed"],
+                                  got["greedy"] + got["mixed"]):
+        if not opts:
+            assert toks == _direct_greedy(model, var, p, n)
+    assert got["sampled"] != got["greedy"]
+    assert piped.recompiles == declared
+
+
+def test_a_budget_end_computes_no_discarded_token(piped, monkeypatch):
+    """A row that ends by its budget is known by count: it is left out
+    of the tick after its last, so on an all-"length" workload the
+    rows of the ticks enqueued are the tokens served after each
+    request's first, and nothing was in flight when the last was
+    read."""
+    rows = _row_ticks(piped, monkeypatch)
+    n_news = [5, 9, 2, 12, 7, 3, 6]
+    before = _counts(piped)
+    outs = _drive(piped, [([1 + i, 2, 3], n, {})
+                          for i, n in enumerate(n_news)])
+    _, _, tokens, ended = _counts(piped) - before
+    assert [len(o) for o in outs] == n_news
+    assert ended == len(n_news)
+    assert sum(rows) == sum(n - 1 for n in n_news) == tokens
+    assert 0 not in rows and piped._flight is None
+    assert not piped._host_len.any() and not piped._limit.any()
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_overlapped_ticks_are_counted_and_carried_by_the_spans(
+        engine_lm, tracer, layout):
+    """A long all-greedy stream overlaps nearly every tick:
+    ``overlapped_tick_share()`` >= 0.9, ``overlap=..%`` in the log
+    line, and ``loop/tick_dispatch`` carries ``args.in_flight`` 1 on
+    exactly the ticks the metric counted (the first after an empty
+    engine and the one after each admission read their predecessor
+    first)."""
+    model, var = engine_lm
+    tracer.enable()
+    with _engine(model, var, slots=3, max_len=48,
+                 **_LAYOUTS[layout]) as eng:
+        _drive(eng, [([1, 2, 3 + i], 40, {}) for i in range(3)])
+        tracer.disable()
+        m = eng.metrics
+        assert m.overlapped_tick_share() >= 0.9
+        assert m.snapshot()["overlapped_tick_share"] >= 0.9
+        assert "overlap=" in eng.log_line()
+    ticks = [s for s in tracer.spans() if s.name == "loop/tick_dispatch"]
+    flags = [s.args["in_flight"] for s in ticks]
+    # warm-up's second call runs on the first's outputs, as the loop's
+    assert flags[:3] == [0, 1, 0] and set(flags) == {0, 1}
+    assert sum(flags[2:]) == m.overlapped_ticks
+    assert len(ticks) - 2 == m.base.count("decode_tick")
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_end_of_sequence_is_learnt_a_tick_late(engine_lm, monkeypatch,
+                                               layout):
+    """With an ``eos_id`` the row ends at its end of sequence: the
+    token the tick in flight computed for it is dropped at the read
+    (one row-tick more than the tokens served), and the freed slot's
+    next tenant, admitted while that tick was unread, decodes its own
+    tokens.  (The tiny model's greedy rollouts repeat one token, so
+    the row that ends is a seeded sampled one.)"""
+    model, var = engine_lm
+    first = ([1, 2, 3], 12, dict(temperature=3.0, top_p=0.95, seed=11))
+    second = ([4, 5, 6, 7], 7, {})
+    with _engine(model, var, slots=1, **_LAYOUTS[layout]) as eng:
+        roll, tenant = _drive(eng, [first, second])
+        # a token first seen a few ticks in becomes the end of sequence
+        eos = next(t for i, t in enumerate(roll)
+                   if 3 <= i < 11 and t not in roll[:i])
+        want = roll[:roll.index(eos) + 1]
+        assert eos not in tenant
+        eng.eos_id = eos
+        rows = _row_ticks(eng, monkeypatch)
+        before = _counts(eng)
+        assert _drive(eng, [first, second]) == [want, tenant]
+        assert eng.metrics.finished("eos") == 1
+        served = len(want) - 1 + len(tenant) - 1
+        _, _, tokens, _ = _counts(eng) - before
+        assert tokens == served
+        assert sum(rows) == served + 1  # the one computed and dropped
+        assert eng._flight is None and eng._kv.pages_in_use == 0
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_truncation_and_contention_with_a_tick_in_flight(engine_lm,
+                                                         layout):
+    """A deadline that passes while decoding truncates to a prefix of
+    the rollout and the slot's next tenant decodes its own tokens;
+    under page contention (paged: evictions and pauses while ticks are
+    in flight) seeded sampled and greedy requests are served the tokens
+    of an engine with room for all."""
+    model, var = engine_lm
+    with _engine(model, var, slots=1, max_len=2048, prompt_buckets=(8,),
+                 prefill_batch_sizes=(1,), **_LAYOUTS[layout]) as eng:
+        cut = eng.submit([1, 2, 3], 2000, deadline_ms=150)
+        after = eng.submit([2, 3, 4], 6)
+        got = list(cut.result(120))
+        assert 1 <= len(got) < 2000
+        assert eng.metrics.finished("deadline") == 1
+        assert list(after.result(120)) == _direct_greedy(
+            model, var, [2, 3, 4], 6)
+    assert got[:24] == _direct_greedy(model, var, [1, 2, 3], 24)[:len(got)]
+    requests = [([1 + i, 2 + i, 3 + i], 12,
+                 dict(temperature=0.9, top_p=0.9, seed=90 + i) if i % 2
+                 else {}) for i in range(5)]
+    with _engine(model, var, slots=4, **_LAYOUTS[layout]) as eng:
+        want = _drive(eng, requests)
+    # 6 usable pages of 4 tokens: two requests at their longest fill it
+    tight = dict(num_pages=7) if layout == "paged" else {}
+    with _engine(model, var, slots=4, **_LAYOUTS[layout], **tight) as eng:
+        assert _drive(eng, requests) == want
+        if layout == "paged":
+            assert eng.metrics.page_evictions > 0
+            assert eng._kv.pages_in_use == 0
+        assert eng.metrics.overlapped_ticks > 0
+
+
+def _turn(eng, now=200.0):
+    """One loop turn's budget, round and retirement, by hand."""
+    eng._budget_pages()
+    emitted, n_emit = eng._round()
+    eng._retire(emitted, n_emit, now)
+    return [bool(b) for b in n_emit]
+
+
+@pytest.mark.parametrize("case", ["evict", "new_tenant", "deadline",
+                                  "pause", "spec_extent"])
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_a_token_belongs_to_the_slot_it_was_dispatched_for(
+        engine_lm, layout, case):
+    """The pipeline turn by turn on an engine without a loop thread: a
+    tick's token is delivered only if the slot still holds the
+    ``_Slot`` it was enqueued for.  An eviction and a deadline between
+    dispatch and read drop it; a new tenant keeps the token and key its
+    admission wrote; a paused row takes the token already computed for
+    it and resumes from the device's own state.  The extent is the
+    round's: the tick advances it as it enqueues, a verify by what it
+    accepted."""
+    model, var = engine_lm
+    kw = dict(_LAYOUTS[layout], slots=3)
+    if case == "spec_extent":
+        eng = _still(model, var, draft=(model, var), draft_k=3, **kw)
+        _bind(eng, 0, 1), _bind(eng, 2, 2)
+        n_emit = np.array([2, 0, 4], np.int32)
+        eng._run_propose = lambda: None
+        eng._run_verify = lambda props: (np.zeros((3, 4), np.int32),
+                                         n_emit)
+        emitted, got = eng._spec_round()
+        assert list(got) == [2, 0, 4] and emitted.shape == (3, 4)
+        assert list(eng._host_len) == [5, 0, 7]
+        eng.close()
+        return
+    eng = _still(model, var, **kw)
+    a = _bind(eng, 0, 1, deadline=150.0 if case == "deadline" else None)
+    b = _bind(eng, 1, 2)
+    st_a, st_b = eng._slot_state[:2]
+    assert _turn(eng, now=100.0) == [False] * 3  # fills the pipeline
+    assert eng._flight.owners[:2] == [st_a, st_b]
+    assert list(eng._host_len) == [4, 4, 0]
+    assert list(eng._limit) == [66, 66, 0]
+    assert _turn(eng, now=100.0) == [True, True, False]
+    assert list(eng._host_len) == [5, 5, 0]      # two enqueued, one read
+    assert len(st_a.generated) == len(st_b.generated) == 2
+    assert eng._flight.args["in_flight"] == 1
+    if case == "evict":
+        eng._evict(1)
+        assert list(eng._pending) == [b] and eng._host_len[1] == 0
+        # the tick in flight ran row 1 for the evicted _Slot
+        assert _turn(eng) == [True, False, False]
+        assert len(st_b.generated) == 2 and len(st_a.generated) == 3
+        assert list(eng._flight.mask) == [True, False, False]
+        assert _turn(eng) == [True, False, False]
+        # the overlapped ticks are counted as each is read and serves:
+        # a tick nobody is left to be served by is read, not counted
+        assert eng.metrics.overlapped_ticks == 2
+        eng._evict(0)
+        assert _turn(eng) == [False] * 3 and eng._flight is None
+        assert eng.metrics.overlapped_ticks == 2
+    elif case == "new_tenant":
+        eng._evict(1)
+        c = _bind(eng, 1, 7, tok0=5)
+        c.key[:] = (11, 13)
+        eng._keys[1] = c.key
+        kept = eng._keys[0].copy()
+        assert _turn(eng) == [True, False, False]  # read first, whole
+        assert eng._flight.args["in_flight"] == 0
+        assert eng._flight.owners[1] is eng._slot_state[1]
+        assert eng._slot_state[1].generated == [5]
+        assert eng._tokens[1] == 5 and list(eng._keys[1]) == [11, 13]
+        # greedy rows split their keys too: row 0's came off the device
+        assert list(eng._keys[0]) != list(kept)
+        assert not eng._host_rows.any()
+        assert _turn(eng) == [True, True, False]
+        assert len(eng._slot_state[1].generated) == 2
+        assert len(st_b.generated) == 2 and not b.fut.done()
+    elif case == "deadline":
+        # the read after the deadline truncates row 0; the tick already
+        # in flight for it is dropped a turn later
+        assert _turn(eng, now=151.0) == [True, True, False]
+        assert list(a.fut.result(0)) == st_a.generated
+        assert len(st_a.generated) == 3 and eng._slot_state[0] is None
+        assert _turn(eng, now=152.0) == [False, True, False]
+        assert len(st_a.generated) == 3
+    else:
+        ref = _still(model, var, **kw)
+        _bind(ref, 0, 1), _bind(ref, 1, 2)
+        for _ in range(8):
+            _turn(ref)
+        fund = eng._ensure_pages     # no room for row 1: the page
+        eng._ensure_pages = lambda s, n: s != 1 and fund(s, n)  # policy
+        assert _turn(eng) == [True, True, False]   # pauses it; computed
+        assert not eng._active[1]                  # before, delivered
+        assert list(eng._flight.mask) == [True, False, False]
+        assert _turn(eng) == [True, False, False]
+        assert _turn(eng) == [True, False, False]
+        eng._ensure_pages = fund    # ... and resumes it: the row goes
+        # on from the token and key the device held for it
+        assert _turn(eng) == [True, False, False]
+        assert eng._flight.args["in_flight"] == 1
+        assert list(eng._flight.mask) == [True, True, False]
+        assert _turn(eng) == [True, True, False]
+        # the paused row's tokens are those of a row never paused
+        n = len(st_b.generated)
+        assert n == 4
+        assert st_b.generated == ref._slot_state[1].generated[:n]
+        assert st_a.generated == ref._slot_state[0].generated[:7]
+        ref.close()
     eng.close()
 
 
@@ -919,14 +1263,18 @@ def test_close_releases_device_buffers(engine_lm, kind):
     eng.close()  # idempotent
 
 
-def test_decode_production_arms_gates():
+def test_decode_production_arms_gates(read_each_tick_first):
     """ISSUE 14 acceptance on the long-context mixed-traffic bench:
     paged serves 2x the slots inside the dense arm's fixed HBM-estimate
     budget (HbmLedger is the meter), int8 at least halves cache bytes
     with parity within tolerance, the speculative arm reports its
     acceptance rate at >= 1.0x dense tokens/s, sampling is reproducible
-    per seed, and every arm serves with zero steady-state recompiles."""
+    per seed, and every arm serves with zero steady-state recompiles.
+    Speculation has to pay for its synchronous round, so the dense arm
+    it is held against runs the same one: every tick read before the
+    next is enqueued."""
     bench = pytest.importorskip("bench")
+    read_each_tick_first()
 
     rec = bench.decode_production_arms(n_requests=8)
     if rec["spec_speedup"] < 1.0 or rec["paged"]["peak_active_slots"] \
@@ -1028,12 +1376,16 @@ def test_tick_dispatch_counts_the_pages_held(engine_lm, tracer):
         eng.generate([1, 2, 3], 4, timeout=120)
         tracer.disable()
     ticks = [s for s in tracer.spans() if s.name == "loop/tick_dispatch"]
-    assert ticks and all(s.args == {"sampled_rows": 0} for s in ticks)
+    assert ticks and all(set(s.args) == {"sampled_rows", "in_flight"}
+                         and s.args["sampled_rows"] == 0 for s in ticks)
+    # the tick that fills the pipeline goes in from the host mirrors,
+    # the request's other two on their predecessor's outputs
+    assert [s.args["in_flight"] for s in ticks] == [0, 1, 1]
 
 
 @pytest.mark.parametrize("layout", ["paged", "dense"])
 def test_engine_under_a_live_profiler_session(engine_lm, tracer, tmp_path,
-                                              layout):
+                                              until, layout):
     """What only a ``--trace 1`` run executes, on the CPU: the profiler
     session turns the tracer on, ``_run_tick`` fetches the model's
     counters into the span's ``args``, every ``loop/*`` span is a
@@ -1107,6 +1459,40 @@ def test_engine_under_a_live_profiler_session(engine_lm, tracer, tmp_path,
         assert read_metric(metric, run) > 0
     cost = read_metric("admit_cost_ms.serve", run)
     assert cost is None or isinstance(cost, float)
+    # a session that opens and closes under decoding requests: a tick
+    # enqueued dark is read lit, and the last lit one is read dark
+    tracer.clear()
+    long = [(rs.randint(0, VOCAB, (5,)), 2000,
+             dict(temperature=1.5, top_p=0.95, seed=7 + i) if i else {})
+            for i in range(2)]
+
+    def lit_ticks():
+        return [s for s in tracer.spans()
+                if s.name == "loop/tick_dispatch"]
+
+    with _engine(model, var, max_len=2048, prompt_buckets=(8,),
+                 prefill_batch_sizes=(1,), **kw) as eng:
+        want_long = drive(eng, long)
+        decoded = eng.metrics.decoded_tokens
+        futs = [eng.submit(p, n, **opts) for p, n, opts in long]
+        until(lambda: eng.metrics.decoded_tokens >= decoded + 20)
+        jax.profiler.start_trace(str(tmp_path / "under_way"),
+                                 profiler_options=device_only())
+        try:
+            until(lambda: len(lit_ticks()) >= 20)
+        finally:
+            jax.profiler.stop_trace()
+        assert not any(f.done() for f in futs)  # closed under decoding
+        assert [list(f.result(120)) for f in futs] == want_long
+        until(lambda: not tracer.enabled)
+    ticks = lit_ticks()
+    # the first lit tick went in on its dark predecessor's outputs
+    assert ticks[0].args["in_flight"] == 1
+    assert all(s.args["sampled_rows"] == 1 and
+               ("pages_held" in s.args) == (layout == "paged")
+               for s in ticks)
+    waits = [s for s in tracer.spans() if s.name == "loop/tick_wait"]
+    assert 0 <= len(ticks) - len(waits) <= 1
 
 
 def test_token_times_ttft_and_gaps(engine_lm, tracer):

@@ -402,6 +402,89 @@ def test_tick_counters_reach_the_dispatch_span_only_while_tracing(tiny):
     tracer.clear()
 
 
+@pytest.mark.parametrize("session", ["whole", "under_way"])
+def test_a_ticks_counters_sit_on_its_own_dispatch_span(
+        tiny, read_each_tick_first, until, session):
+    """With one tick in flight a tick is read a turn after it was
+    enqueued.  ``whole``: traced from the first tick, its counters are
+    those of the same tick in a loop that reads each tick before the
+    next is enqueued.  ``under_way``: the tracer turns on and off under
+    decoding requests (a tick enqueued dark is read lit, the last lit
+    one is read dark): the tokens are the untraced engine's, every lit
+    tick carries its counters and ``traced_ticks`` reads them."""
+    from benchmark.flops_latent_moe import traced_ticks
+    from bigdl_tpu.telemetry import get_tracer
+
+    model, var, _ = tiny
+    tracer = get_tracer()
+    prompts, budget = [ids_of(60, 6), ids_of(61, 5)], [40, 25]
+
+    def engine():
+        # started by hand: both requests are admitted in the first turn
+        return DecodeEngine(model, var, slots=2, max_len=48,
+                            prompt_buckets=[8], prefill_batch_sizes=[1],
+                            kv_layout="paged", page_size=4, start=False)
+
+    def decode(eng, between=lambda futs: None):
+        futs = [eng.submit(p, n) for p, n in zip(prompts, budget)]
+        eng.start()
+        between(futs)
+        return [list(f.result(120)) for f in futs]
+
+    def lit():
+        return [s for s in tracer.spans() if s.name == "loop/tick_dispatch"]
+
+    tracer.disable()
+    tracer.clear()
+    try:
+        if session == "whole":
+            with engine() as eng:
+                tracer.enable()
+                got = decode(eng)
+                tracer.disable()
+            ours = [s.args for s in lit()]
+            tracer.clear()
+            read_each_tick_first()
+            with engine() as eng:
+                tracer.enable()
+                want = decode(eng)
+                tracer.disable()
+            theirs = [s.args for s in lit()]
+            assert got == want and len(ours) == budget[0] - 1
+            assert sum(a["in_flight"] for a in ours) >= len(ours) - 2
+            assert not any(a["in_flight"] for a in theirs)
+            # the pages are the pool's at dispatch: a row whose last
+            # token is in flight still holds its own for that one tick
+            assert all(a.pop("pages_held") >= b.pop("pages_held")
+                       for a, b in zip(ours, theirs))
+            for a in ours + theirs:
+                a.pop("in_flight")
+            assert ours == theirs
+            return
+        with engine() as eng:
+            want = decode(eng)
+        with engine() as eng:
+            def flip(futs):
+                until(lambda: eng.metrics.decoded_tokens >= 6)
+                tracer.enable()
+                until(lambda: len(lit()) >= 8)
+                tracer.disable()
+                assert not all(f.done() for f in futs)
+
+            assert decode(eng, flip) == want
+        ticks = lit()
+        assert ticks[0].args["in_flight"] == 1
+        assert all(np.shape(s.args["expert_tokens"]) == (2, 6)
+                   and "pages_held" in s.args for s in ticks)
+        traced = traced_ticks({"traffic": {"page_size": 4}})
+        assert len(traced) >= len(ticks) - 1
+        assert [t["expert_tokens"] for t in traced] == \
+            [s.args["expert_tokens"] for s in ticks[:len(traced)]]
+    finally:
+        tracer.disable()
+        tracer.clear()
+
+
 def test_opt_tokens_unchanged_through_the_generalised_page_code():
     """The multi-head model's paged engine (K and V leaves, allocated
     and written by its declaration) still serves what its uncached
