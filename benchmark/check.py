@@ -115,5 +115,8 @@ def print_compared(verdict: dict) -> None:
 
 
 def percentile(values, q: float) -> float:
-    """q in [0, 100], linear interpolation (numpy's default)."""
+    """q in [0, 100], linear interpolation (numpy's default); of no
+    values, not a number."""
+    if not len(values):
+        return float("nan")
     return float(np.percentile(np.asarray(values, np.float64), q))

@@ -69,21 +69,26 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=8.0)
-    ap.add_argument("--rate", type=float, default=None,
+    ap.add_argument("--rate", default=None,
                     help="offer this many requests/s instead of the "
-                         "mix's own (the sweep for the knee)")
+                         "mix's own (the sweep for the knee): one rate, "
+                         "or one a seed, separated by commas")
     args = ap.parse_args(argv)
 
     from benchmark.device import CompileCount, enable_cache, find_device
     from benchmark.run import load_cell
 
     cell = load_cell(args.workload)
-    if args.rate is not None:
-        cell["traffic"]["rate"] = args.rate
+    seeds = [int(s) for s in args.seeds.split(",")]
+    rates = [float(r) for r in args.rate.split(",")] if args.rate \
+        else [cell["traffic"].get("rate")]
     device = find_device(cell["chips"])
     enable_cache()
     compiles = CompileCount()
-    for seed in (int(s) for s in args.seeds.split(",")):
+    for i, seed in enumerate(seeds):
+        rate = rates[i % len(rates)]
+        if rate is not None:
+            cell["traffic"]["rate"] = rate
         t0 = time.perf_counter()
         if cell["traffic"]["driver"] == "train":
             readings = train_controls(cell, seed)
@@ -94,12 +99,20 @@ def main(argv=None) -> int:
                              seconds=args.seconds, trace=False,
                              t_start=t0, compiles=compiles,
                              control=cell["config"]["serve"]["control"])
-            readings = {"program": run["numbers"],
+            readings = {"served_tokens": run["served_tokens"],
+                        "program": run["numbers"],
                         "lower_precision": run["control_numbers"],
                         "end_to_end": run["end_to_end"],
                         "completed_tokens_per_s":
                         run["completed_tokens_per_s"],
-                        "rate": cell["traffic"]["rate"],
+                        "rate": cell["traffic"].get("rate"),
+                        "unanswered_at_open_and_close": run["waiting"],
+                        "in_flight_mean": run["in_flight_mean"],
+                        "lateness_mean_ms":
+                        1e3 * float(run["lateness_s"].mean()),
+                        "slot_occupancy": run["slot_occupancy"],
+                        "tick_ms_p50": run["tick_ms_p50"],
+                        "attempted": run["attempted"],
                         "failed": run["failed"]}
         print(json.dumps({"control": args.workload, "seed": seed,
                           "limits": cell["limits"], "readings": readings,

@@ -187,7 +187,8 @@ def run(cell, device, seed, seconds, trace, t_start, compiles) -> dict:
     opt.final_params = opt.final_state = model._variables = None
     del opt
 
-    reduced = trace_reduce.reduce_and_remove(trace_dir) if trace else None
+    reduced = trace_reduce.reduce_and_remove(
+        trace_dir, span_s=watch.trace_span["seconds"]) if trace else None
 
     t_ref = time.perf_counter()
     reference = importlib.import_module(

@@ -50,6 +50,24 @@ def lm_tick_cost(cfg: dict, active: float, context: float,
             "bytes": weights + kv}
 
 
+def tokens_held_by_traced_ticks(run: dict):
+    """Mean over the traced ticks of the tokens all their rows hold
+    together: ``loop/tick_dispatch`` carries the pages the slots hold
+    (``args.pages_held``; the tracer's ring holds the spans of the
+    profiler session), so bytes and device time are of the same ticks.
+    Nothing where no tick carries the counter."""
+    import statistics
+
+    from bigdl_tpu.telemetry import get_tracer
+
+    pages = [s.args["pages_held"] for s in get_tracer().spans()
+             if s.name == "loop/tick_dispatch" and s.args
+             and "pages_held" in s.args]
+    if not pages:
+        return None
+    return statistics.fmean(pages) * run["traffic"]["page_size"]
+
+
 # ---- ResNet-50 v1 (He et al. 2015, Table 1), 224 px
 def resnet50_forward_flops(image: int = 224, classes: int = 1000) -> float:
     """Convolutions and the classifier of ResNet-50 v1 for one image

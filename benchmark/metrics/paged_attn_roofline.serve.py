@@ -7,8 +7,6 @@ themselves, not the window's mean: ``loop/tick_dispatch`` carries the
 pages the slots hold (``args.pages_held``), so bytes and time are of
 the same ticks.  A program without the kernel or the counter reads
 nothing."""
-import statistics
-
 from benchmark import flops, trace_reduce
 
 KERNEL = r"paged_attn"  # ops/pallas/paged_attention.py: one call a layer
@@ -19,17 +17,10 @@ def read(run):
         return None
     seconds, calls = trace_reduce.seconds_matching(
         run["trace"]["by_name"], KERNEL)
-    if not calls:
-        return None
-    from bigdl_tpu.telemetry import get_tracer
-
-    pages = [s.args["pages_held"] for s in get_tracer().spans()
-             if s.name == "loop/tick_dispatch" and s.args
-             and "pages_held" in s.args]
-    if not pages:
+    tokens = flops.tokens_held_by_traced_ticks(run)
+    if not calls or tokens is None:
         return None
     model = run["config"]["model"]
-    tokens = statistics.fmean(pages) * run["traffic"]["page_size"]
     held = (flops.lm_tick_cost(model, 1, tokens)["bytes"]
             - flops.lm_tick_cost(model, 0, tokens)["bytes"])
     least = held / model["num_layers"] / run["peaks"]["hbm_bytes_per_s"]

@@ -98,8 +98,9 @@ def result_line(cell: dict, device: dict, run: dict, trace: bool) -> dict:
     if trace:
         dev["busy_s"] = run["trace"]["busy_s"]
         dev["window_s"] = run["trace"]["window_s"]
-        line["breakdown"] = {"device_ops": run["trace"]["device_ops"],
-                             "idle_gaps": run["trace"]["idle_gaps"]}
+        if run["trace"]["device_ops"]:  # none where the device was idle
+            line["breakdown"] = {"device_ops": run["trace"]["device_ops"],
+                                 "idle_gaps": run["trace"]["idle_gaps"]}
     line["compared"] = verdict["compared"]
     return line
 

@@ -30,6 +30,41 @@ def test_trace_reduction_on_the_recorded_fixture():
                                  pytest.approx(20e-6)]
 
 
+def test_a_trace_with_no_device_operation_is_a_reading():
+    """An idle traced span: busy 0, idle all of the span, nothing to
+    name, and no exception (a traced run used to end with exit 1)."""
+    from benchmark import trace_reduce as tr
+    from benchmark import trace_scopes
+    from benchmark.run import read_metric
+
+    path = os.path.join(ROOT, "benchmark", "fixtures", "idle.xplane.txt")
+    assert tr.window_of({0: []}) is None and tr.window_of({}) is None
+    r = tr.reduce_trace(path, span_s=3.0)
+    assert (r["busy_s"], r["window_s"]) == (0.0, 3.0)
+    assert r["device_ops"] == r["idle_gaps"] == [] and not r["by_module"]
+    assert trace_scopes.read(path) == {}
+    run = {"kind": "decode", "trace": r}
+    assert read_metric("device_idle_share.serve", run) == 100.0
+    assert read_metric("prefill_device_share.moe_serve", run) is None
+
+
+def test_a_cell_judged_otherwise_reads_the_same_run_by_its_own_names():
+    """A per-layer metric moves one end-to-end metric, so the routed
+    cell (tokens a second) reads the tick and the idle share under
+    names of its own; ``.serve`` stays the latency-judged cell's."""
+    from benchmark.run import read_metric
+
+    run = {"kind": "decode", "ticks": 7, "tick_ms_p50": 12.4,
+           "trace": {"busy_s": 2.4, "window_s": 3.0}}
+    for suffix in ("serve", "moe_serve"):
+        assert read_metric("tick_ms_p50." + suffix, run) == 12.4
+        assert read_metric("device_idle_share." + suffix, run) == \
+            pytest.approx(20.0)
+    assert read_metric("tick_ms_p50.moe_serve", dict(run, ticks=0)) is None
+    assert read_metric("device_idle_share.moe_serve",
+                       dict(run, trace=None)) is None
+
+
 def test_flops_against_hand_counts():
     from benchmark import flops
 
@@ -54,7 +89,7 @@ def test_traffic_is_a_pure_function_of_the_seed():
     from benchmark import traffic
 
     with open(os.path.join(ROOT, "benchmark", "traffic",
-                           "decode-steady.json")) as f:
+                           "decode-loaded.json")) as f:
         mix = json.load(f)
     a = traffic.request_stream(mix, 2 ** 31 + 5, 20.0, 50272)
     b = traffic.request_stream(mix, 2 ** 31 + 5, 20.0, 50272)
@@ -113,10 +148,17 @@ def test_every_name_in_benchmark_json_has_its_files():
         assert have("cells", w["name"] + ".json")
         assert len(w["why"]) <= 200
         cells.add(w["name"])
-    e2e = {m["name"] for m in bench["end_to_end"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert {"setup_s", "decode_tokens_per_s"} <= set(e2e)
+    for m in e2e.values():
+        assert name.match(m["name"]) and 0 < m["bound"] <= 0.1
+        assert set(m.get("workloads", cells)) <= cells
     for m in bench["per_layer"]:
         assert have("metrics", m["name"] + ".py"), m["name"]
         assert m["moves"] in e2e and set(m["workloads"]) <= cells
+        # every cell that reads it reports the metric it should move
+        assert set(m["workloads"]) <= set(
+            e2e[m["moves"]].get("workloads", cells)), m["name"]
     for cell in cells:  # setup_s, another end-to-end metric, a per-layer one
         reports = lambda m: cell in m.get("workloads", [cell])
         assert sum(reports(m) for m in bench["end_to_end"]) >= 2

@@ -33,8 +33,8 @@ def fresh_trace_dir() -> str:
     return path
 
 
-def reduce_and_remove(trace_dir: str) -> dict:
-    reduced = reduce_trace(find_xplane(trace_dir))
+def reduce_and_remove(trace_dir: str, span_s: float) -> dict:
+    reduced = reduce_trace(find_xplane(trace_dir), span_s=span_s)
     shutil.rmtree(trace_dir, ignore_errors=True)
     return reduced
 
@@ -111,12 +111,14 @@ def module_times(profile, t0: float, t1: float) -> Dict[str, List[float]]:
     return out
 
 
-def window_of(events_by_device) -> Tuple[float, float]:
+def window_of(events_by_device) -> Optional[Tuple[float, float]]:
+    """First start to last end of the device's operations; nothing
+    where the trace holds none."""
     starts = [ev[0][0] for ev in events_by_device.values() if ev]
     ends = [max(e for _, e, _ in ev)
             for ev in events_by_device.values() if ev]
     if not starts:
-        raise ValueError("the trace holds no device operation")
+        return None
     return min(starts), max(ends)
 
 
@@ -174,11 +176,19 @@ def seconds_matching(by_name, pattern: str) -> Tuple[float, int]:
 
 
 def reduce_trace(path: str, window: Optional[Tuple[float, float]] = None,
-                 top: int = 10) -> dict:
-    """Everything the metric readers need from one trace."""
+                 top: int = 10, span_s: float = 0.0) -> dict:
+    """Everything the metric readers need from one trace.  A trace in
+    which no operation ran on the device is a reading too: busy 0 and
+    idle all of the traced span (``span_s``, by the host's clock), with
+    no operation and no gap to name."""
     profile = load(path)
     events = device_events(profile)
-    t0, t1 = window or window_of(events)
+    window = window or window_of(events)
+    if window is None:
+        return {"window_s": span_s, "busy_s": 0.0, "devices": {},
+                "by_name": {}, "by_module": {}, "device_ops": [],
+                "idle_gaps": []}
+    t0, t1 = window
     per_device = {}
     for dev, evs in events.items():
         per_device[dev] = {
@@ -186,8 +196,6 @@ def reduce_trace(path: str, window: Optional[Tuple[float, float]] = None,
             "by_name": time_by_name(evs, t0, t1),
             "gaps": idle_gaps(evs, t0, t1, top),
         }
-    if not per_device:
-        raise ValueError("the trace holds no device plane")
     busy = sum(d["busy_s"] for d in per_device.values()) / len(per_device)
     first = per_device[min(per_device)]
     ops = sorted(first["by_name"].items(), key=lambda kv: -kv[1][0])

@@ -133,13 +133,15 @@ def read(trace: str) -> dict:
     of the line ``XLA Ops`` under the run of the compiled program
     (line ``XLA Modules``) it starts in, summed by operation name and
     scope.  ``trace`` is the profiler's directory or one xplane file;
-    a trace without a device plane raises."""
+    a trace in which no program ran on the device gives nothing."""
     path = trace if os.path.isfile(trace) \
         else trace_reduce.find_xplane(trace)
     profile = trace_reduce.load(path)
-    device = next(p for p in sorted(profile.planes, key=lambda p: p.name)
-                  if trace_reduce.DEVICE_PLANE.match(p.name))
-    lines = {ln.name: ln for ln in device.lines}
+    device = next((p for p in sorted(profile.planes, key=lambda p: p.name)
+                   if trace_reduce.DEVICE_PLANE.match(p.name)), None)
+    lines = {ln.name: ln for ln in device.lines} if device else {}
+    if "XLA Modules" not in lines:  # no program ran in the traced span
+        return {}
     modules = sorted((ev.start_ns, ev.start_ns + ev.duration_ns,
                       re.sub(r"\(\d+\)$", "", ev.name))
                      for ev in lines["XLA Modules"].events)

@@ -3,7 +3,12 @@ request streams, pure functions of the mix's parameters and ``--seed``.
 
 A serving mix fixes its *set* of request sizes and arrival gaps from its
 own ``base_seed``; ``--seed`` only reorders them and fills the prompts,
-so every seed offers the same work.
+so every seed offers the same work.  A mix with ``clients`` is a closed
+loop: the driver sends a client's next request when its last one is
+answered, so ``due`` is the order of sending and the set is a supply
+(``supply_requests_per_s``) of which a run consumes what the engine
+serves; ``strata`` deals that supply into blocks of equal work, so that
+what a window consumes does not depend on the seed's order either.
 """
 from __future__ import annotations
 
@@ -50,13 +55,36 @@ def _lognormal(rng, n: int, spec: dict):
     return np.clip(np.rint(x), spec["min"], spec["max"]).astype(np.int64)
 
 
+def stratified_blocks(prompt_len, max_new, strata: int):
+    """The set dealt into blocks of ``strata`` requests of equal work,
+    by the set alone: sorted by prompt length and cut into ``strata``
+    bands, a block holds one request of each band; within a band the
+    longest answer goes to the block that has the fewest answer tokens
+    so far.  -> indices, (blocks, strata); what does not fill a block
+    is left out."""
+    blocks = len(prompt_len) // strata
+    bands = np.lexsort((max_new, prompt_len))[:blocks * strata].reshape(
+        strata, blocks)
+    out = np.empty((blocks, strata), np.int64)
+    answer_tokens = np.zeros(blocks, np.int64)
+    for k, band in enumerate(bands):
+        band = band[np.argsort(-max_new[band], kind="stable")]
+        out[np.argsort(answer_tokens, kind="stable"), k] = band
+        answer_tokens += max_new[out[:, k]]
+    return out
+
+
 def request_stream(mix: dict, seed: int, seconds: float, vocab: int):
-    """Open-loop arrivals at ``mix['rate']`` requests/s: ``lead_in_s`` of
-    them before the window opens (due < 0, not measured), then
-    ``seconds`` of window.  -> list of dicts ``due, prompt, max_new,
-    measured`` sorted by due time."""
+    """Open loop: arrivals at ``mix['rate']`` requests/s, ``lead_in_s``
+    of them before the window opens (due < 0, not measured), then
+    ``seconds`` of window.  Closed loop (``mix['clients']``): a supply
+    of ``supply_requests_per_s`` over the same span, ``due`` its order;
+    the driver marks what it sent inside the window as measured.
+    -> list of dicts ``due, prompt, max_new, measured`` sorted by due."""
     lead = float(mix.get("lead_in_s", 0.0))
-    n = int(round(mix["rate"] * (lead + seconds)))
+    closed = "clients" in mix
+    rate = mix["supply_requests_per_s"] if closed else mix["rate"]
+    n = int(round(rate * (lead + seconds)))
     base = rng_for(mix["base_seed"])
     gaps = base.exponential(1.0, n)
     if mix.get("burst"):  # arrivals in bursts: the gaps inside are zero
@@ -71,9 +99,17 @@ def request_stream(mix: dict, seed: int, seconds: float, vocab: int):
     max_new = _lognormal(base, n, mix["output_tokens"])
 
     rng = rng_for(seed, 1)
-    order = rng.permutation(n)
-    due = np.cumsum(gaps[rng.permutation(n)]) - gaps[0] - lead
-    due = np.sort(due)
+    if mix.get("strata"):  # the seed orders the blocks and each inside
+        blocks = stratified_blocks(prompt_len, max_new, mix["strata"])
+        order = np.concatenate([rng.permutation(b) for b in
+                                blocks[rng.permutation(len(blocks))]])
+    else:
+        order = rng.permutation(n)
+    if closed:
+        due = np.arange(len(order), dtype=np.float64)
+    else:
+        due = np.cumsum(gaps[rng.permutation(n)]) - gaps[0] - lead
+        due = np.sort(due)
     out = []
     for i, j in enumerate(order):
         out.append({
@@ -81,6 +117,6 @@ def request_stream(mix: dict, seed: int, seconds: float, vocab: int):
             "prompt": rng.integers(0, vocab, int(prompt_len[j]),
                                    dtype=np.int64).astype(np.int32),
             "max_new": int(max_new[j]),
-            "measured": bool(0.0 <= due[i] < seconds),
+            "measured": bool(not closed and 0.0 <= due[i] < seconds),
         })
     return out
