@@ -582,9 +582,11 @@ class Transformer(Container):
         logits = self._head(params, state, h)
         return logits[:, 0], cache
 
-    def extend(self, params, state, cache, ids, advance=None):
+    def extend(self, params, state, cache, ids, advance=None, rows=None):
         """Append ``ids`` (N, T) at each row's *current* cache length
-        and return logits for every appended position (N, T, V) — the
+        and return logits for every appended position (N, T, V), or of
+        the positions ``rows`` (N, R) alone (the head then runs only
+        over those) — the
         workhorse behind chunked prefill (feed a long prompt in bounded
         chunks) and the speculative verify pass (score draft tokens in
         one forward).  On a fresh cache this is exactly ``prefill``
@@ -607,6 +609,8 @@ class Transformer(Container):
             if advance is not None:
                 new = dict(new, length=pos0 + advance.astype(jnp.int32))
             cache[lk] = new
+        if rows is not None:
+            h = jnp.take_along_axis(h, rows[:, :, None], axis=1)
         logits = self._head(params, state, h)
         return logits, cache
 

@@ -618,16 +618,19 @@ class LatentMoETransformer(Module):
         last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)
         return self._head(params, last)[:, 0], cache
 
-    def extend(self, params, state, cache, ids, advance=None):
+    def extend(self, params, state, cache, ids, advance=None, rows=None):
         """Append ``ids`` (N, T) at each row's current length; logits
-        for every appended position.  ``advance`` (N,) is how many of
-        the T are real (the rest pad the last chunk)."""
+        of the appended positions ``rows`` (N, R) (default every one:
+        the head runs only over what is asked for).  ``advance`` (N,) is
+        how many of the T are real (the rest pad the last chunk)."""
         h, new, _ = self._run(
             params, self._embed(params, ids),
             lambda lk, layer: lambda x: layer.mla.apply_cached(
                 params[lk]["mla"], x, cache[lk]),
             rows=None if advance is None
             else jnp.arange(ids.shape[1])[None, :] < advance[:, None])
+        if rows is not None:
+            h = jnp.take_along_axis(h, rows[:, :, None], axis=1)
         return self._head(params, h), _advanced(cache, new, advance)
 
     def decode_step(self, params, state, cache, ids_t):

@@ -256,12 +256,18 @@ def flash_attention(
     causal: bool = False, sm_scale: Optional[float] = None,
     block_q: int = 1024, block_k: int = 1024,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention over ``(B, H, T, D)`` tensors.
 
     On TPU this is the Pallas online-softmax kernel; elsewhere it runs
     in interpreter mode (tests) unless shapes don't divide the blocks,
     in which case the XLA reference path is used.
+
+    ``k``/``v`` with fewer heads ``(B, G, S, D)`` are grouped: query
+    head ``h`` reads K/V head ``h // (H/G)``.  ``window`` keeps, of the
+    causal keys, those at ``i - j < window``.  Both go through the
+    banded kernel (:func:`banded_flash_attention`), forward only.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -272,6 +278,26 @@ def flash_attention(
     from bigdl_tpu.ops.pallas import report as _report
 
     key_shape = (q.shape[0], q.shape[1], t, s, q.shape[3])  # tuning key
+    if window is not None or k.shape[1] != q.shape[1]:
+        if not causal:
+            raise ValueError("a band and grouped heads are causal only")
+        if interpret:
+            blocks = (min(block_q, t), min(block_k, s))
+            blocks = None if t % blocks[0] or s % blocks[1] else blocks
+        elif _report.force_pallas() or jax.default_backend() == "tpu":
+            blocks = band_blocks(t, s, q.shape[1] // k.shape[1])
+        else:
+            blocks = None
+        if blocks is None:
+            _report.record("flash_attention", "xla", key_shape)
+            return band_attention_reference(
+                q, k, v, jnp.arange(t)[None, :], window, sm_scale)
+        _report.record("flash_attention", "pallas")
+        return banded_flash_attention(
+            q, k, v, jnp.zeros((q.shape[0],), jnp.int32),
+            sm_scale=sm_scale, window=window, blocks=blocks,
+            name="flash_fwd", interpret=bool(interpret))
+
     on_tpu = (_report.force_pallas()
               or jax.default_backend() == "tpu")
     if interpret is None:
@@ -379,16 +405,23 @@ def prefix_blocks(t: int, s: int, block_q: int = 512, block_k: int = 1024):
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "blocks",
-                                             "interpret"))
+                                             "interpret", "window"))
 def prefix_flash_attention(q, k, v, offset, *, sm_scale: float, blocks,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           window: Optional[int] = None):
     """A chunk of queries against the extent that holds them: ``q``
     (B, H, T, D) at absolute positions ``offset[b] + i`` attends ``k``,
     ``v`` (B, H, S, D) at positions ``<=`` its own (forward only).  Key
     blocks past a q block's last position are neither computed nor
-    fetched again (their index is held at the last live block)."""
+    fetched again (their index is held at the last live block).  A
+    ``window`` or fewer K/V heads than query heads take the banded
+    kernel under this one's name (:func:`banded_flash_attention`)."""
     b, h, t, d = q.shape
     s = k.shape[2]
+    if window is not None or k.shape[1] != h:
+        return banded_flash_attention(
+            q, k, v, offset, sm_scale=sm_scale, window=window,
+            blocks=blocks, name="flash_prefix", interpret=interpret)
     bq, bk = blocks
     kernel = functools.partial(_prefix_kernel, heads=h, bq=bq, bk=bk,
                                sm_scale=sm_scale)
@@ -420,4 +453,153 @@ def prefix_flash_attention(q, k, v, offset, *, sm_scale: float, blocks,
         name="flash_prefix",  # the device trace finds the kernel by it
     )(offset.astype(jnp.int32), q.reshape(b * h, t, d),
       k.reshape(b * h, s, d), v.reshape(b * h, s, d))
+    return out.reshape(b, h, t, d)
+
+
+# ----------------------------------------------------------------------
+# a band of the causal keys, grouped heads: forward only
+# ----------------------------------------------------------------------
+def band_attention_reference(q, k, v, q_pos, window: Optional[int],
+                             sm_scale: float):
+    """The banded kernel's result by plain XLA: ``q`` (B, H, T, D) at
+    absolute ``q_pos`` (B|1, T) against ``k``, ``v`` (B, G, S, D) at
+    positions ``0..S-1``; f32 scores and softmax."""
+    b, h, t, d = q.shape
+    g, s = k.shape[1], k.shape[2]
+    qg = q.reshape(b, g, h // g, t, d)
+    sc = jnp.einsum("bgrtd,bgsd->bgrts", qg, k,
+                    preferred_element_type=jnp.float32) * sm_scale
+    behind = q_pos[:, :, None] - jnp.arange(s)[None, None, :]  # (B, T, S)
+    seen = behind >= 0
+    if window is not None:
+        seen &= behind < window
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], sc, _NEG_INF), -1)
+    out = jnp.einsum("bgrts,bgsd->bgrtd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, t, d).astype(q.dtype)
+
+
+def _band_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                 acc_ref, *, groups: int, bq: int, bk: int, last_kb: int,
+                 window: Optional[int], sm_scale: float):
+    """``_prefix_kernel`` for the ``rep`` query heads that share one
+    K/V head at once (their rows lie one under the other, so a K/V block
+    is fetched once for all of them), over the key blocks a q block's
+    band touches only: step ``j`` of the innermost axis is key block
+    ``lo + j``, live while it is not past ``hi`` (:func:`_band_blocks`)."""
+    off = off_ref[pl.program_id(0) // groups]
+    q_idx = pl.program_id(1)
+    j = pl.program_id(2)
+    lo, hi = _band_blocks(off, q_idx, bq, bk, last_kb, window)
+    rep = q_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(lo + j <= hi)
+    def _():
+        q = q_ref[:].reshape(rep * bq, q_ref.shape[-1]) * sm_scale
+        s = jax.lax.dot_general(
+            q, k_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (rep*bq, bk)
+        q_pos = off + q_idx * bq + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0) % bq
+        k_pos = (lo + j) * bk + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen &= q_pos - k_pos < window
+        s = jnp.where(seen, s, _NEG_INF)
+        m = m_ref[:]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # a row whose keys all lie in later blocks has seen nothing yet
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        o_ref[:] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+
+
+def _band_blocks(off, q_idx, bq: int, bk: int, last_kb: int,
+                 window: Optional[int]):
+    """The first and the last key block that q block ``q_idx`` (queries
+    at ``off + q_idx*bq ...``) reads."""
+    first_q = off + q_idx * bq
+    hi = jnp.minimum((first_q + bq - 1) // bk, last_kb)
+    lo = 0 if window is None \
+        else jnp.maximum(first_q - window + 1, 0) // bk
+    return lo, hi
+
+
+def band_blocks(t: int, s: int, rep: int = 1, block_q: int = 1024,
+                block_k: int = 512):
+    """The (bq, bk) :func:`banded_flash_attention` tiles with (``rep``
+    query heads share a q block's rows), or nothing where no legal pair
+    exists."""
+    bq = fit_block(t, max(block_q // rep, 128))
+    bk = fit_block(s, block_k, multiple=128)
+    return (bq, bk) if bq and bk else None
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "window", "blocks", "name", "interpret"))
+def banded_flash_attention(q, k, v, offset, *, sm_scale: float,
+                           window: Optional[int] = None, blocks=None,
+                           name: str = "flash_prefix",
+                           interpret: bool = False):
+    """Queries ``q`` (B, H, T, D) at absolute positions ``offset[b] +
+    i`` against ``k``, ``v`` (B, G, S, D), ``G`` dividing ``H``: query
+    head ``h`` reads K/V head ``h // (H/G)`` at positions ``<=`` its
+    own and, with ``window``, less than ``window`` behind it.  Key
+    blocks outside a q block's band are not in the grid (a band) or
+    held at the last live block's index (past the diagonal): none is
+    fetched to be masked.  Forward only."""
+    b, h, t, d = q.shape
+    g, s = k.shape[1], k.shape[2]
+    rep = h // g
+    bq, bk = blocks or band_blocks(t, s, rep)
+    last_kb = s // bk - 1
+    steps = s // bk if window is None \
+        else min(s // bk, (window + bq - 2) // bk + 2)
+    kernel = functools.partial(_band_kernel, groups=g, bq=bq, bk=bk,
+                               last_kb=last_kb, window=window,
+                               sm_scale=sm_scale)
+
+    def kv_index(n, i, j, off):
+        lo, hi = _band_blocks(off[n // g], i, bq, bk, last_kb, window)
+        return (n, jnp.minimum(lo + j, hi), 0)
+
+    q_spec = pl.BlockSpec((None, rep, bq, d),
+                          lambda n, i, j, off: (n, 0, i, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * g, t // bq, steps),
+            in_specs=[q_spec,
+                      pl.BlockSpec((None, bk, d), kv_index),
+                      pl.BlockSpec((None, bk, d), kv_index)],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((rep * bq, 1), jnp.float32),   # running max
+                pltpu.VMEM((rep * bq, 1), jnp.float32),   # running sum
+                pltpu.VMEM((rep * bq, d), jnp.float32),   # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b * g, rep, t, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,  # the device trace finds the kernel by it
+    )(offset.astype(jnp.int32), q.reshape(b * g, rep, t, d),
+      k.reshape(b * g, s, d), v.reshape(b * g, s, d))
     return out.reshape(b, h, t, d)

@@ -388,7 +388,8 @@ class DecodeEngine:
                     else paging.default_num_pages(self.slots,
                                                   self.max_len,
                                                   page_size)),
-                kv_dtype, gauge=self.metrics.record_pages)
+                kv_dtype, gauge=self.metrics.record_pages,
+                step=self._page_slack())
         else:
             self._kv = paging.DenseCache(self.slots, self.max_len)
 

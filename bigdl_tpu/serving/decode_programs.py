@@ -163,23 +163,52 @@ def build_paged_tick(model, **jit_kw):
     return jax.jit(tick, donate_argnums=(2,), **jit_kw)
 
 
-def build_paged_write_slot(**jit_kw):
+def build_paged_write_slot(windows: Optional[dict] = None,
+                           band_pages: int = 0, **jit_kw):
     """Splice one dense prefill-batch row into a slot's pages: every
     per-token leaf the layer declared (K and V, or a latent row;
     quantized when the pool is int8) goes through the slot's block-table
     row as whole pages.  Unmapped logical pages redirect to the trash
-    page — only the pages the allocator granted are ever written."""
+    page — only the pages the allocator granted are ever written.
+
+    ``windows`` ``{layer: window}`` names the layers that keep a band:
+    ``table_row`` is then the two extents' rows stacked (2, M), and of
+    such a layer only the ``band_pages`` pages from the one that holds
+    the band's first row (``length - window``) are written."""
     from bigdl_tpu.ops import paged_kv
+
+    windows = {lk: w for lk, w in (windows or {}).items() if w}
 
     def write(pool_cache, table_row, batch_cache, row, slot):
         out = {}
         for lk, pool in pool_cache.items():
             bc = batch_cache[lk]
             new = dict(pool)
-            for name in paged_kv.state_leaves(pool):
+            leaves = paged_kv.state_leaves(pool)
+            trow = table_row if not windows \
+                else table_row[1 if lk in windows else 0]
+            band = None
+            if lk in windows:
+                # the band's pages: from the one that holds its first row
+                page = pool[leaves[0]].shape[1]
+                length = jax.lax.dynamic_index_in_dim(
+                    bc["length"], row, keepdims=False)
+                first = jnp.clip((length - windows[lk]) // page, 0,
+                                 trow.shape[0] - band_pages)
+                short = trow.shape[0] * page - bc[leaves[0]].shape[2]
+                band = (first * page, band_pages * page, max(short, 0))
+                trow = jax.lax.dynamic_slice_in_dim(trow, first,
+                                                    band_pages)
+            for name in leaves:
                 r = jax.lax.dynamic_index_in_dim(
                     bc[name], row, axis=0, keepdims=False)  # (H,T,D)
-                paged_kv.write_pages(new, name, table_row,
+                if band is not None:
+                    start, rows, short = band
+                    if short:  # the extent's last page is not whole
+                        r = jnp.pad(r, ((0, 0), (0, short), (0, 0)))
+                    r = jax.lax.dynamic_slice_in_dim(r, start, rows,
+                                                     axis=1)
+                paged_kv.write_pages(new, name, trow,
                                      r.transpose(1, 0, 2))
             lrow = jax.lax.dynamic_slice_in_dim(bc["length"], row, 1,
                                                 axis=0)
@@ -203,15 +232,13 @@ def build_prefill_chunk(model, **jit_kw):
     interleaved with grid ticks instead of one giant stalling prefill.
     ``advance`` (1,) is the chunk's true token count (the final chunk
     is padded); returns the last *valid* position's logits — only the
-    final chunk's matter (they seed token 0)."""
+    final chunk's matter (they seed token 0) — and the model applies
+    its head to that one row alone."""
     def chunk(params, state, cache, ids, advance):
+        last = (jnp.maximum(advance, 1) - 1).astype(jnp.int32)
         logits, cache = model.extend(params, state, cache, ids,
-                                     advance=advance)
-        last = jnp.take_along_axis(
-            logits,
-            (jnp.maximum(advance, 1) - 1)[:, None, None].astype(
-                jnp.int32), axis=1)[:, 0]
-        return last, cache
+                                     advance=advance, rows=last[:, None])
+        return logits[:, 0], cache
 
     return jax.jit(chunk, donate_argnums=(2,), **jit_kw)
 

@@ -63,6 +63,7 @@ class ServingMetrics:
         self._lock = threading.Lock()
         self._queue_depth = 0
         self._pages_in_use = 0
+        self._window_pages_in_use = 0
         # raw (non-cumulative) latency histogram counts; the last cell
         # is the +Inf overflow
         self._lat_buckets = [0] * (len(LATENCY_BUCKETS) + 1)
@@ -131,11 +132,13 @@ class ServingMetrics:
             self._queue_depth = depth
 
     # -- paged KV / chunked prefill / speculative (ISSUE 14) -----------
-    def record_pages(self, in_use: int):
+    def record_pages(self, in_use: int, window_in_use: int = 0):
         """Current physical KV pages allocated (gauge; paged engines
-        call this on every allocation/release)."""
+        call this on every allocation/release): the full extent's and,
+        where window layers keep a pool of their own, that pool's."""
         with self._lock:
             self._pages_in_use = int(in_use)
+            self._window_pages_in_use = int(window_in_use)
 
     def inc_page_evictions(self, n: int = 1):
         self.base.inc("page_evictions", n)
@@ -247,6 +250,11 @@ class ServingMetrics:
             return self._pages_in_use
 
     @property
+    def window_pages_in_use(self) -> int:
+        with self._lock:
+            return self._window_pages_in_use
+
+    @property
     def page_evictions(self) -> int:
         return self.base.counter("page_evictions")
 
@@ -304,6 +312,7 @@ class ServingMetrics:
             "p50_token_gap_ms": round(self.token_gap_ms(50), 3),
             "p95_token_gap_ms": round(self.token_gap_ms(95), 3),
             "pages_in_use": self.pages_in_use,
+            "window_pages_in_use": self.window_pages_in_use,
             "page_evictions": self.page_evictions,
             "spec_acceptance_rate": round(self.spec_acceptance_rate(),
                                           4),
@@ -370,7 +379,9 @@ class ServingMetrics:
                      f"overlap={100 * s['overlapped_tick_share']:.0f}%")
         if s["pages_in_use"] or s["page_evictions"]:
             line += (f" | pages={s['pages_in_use']} "
-                     f"evict={s['page_evictions']}")
+                     + (f"window_pages={s['window_pages_in_use']} "
+                        if s["window_pages_in_use"] else "")
+                     + f"evict={s['page_evictions']}")
         if s["prefill_chunks"]:
             line += f" | chunks={s['prefill_chunks']}"
         if s["spec_acceptance_rate"]:

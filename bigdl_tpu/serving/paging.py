@@ -16,6 +16,14 @@ ops/paged_kv.py for the array ops).  Everything stateful — the free
 list, which slot owns which physical page — lives in
 :class:`PageAllocator`, in plain Python, under the engine loop's single
 thread.
+
+Two extents (docs/decoding.md §Two extents): a model whose layers say
+how many rows of a slot they keep (``decode_extents()``: all, or the
+last ``window``) gets a second pool and table for its window layers
+(:class:`BandAllocator`), in which the pages behind a slot's band go
+back to the free list as the slot grows; the tick then takes the two
+tables stacked (2, S, M).  A model that says nothing keeps the one pool
+and the (S, M) table.
 """
 from __future__ import annotations
 
@@ -102,6 +110,51 @@ class PageAllocator:
         self.table[slot, :] = 0
 
 
+class BandAllocator(PageAllocator):
+    """The window layers' pages: a slot keeps the pages that hold its
+    last ``window + step - 1`` rows (the band of every query the next
+    round can make: ``step`` tokens a round) and no other.  Logical
+    pages keep their absolute index, so a row's position is its
+    position in both extents; the entries behind the band are unmapped
+    (0, the trash page, which no query inside the band reads).  The pool
+    is sized for every slot at its fullest, so ``ensure`` never runs
+    short."""
+
+    def __init__(self, page_size: int, slots: int, max_len: int,
+                 window: int, step: int):
+        self.window, self.step = int(window), int(step)
+        # the band's rows, not aligned to a page: one page more
+        self.pages_per_band = min(
+            -(-max_len // page_size),
+            -(-(self.window + self.step - 1) // page_size) + 1)
+        super().__init__(slots * self.pages_per_band + 1, page_size,
+                         slots, max_len)
+        self._first = [0] * self.slots    # first logical page mapped
+
+    def first_row(self, tokens: int) -> int:
+        """The first row a round that leaves ``tokens`` rows reads."""
+        return max(tokens - self.step - self.window + 1, 0)
+
+    def ensure(self, slot: int, tokens: int) -> bool:
+        first = self.first_row(tokens) // self.page_size
+        own = self._owned[slot]
+        if not own:
+            self._first[slot] = first
+        drop = min(first - self._first[slot], len(own))
+        if drop > 0:        # the pages behind the band go back
+            lo = self._first[slot]
+            self._free.extend(own[:drop])
+            del own[:drop]
+            self.table[slot, lo:lo + drop] = 0
+            self._first[slot] = first if not own else lo + drop
+        for logical in range(self._first[slot] + len(own),
+                             self.pages_for(tokens)):
+            phys = self._free.popleft()
+            self.table[slot, logical] = phys
+            own.append(phys)
+        return True
+
+
 class DenseCache:
     """One ``max_len`` row per slot, reserved whole: nothing to budget,
     so every request for room is granted."""
@@ -175,41 +228,65 @@ class PagedCache(PageAllocator):
 
     def __init__(self, slots: int, max_len: int, page_size: int,
                  num_pages: int, kv_dtype=None,
-                 gauge: Callable[[int], None] = lambda n: None):
+                 gauge: Callable[..., None] = lambda n, band=0: None,
+                 step: int = 1):
         super().__init__(num_pages, page_size, slots, max_len)
         self.kv_dtype = kv_dtype
+        self.step = int(step)   # tokens a slot may write in one round
         self.page_bytes = 0
+        # the window layers' extent, where the model declares one
+        self.band: Optional[BandAllocator] = None
+        self.band_page_bytes = 0
+        self._windows: dict = {}
         self._gauge = gauge
 
     # ----------------------------------------------- cache and programs
     def init_cache(self, model, dtype):
+        extents = getattr(model, "decode_extents", dict)()
+        self._windows = {lk: w for lk, w in extents.items() if w}
+        kw = {}
+        if self._windows:
+            window, = set(self._windows.values())   # one band a model
+            self.band = BandAllocator(self.page_size, self.slots,
+                                      self.max_len, window, self.step)
+            kw["window_pages"] = self.band.num_pages
         cache = model.init_paged_cache(self.num_pages, self.page_size,
                                        self.slots, dtype,
-                                       kv_dtype=self.kv_dtype)
-        # bytes one physical page costs across every layer's pool
-        # (K + V + scales)
-        self.page_bytes = sum(
+                                       kv_dtype=self.kv_dtype, **kw)
+        # bytes one physical page costs across every layer's pool of
+        # its extent (K + V + scales)
+        page_bytes = lambda lks: sum(
             int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
-            for pool in cache.values()
-            for name, leaf in pool.items() if name != "length")
+            for lk in lks for name, leaf in cache[lk].items()
+            if name != "length")
+        self.page_bytes = page_bytes(lk for lk in cache
+                                     if lk not in self._windows)
+        self.band_page_bytes = page_bytes(self._windows)
         return cache
 
     def build_tick(self, model):
         return decode_programs.build_paged_tick(model)
 
     def build_write(self):
-        return decode_programs.build_paged_write_slot()
+        return decode_programs.build_paged_write_slot(
+            self._windows,
+            self.band.pages_per_band if self.band else 0)
 
     def build_verify(self, model, k: int):
         return decode_programs.build_spec_verify(model, k, paged=True)
 
+    def _tables(self):
+        """(S, M), or the two extents' tables stacked (2, S, M)."""
+        return self.table if self.band is None \
+            else np.stack([self.table, self.band.table])
+
     def tick_extra(self) -> tuple:
         """The block table, a plain device argument each call (values
         change, shape never)."""
-        return (self.table,)
+        return (self._tables(),)
 
     def write_extra(self, slot: int) -> tuple:
-        return (self.table[slot],)
+        return (self._tables()[..., slot, :],)
 
     # ------------------------------------------------------------- room
     def check_servable(self, tokens: int):
@@ -223,24 +300,40 @@ class PagedCache(PageAllocator):
     def reserve(self, slot: int, tokens: int) -> bool:
         """Grow ``slot`` to hold ``tokens``; False (nothing changed)
         when the free list is short."""
-        free = self.pages_free
+        held = self._held()
         if not self.ensure(slot, tokens):
             return False
-        if self.pages_free != free:
-            self._gauge(self.pages_in_use)
+        if self.band is not None:   # never short: sized for every slot
+            self.band.ensure(slot, tokens)
+        if self._held() != held:
+            self._gauge(*self._held())
         return True
 
     def release(self, slot: int):
         super().release(slot)
-        self._gauge(self.pages_in_use)
+        if self.band is not None:
+            self.band.release(slot)
+        self._gauge(*self._held())
+
+    def _held(self) -> tuple:
+        """Pages in use: ``(full extent,)`` or ``(full, window)``."""
+        return (self.pages_in_use,) if self.band is None \
+            else (self.pages_in_use, self.band.pages_in_use)
 
     # ---------------------------------------------------------- readouts
     def resident_bytes(self) -> int:
-        """Bytes of the pages actually held — the readout that
-        retirement frees memory."""
-        return self.pages_in_use * self.page_bytes
+        """Bytes of the pages actually held, both extents' — the
+        readout that retirement frees memory."""
+        return self.pages_in_use * self.page_bytes + (
+            self.band.pages_in_use * self.band_page_bytes
+            if self.band else 0)
 
     def span_args(self) -> Optional[dict]:
-        """``loop/tick_dispatch``'s counter: the share of the ``S * M``
-        extent this tick's attention has to read."""
-        return {"pages_held": self.pages_in_use}
+        """``loop/tick_dispatch``'s counters: the share of the ``S * M``
+        extent this tick's attention has to read at a full layer
+        (``pages_held``) and, where window layers keep their own, at one
+        of those (``window_pages_held``)."""
+        args = {"pages_held": self.pages_in_use}
+        if self.band is not None:
+            args["window_pages_held"] = self.band.pages_in_use
+        return args

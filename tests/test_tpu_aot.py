@@ -346,3 +346,89 @@ def test_latent_serving_kernels_compile_at_cell_shapes(one_chip):
         one_chip, S((b, h, t, d), BF16), S((b, h, s, d), BF16),
         S((b, h, s, d), BF16), S((b,), jnp.int32))
     assert "flash_prefix" in text
+
+
+# the window-and-full cell's geometry (benchmark/traffic/
+# decode-mixedlen-closed32.json): 32 slots x 32768 in pages of 64
+SWA_SLOTS, SWA_MAX_LEN, SWA_PAGE, SWA_WINDOW = 32, 32768, 64, 2048
+SWA_HEADS, SWA_KV_HEADS, SWA_DIM = 32, 4, 128
+
+
+def test_window_serving_kernels_compile_at_cell_shapes(one_chip):
+    """``paged_attn`` with grouped heads and a first row over a bf16
+    pool, and the banded kernel as the chunk (``flash_prefix``: a band,
+    no band) and the bucketed prefill (``flash_fwd``) run it, at the
+    window-and-full cell's shapes."""
+    from bigdl_tpu.ops.pallas.flash_attention import (
+        band_blocks, banded_flash_attention, flash_attention)
+    from bigdl_tpu.ops.pallas.paged_attention import paged_attn
+
+    per_slot = SWA_MAX_LEN // SWA_PAGE
+    pool = S((SWA_SLOTS * 33 + 1, SWA_PAGE, SWA_KV_HEADS * SWA_DIM), BF16)
+    text = _compile(
+        lambda q, k, v, table, kv_len, first: paged_attn(
+            q, k, v, table, kv_len, first, num_heads=SWA_HEADS,
+            kv_heads=SWA_KV_HEADS),
+        one_chip, S((SWA_SLOTS, SWA_HEADS, SWA_DIM), BF16), pool, pool,
+        S((SWA_SLOTS, per_slot), jnp.int32), S((SWA_SLOTS,), jnp.int32),
+        S((SWA_SLOTS,), jnp.int32))
+    assert "paged_attn" in text
+    t = 2048
+    blocks = band_blocks(t, SWA_MAX_LEN, SWA_HEADS // SWA_KV_HEADS)
+    assert blocks == (128, 512)
+    q = S((1, SWA_HEADS, t, SWA_DIM), BF16)
+    kv = S((1, SWA_KV_HEADS, SWA_MAX_LEN, SWA_DIM), BF16)
+    for window in (SWA_WINDOW, None):
+        text = _compile(
+            lambda q, k, v, off: banded_flash_attention(
+                q, k, v, off, sm_scale=0.1, window=window, blocks=blocks),
+            one_chip, q, kv, kv, S((1,), jnp.int32))
+        assert "flash_prefix" in text
+    fresh = S((1, SWA_KV_HEADS, t, SWA_DIM), BF16)
+    text = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        one_chip, q, fresh, fresh)
+    assert "flash_fwd" in text
+
+
+def test_window_moe_tick_compiles_at_published_widths(one_chip):
+    """The paged tick of the decoder with window and full layers at the
+    published widths (one window and one full layer, both routed over
+    all 128 experts; bf16; the cell's 32 slots x 32768 in pages of 64):
+    both extents' pools are donated and aliased with no whole-pool
+    copy, attention is ``paged_attn`` reading them in place, the expert
+    products are the grouped-matmul kernel."""
+    import json
+    import os
+
+    from bigdl_tpu.nn.window_moe import WindowMoETransformer
+    from bigdl_tpu.ops.pallas import report
+    from bigdl_tpu.serving import paging
+    from bigdl_tpu.serving.decode_programs import build_paged_tick
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-mini-5of32.json")) as f:
+        cfg = json.load(f)["model"]
+    model = WindowMoETransformer(**dict(
+        cfg, num_hidden_layers=2, num_dense_layers=0,
+        layer_types=["sliding_attention", "full_attention"]))
+    kv = paging.PagedCache(
+        SWA_SLOTS, SWA_MAX_LEN, SWA_PAGE,
+        paging.default_num_pages(SWA_SLOTS, SWA_MAX_LEN, SWA_PAGE))
+    var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), BF16))
+    cache = jax.eval_shape(lambda: kv.init_cache(model, BF16))
+    assert cache["layer0"]["k"].shape == (SWA_SLOTS * 33 + 1, SWA_PAGE, 512)
+    assert cache["layer1"]["k"].shape == (SWA_SLOTS * 512 + 1, SWA_PAGE,
+                                          512)
+    before = report.report().get("paged_attention", {}).get("pallas", 0)
+    compiled = build_paged_tick(model, **_on(one_chip)).lower(
+        var["params"], var["state"], cache,
+        S((2, SWA_SLOTS, SWA_MAX_LEN // SWA_PAGE), jnp.int32),
+        S((SWA_SLOTS,), jnp.int32), S((SWA_SLOTS,), jnp.bool_),
+        S((SWA_SLOTS, 2), jnp.uint32), S((SWA_SLOTS,), F32),
+        S((SWA_SLOTS,), jnp.int32), S((SWA_SLOTS,), F32)).compile()
+    text = compiled.as_text()
+    _pool_in_place(text, cache)
+    assert report.report()["paged_attention"]["pallas"] == before + 2
+    assert "paged_attn" in text and "ragged-dot" in text
