@@ -8,6 +8,13 @@ choice is limited to the ``topk_group`` best of ``n_group`` groups, the
 sees every token.  There is no capacity: a token is never dropped, and
 a row's output depends on no other row of its batch.
 
+Two forms of expert are arguments of the layer: ``activation="silu"``,
+the gated unit ``Wd (silu(Wg x) * (Wu x))`` of three matrices, and
+``"relu2"``, ``Wd relu(Wu x)**2`` of two (Nemotron-H).  ``latent_size``
+runs the routed experts in a space of that width shared by all of them
+(LatentMoE): ``W_up sum_e w_e E_e(W_down x)``, the router and the shared
+expert reading ``x`` at full width.
+
 Expert parallelism is in the constructor: ``experts_held`` names the
 experts whose weights this rank holds.  The router keeps its published
 width, and the layer computes the part of the sum that its own experts
@@ -58,12 +65,31 @@ def gated_ffn(x, p):
             .astype(w) @ p["wd"]).astype(x.dtype)
 
 
+def relu2_ffn(x, p):
+    """``relu(x Wu)**2 Wd``, the products in the weights' dtype."""
+    w = p["wu"].dtype
+    u = (x.astype(w) @ p["wu"]).astype(jnp.float32)
+    return (jnp.square(jax.nn.relu(u)).astype(w) @ p["wd"]).astype(x.dtype)
+
+
 def _gated_init(rng, d_in: int, width: int, dtype, lead=()):
     k1, k2, k3 = jax.random.split(rng, 3)
     init = RandomNormal(0.0, 0.02)
     return {"wg": init(k1, lead + (d_in, width), dtype),
             "wu": init(k2, lead + (d_in, width), dtype),
             "wd": init(k3, lead + (width, d_in), dtype)}
+
+
+def _relu2_init(rng, d_in: int, width: int, dtype, lead=()):
+    k1, k2 = jax.random.split(rng)
+    init = RandomNormal(0.0, 0.02)
+    return {"wu": init(k1, lead + (d_in, width), dtype),
+            "wd": init(k2, lead + (width, d_in), dtype)}
+
+
+# an expert's form: (its weights' init, the unit itself)
+_FORMS = {"silu": (_gated_init, gated_ffn),
+          "relu2": (_relu2_init, relu2_ffn)}
 
 
 class GatedFeedForward(Module):
@@ -90,8 +116,14 @@ class RoutedExperts(Module):
                  routed_scaling_factor: float = 1.0,
                  norm_topk_prob: bool = True, n_shared: int = 1,
                  experts_held: Optional[Sequence[int]] = None,
+                 activation: str = "silu",
+                 latent_size: Optional[int] = None,
+                 shared_width: Optional[int] = None,
                  name: Optional[str] = None):
         super().__init__(name)
+        if activation not in _FORMS:
+            raise ValueError(f"activation {activation!r} is none of "
+                             f"{sorted(_FORMS)}")
         if n_routed % n_group:
             raise ValueError(f"{n_routed} experts do not split into "
                              f"{n_group} groups")
@@ -101,6 +133,8 @@ class RoutedExperts(Module):
         self.scaling = float(routed_scaling_factor)
         self.norm_topk_prob = norm_topk_prob
         self.n_shared = n_shared
+        self.activation, self.latent_size = activation, latent_size
+        self.shared_width = shared_width or n_shared * expert_width
         held = list(range(n_routed)) if experts_held is None \
             else [int(e) for e in experts_held]
         if len(set(held)) != len(held) or not all(
@@ -116,17 +150,23 @@ class RoutedExperts(Module):
 
     def init_params(self, rng, dtype=jnp.float32):
         kr, ke, ks = jax.random.split(rng, 3)
+        init = _FORMS[self.activation][0]
+        d = self.hidden_size
         p = {"router": {
-            "weight": RandomNormal(0.0, 0.02)(
-                kr, (self.hidden_size, self.n_routed), dtype),
+            "weight": RandomNormal(0.0, 0.02)(kr, (d, self.n_routed),
+                                              dtype),
             "bias": jnp.zeros((self.n_routed,), dtype)},
-            "experts": _gated_init(ke, self.hidden_size,
-                                   self.expert_width, dtype,
-                                   lead=(len(self.experts_held),))}
+            "experts": init(ke, self.latent_size or d, self.expert_width,
+                            dtype, lead=(len(self.experts_held),))}
+        if self.latent_size:
+            kd, ku = jax.random.split(jax.random.fold_in(rng, 3))
+            p["latent"] = {
+                "down": RandomNormal(0.0, 0.02)(kd, (d, self.latent_size),
+                                                dtype),
+                "up": RandomNormal(0.0, 0.02)(ku, (self.latent_size, d),
+                                              dtype)}
         if self.n_shared:
-            p["shared"] = _gated_init(
-                ks, self.hidden_size, self.n_shared * self.expert_width,
-                dtype)
+            p["shared"] = init(ks, d, self.shared_width, dtype)
         return p
 
     # ---------------------------------------------------------- router
@@ -178,14 +218,17 @@ class RoutedExperts(Module):
             sent = jnp.take(x, order[:cap] // self.k, axis=0)
         with jax.named_scope("moe/experts"):
             p = params["experts"]
-            wd = p["wg"].dtype
+            wd = p["wd"].dtype
             sent = sent.astype(wd)
             expect = max(1, x.shape[0] * self.k // self.n_routed)
-            g = self._product(sent, p["wg"], counts, expect)
             u = self._product(sent, p["wu"], counts, expect)
-            h = (jax.nn.silu(g.astype(jnp.float32))
-                 * u.astype(jnp.float32)).astype(wd)
-            out = self._product(h, p["wd"], counts, expect)
+            if self.activation == "silu":
+                g = self._product(sent, p["wg"], counts, expect)
+                h = jax.nn.silu(g.astype(jnp.float32)) \
+                    * u.astype(jnp.float32)
+            else:
+                h = jnp.square(jax.nn.relu(u.astype(jnp.float32)))
+            out = self._product(h.astype(wd), p["wd"], counts, expect)
         with jax.named_scope("moe/combine"):
             # a token reads its own rows, in the order of its experts'
             # ids, whatever else the batch holds; a row that did not land
@@ -239,12 +282,19 @@ class RoutedExperts(Module):
         lead = x.shape[:-1]
         flat = x.reshape(-1, x.shape[-1])
         ids, w = self.route(params, flat)
+        sent = flat
+        if self.latent_size:
+            with jax.named_scope("moe/latent"):
+                sent = flat @ params["latent"]["down"].astype(flat.dtype)
         y, counts = self.routed_part(
-            params, flat, ids, w,
+            params, sent, ids, w,
             None if rows is None else rows.reshape(-1))
+        if self.latent_size:
+            with jax.named_scope("moe/latent"):
+                y = y @ params["latent"]["up"].astype(y.dtype)
         if include_shared and self.n_shared:
             with jax.named_scope("moe/shared"):
-                y = y + gated_ffn(flat, params["shared"])
+                y = y + _FORMS[self.activation][1](flat, params["shared"])
         return y.reshape(lead + (x.shape[-1],)), counts
 
     def apply(self, params, state, x, training=False, rng=None):

@@ -186,9 +186,11 @@ class GatedWindowAttention(Module):
         return {"k": (self.kv_heads, self.head_dim),
                 "v": (self.kv_heads, self.head_dim)}
 
-    def apply_prefill(self, params, x, cache):
+    def apply_prefill(self, params, x, cache, rows=None):
         """A fresh row's prompt: keep its K and V at ``[0, T)`` and
-        attend over the prompt itself."""
+        attend over the prompt itself.  ``rows`` (the tokens that are no
+        padding) is not needed: a row past a slot's length is never
+        read."""
         pos = jnp.arange(x.shape[1])[None, :]
         q, k, v, gate = self.project(params, x, pos)
         kept = {name: jax.lax.dynamic_update_slice_in_dim(
@@ -198,7 +200,7 @@ class GatedWindowAttention(Module):
         return out, dict(cache, **kept,
                          length=cache["length"] + x.shape[1])
 
-    def apply_cached(self, params, x, cache):
+    def apply_cached(self, params, x, cache, rows=None):
         """Append ``x`` (N, T, d) at each row's ``length`` of the dense
         cache (absolute rows: a window layer's staging row is as long as
         a full layer's) and attend under the layer's mask."""
@@ -216,7 +218,7 @@ class GatedWindowAttention(Module):
         return self.finish(params, out, gate), dict(
             cache, **kept, length=cache["length"] + t)
 
-    def apply_paged(self, params, x, cache, table, active):
+    def apply_paged(self, params, x, cache, table, active, rows=None):
         """``apply_cached`` over this layer's pool and block ``table``
         (S, M): one token a slot on the TPU reads only the pages held,
         a window layer none before its band
@@ -258,6 +260,8 @@ class SandwichBlock(Module):
     """A norm before and after the mixer, a norm before and after the
     feed-forward (dense or routed)."""
 
+    mixer = "attn"  # the mixer's key in the block's parameters
+
     def __init__(self, attention: GatedWindowAttention, ffn: Module,
                  rms_norm_eps: float = 1e-5, name: Optional[str] = None):
         super().__init__(name)
@@ -271,13 +275,13 @@ class SandwichBlock(Module):
                 "ffn": self.ffn.init_params(kf, dtype), "ln2_post": norm()}
 
     def run(self, params, x, attend, rows=None):
-        """``attend(h) -> (a, aux)`` is the attention path; ``rows``
-        (N, T) bool marks the tokens that are no padding.  ->
+        """``attend(h, rows) -> (a, aux)`` is the attention path;
+        ``rows`` (N, T) bool marks the tokens that are no padding.  ->
         ``(x, aux, expert counts or None)``."""
         norm = lambda v, name: rms_norm(v, params[name]["weight"], self.eps)
         with jax.named_scope("attention"), \
                 jax.named_scope(self.attn.kind):
-            a, aux = attend(norm(x, "ln1"))
+            a, aux = attend(norm(x, "ln1"), rows)
             x = x + norm(a, "ln1_post")
         with jax.named_scope("ffn"):
             h = norm(x, "ln2")
@@ -290,7 +294,7 @@ class SandwichBlock(Module):
 
     def apply(self, params, state, x, training=False, rng=None):
         out, _, _ = self.run(
-            params, x, lambda h: self.attn.apply(params["attn"], {}, h))
+            params, x, lambda h, rows: self.attn.apply(params["attn"], {}, h))
         return out, state
 
 
@@ -366,11 +370,14 @@ class WindowMoETransformer(Module):
 
     def _run(self, params, h, attend_of, rows=None):
         """Every block over ``h``; ``attend_of(lk, layer)`` gives the
-        block's attention path.  -> ``(h, {lk: aux}, {lk: counts})``."""
+        block's mixer path, ``(x, rows) -> (a, aux)``.  -> ``(h, {lk:
+        aux}, {lk: counts})``; a block that keeps no state gives no
+        aux."""
         aux, counts = {}, {}
         for lk, layer in zip(self._layer_keys(), self.layers):
-            h, aux[lk], c = layer.run(params[lk], h, attend_of(lk, layer),
-                                      rows)
+            h, a, c = layer.run(params[lk], h, attend_of(lk, layer), rows)
+            if a is not None:
+                aux[lk] = a
             if c is not None:
                 counts[lk] = c
         return h, aux, counts
@@ -378,8 +385,8 @@ class WindowMoETransformer(Module):
     def apply(self, params, state, ids, training=False, rng=None):
         h, _, _ = self._run(
             params, self._embed(params, ids),
-            lambda lk, layer: lambda x: layer.attn.apply(
-                params[lk]["attn"], {}, x))
+            lambda lk, layer: lambda x, rows: layer.attn.apply(
+                params[lk][layer.mixer], {}, x))
         return self._head(params, h), state
 
     # ---------------------------------------------- the engine's contract
@@ -420,8 +427,8 @@ class WindowMoETransformer(Module):
             else lengths.astype(jnp.int32)
         h, new, _ = self._run(
             params, self._embed(params, ids),
-            lambda lk, layer: lambda x: layer.attn.apply_prefill(
-                params[lk]["attn"], x, cache[lk]),
+            lambda lk, layer: lambda x, rows: layer.attn.apply_prefill(
+                params[lk][layer.mixer], x, cache[lk], rows),
             rows=jnp.arange(t)[None, :] < lengths[:, None])
         cache = {lk: dict(c, length=lengths) for lk, c in new.items()}
         last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)
@@ -433,8 +440,8 @@ class WindowMoETransformer(Module):
         ``advance`` (N,) is how many of the T are real."""
         h, new, _ = self._run(
             params, self._embed(params, ids),
-            lambda lk, layer: lambda x: layer.attn.apply_cached(
-                params[lk]["attn"], x, cache[lk]),
+            lambda lk, layer: lambda x, rows: layer.attn.apply_cached(
+                params[lk][layer.mixer], x, cache[lk], rows),
             rows=None if advance is None
             else jnp.arange(ids.shape[1])[None, :] < advance[:, None])
         if rows is not None:
@@ -457,9 +464,9 @@ class WindowMoETransformer(Module):
             rows &= jnp.arange(ids.shape[1])[None, :] < advance[:, None]
         h, new, counts = self._run(
             params, self._embed(params, ids),
-            lambda lk, layer: lambda x: layer.attn.apply_paged(
-                params[lk]["attn"], x, cache[lk],
-                band if layer.attn.window else full, active),
+            lambda lk, layer: lambda x, rows: layer.attn.apply_paged(
+                params[lk][layer.mixer], x, cache[lk],
+                band if layer.attn.window else full, active, rows),
             rows=rows)
         counters = {"expert_tokens": jnp.stack(list(counts.values()))} \
             if counts else {}

@@ -9,6 +9,14 @@ and meters by that declaration: a dense cache leaf is ``(N, heads, T,
 width)``, a pool leaf ``(P, Q, heads*width)`` (:func:`init_cache`,
 :func:`init_pool`, :func:`state_leaves`).
 
+A layer may also declare a leaf it keeps *a slot* and not a token: a
+:class:`Block` (the state of a recurrent or state-space layer: a fixed
+block whatever the slot's length).  Such a leaf is ``(N, *shape)`` in
+the dense cache and ``(S, *shape)`` in the pool alike: it is indexed by
+slot, never paged, and costs no page (:func:`page_bytes` leaves it
+out).  The slot write copies a prefilled row's block whole into its
+slot; the tick updates it in place.
+
 The dense decode cache (``init_cache``) reserves
 ``max_len`` rows per slot up front — worst-case HBM whether or not a
 request ever grows that long.  The paged layout breaks each layer's
@@ -68,8 +76,25 @@ sweep and the pallas-routing lint rule.
 """
 from __future__ import annotations
 
+from typing import NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
+
+
+class Block(NamedTuple):
+    """A leaf kept whole a slot: ``shape`` of one slot's block, in
+    ``dtype`` (None: the cache's)."""
+    shape: tuple
+    dtype: Optional[str] = None
+
+
+def is_block(spec) -> bool:
+    return isinstance(spec, Block)
+
+
+def _block_zeros(spec: Block, batch: int, dtype):
+    return jnp.zeros((batch,) + tuple(spec.shape), spec.dtype or dtype)
 
 
 def num_logical_pages(max_len: int, page_size: int) -> int:
@@ -111,8 +136,9 @@ def init_cache(leaves: dict, batch: int, max_len: int,
     """One layer's dense cache for the declared ``leaves``
     ``{name: (heads, width)}``: ``(batch, heads, max_len, width)`` each
     and a per-row ``length``."""
-    cache = {name: jnp.zeros((batch, h, max_len, w), dtype)
-             for name, (h, w) in leaves.items()}
+    cache = {name: _block_zeros(spec, batch, dtype) if is_block(spec)
+             else jnp.zeros((batch, spec[0], max_len, spec[1]), dtype)
+             for name, spec in leaves.items()}
     cache["length"] = jnp.zeros((batch,), jnp.int32)
     return cache
 
@@ -127,11 +153,13 @@ def init_pool(num_pages: int, page_size: int, leaves: dict, batch: int,
     independent in the engine.
     """
     store = jnp.int8 if quantized else dtype
-    pool = {name: jnp.zeros((num_pages, page_size, h * w), store)
-            for name, (h, w) in leaves.items()}
+    pool = {name: _block_zeros(spec, batch, dtype) if is_block(spec)
+            else jnp.zeros((num_pages, page_size, spec[0] * spec[1]), store)
+            for name, spec in leaves.items()}
     pool["length"] = jnp.zeros((batch,), jnp.int32)
     if quantized:
-        for name, (h, _) in leaves.items():
+        for name, (h, _) in ((n, v) for n, v in leaves.items()
+                             if not is_block(v)):
             pool[name + "_scale"] = jnp.zeros(
                 (num_pages, page_size * _scale_width(h)), jnp.float32)
     return pool
@@ -153,7 +181,7 @@ def page_bytes(page_size: int, leaves: dict, dtype=jnp.float32,
     declared leaf + scales) — the unit the HbmLedger resident lane
     reports in."""
     per_tok = 0
-    for h, w in leaves.values():
+    for h, w in (v for v in leaves.values() if not is_block(v)):
         per_tok += h * w + _scale_width(h) * 4 if quantized \
             else h * w * jnp.dtype(dtype).itemsize
     return page_size * per_tok

@@ -62,6 +62,7 @@ import jax
 import numpy as np
 from jax.profiler import StepTraceAnnotation
 
+from bigdl_tpu.ops import paged_kv
 from bigdl_tpu.serving import paging
 from bigdl_tpu.serving.bucketing import BucketGrid
 from bigdl_tpu.serving.decode_programs import (
@@ -84,10 +85,11 @@ from bigdl_tpu.telemetry.tracer import CAT_DECODE, get_tracer, set_correlation
 
 class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "fut", "t_submit", "deadline",
-                 "rid", "temp", "top_k", "top_p", "key")
+                 "rid", "temp", "top_k", "top_p", "key", "keep_blocks")
 
     def __init__(self, prompt, max_new, fut, t_submit, deadline, rid=0,
-                 temp=0.0, top_k=0, top_p=1.0, key=None):
+                 temp=0.0, top_k=0, top_p=1.0, key=None,
+                 keep_blocks=False):
         self.prompt = prompt
         self.max_new = max_new
         self.fut = fut
@@ -100,6 +102,7 @@ class _DecodeRequest:
         # raw (2,) uint32 threefry key — derived from the request seed,
         # threaded through the tick as data (never a compile constant)
         self.key = key if key is not None else np.zeros((2,), np.uint32)
+        self.keep_blocks = keep_blocks
 
 
 def _key_for_seed(seed: int) -> np.ndarray:
@@ -718,13 +721,19 @@ class DecodeEngine:
                deadline_ms: Optional[float] = None, *,
                temperature: float = 0.0, top_k: int = 0,
                top_p: float = 1.0,
-               seed: Optional[int] = None) -> ServingFuture:
+               seed: Optional[int] = None,
+               keep_blocks: bool = False) -> ServingFuture:
         """Queue one prompt (1-D int array, len >= 1); returns a future
         resolving to the generated token ids (1-D ``int32``, EOS
         included when hit).  ``temperature > 0`` samples inside the
         tick (``top_k``/``top_p`` filter, ``seed`` makes the stream
         reproducible; defaults to the request id); ``temperature == 0``
-        is exact greedy.  Raises :class:`QueueFullError` when the
+        is exact greedy.  ``keep_blocks`` leaves on the future, as
+        ``fut.blocks`` ``{layer: {leaf: array}}``, the fixed blocks
+        (ops/paged_kv.Block: a state-space layer's state) the request's
+        slot holds when it finishes: the state after every token fed to
+        the model, which is the prompt and every generated token but the
+        last.  Raises :class:`QueueFullError` when the
         bounded queue is full, :class:`EngineClosedError` after
         ``close()``, and ``ValueError`` when the request cannot fit the
         cache."""
@@ -769,7 +778,8 @@ class DecodeEngine:
                              rid=rid, temp=temperature, top_k=top_k,
                              top_p=top_p,
                              key=_key_for_seed(rid if seed is None
-                                               else seed))
+                                               else seed),
+                             keep_blocks=keep_blocks)
         try:
             self._rq.put_nowait(req)
         except queue.Full:
@@ -1082,7 +1092,10 @@ class DecodeEngine:
                        free_iter) -> int:
         tr = self._tracer
         b = self.grid.choose_batch(len(chunk))
-        with tr.span("prefill_dispatch", CAT_DECODE):
+        # the padded rows the prefill program runs (a bucket's batch x
+        # length): what a reader of its traced device time divides by
+        with tr.span("prefill_dispatch", CAT_DECODE,
+                     args={"rows": b * int(np.prod(dims))}):
             ids = self.grid.pad_batch([r.prompt for r in chunk], dims, b,
                                       np.int32)
             lengths = np.ones((b,), np.int32)
@@ -1348,9 +1361,24 @@ class DecodeEngine:
                 # decoding already started: truncate, don't fail
                 reason = "deadline"
             if reason is not None:
+                if req.keep_blocks:
+                    req.fut.blocks = self._slot_blocks(s)
                 self._finish(req, st.generated, st.times, reason)
                 self._free(s)
         return gaps
+
+    def _slot_blocks(self, slot: int) -> dict:
+        """Device copies of the fixed blocks ``slot`` holds.  The cache
+        may already be the output of the tick in flight: a row at its
+        budget's end was not dispatched in it, so its blocks are
+        those its last token left."""
+        cache = self._target.cache
+        declared = getattr(self._target.model, "decode_state", dict)()
+        return {lk: {name: cache[lk][name][slot]
+                     for name, spec in leaves.items()
+                     if paged_kv.is_block(spec)}
+                for lk, leaves in declared.items()
+                if any(paged_kv.is_block(v) for v in leaves.values())}
 
     def _finish(self, req: _DecodeRequest, tokens: List[int],
                 times: List[float], reason: str):

@@ -164,7 +164,8 @@ def build_paged_tick(model, **jit_kw):
 
 
 def build_paged_write_slot(windows: Optional[dict] = None,
-                           band_pages: int = 0, **jit_kw):
+                           band_pages: int = 0,
+                           blocks: Optional[dict] = None, **jit_kw):
     """Splice one dense prefill-batch row into a slot's pages: every
     per-token leaf the layer declared (K and V, or a latent row;
     quantized when the pool is int8) goes through the slot's block-table
@@ -174,10 +175,15 @@ def build_paged_write_slot(windows: Optional[dict] = None,
     ``windows`` ``{layer: window}`` names the layers that keep a band:
     ``table_row`` is then the two extents' rows stacked (2, M), and of
     such a layer only the ``band_pages`` pages from the one that holds
-    the band's first row (``length - window``) are written."""
+    the band's first row (``length - window``) are written.
+
+    ``blocks`` ``{layer: [leaf, ...]}`` names the leaves a layer keeps
+    one block a slot of (ops/paged_kv.Block): the row's block is copied
+    whole into ``slot``, over whatever the slot's last request left."""
     from bigdl_tpu.ops import paged_kv
 
     windows = {lk: w for lk, w in (windows or {}).items() if w}
+    blocks = blocks or {}
 
     def write(pool_cache, table_row, batch_cache, row, slot):
         out = {}
@@ -200,6 +206,11 @@ def build_paged_write_slot(windows: Optional[dict] = None,
                 trow = jax.lax.dynamic_slice_in_dim(trow, first,
                                                     band_pages)
             for name in leaves:
+                if name in blocks.get(lk, ()):
+                    r = jax.lax.dynamic_slice_in_dim(bc[name], row, 1)
+                    new[name] = jax.lax.dynamic_update_slice_in_dim(
+                        pool[name], r.astype(pool[name].dtype), slot, 0)
+                    continue
                 r = jax.lax.dynamic_index_in_dim(
                     bc[name], row, axis=0, keepdims=False)  # (H,T,D)
                 if band is not None:

@@ -83,8 +83,11 @@ class ServingFuture:
         self._exc: Optional[BaseException] = None
         self._callbacks: List[Callable] = []
         self._lock = threading.Lock()
-        # DecodeEngine: perf_counter time of each returned token
+        # DecodeEngine: perf_counter time of each returned token, and
+        # the slot's fixed blocks at the end where the request asked
+        # for them (``submit(keep_blocks=True)``)
         self.token_times: Optional[Any] = None
+        self.blocks: Optional[dict] = None
 
     def done(self) -> bool:
         return self._ev.is_set()

@@ -24,6 +24,13 @@ last ``window``) gets a second pool and table for its window layers
 back to the free list as the slot grows; the tick then takes the two
 tables stacked (2, S, M).  A model that says nothing keeps the one pool
 and the (S, M) table.
+
+Fixed blocks (docs/decoding.md §Fixed blocks): a leaf that a layer
+declares as a :class:`~bigdl_tpu.ops.paged_kv.Block` (the state of a
+state-space layer) is one block a slot beside the pages.  It takes no
+page and no table; the slot write copies a prefilled row's block into
+its slot, which is the block's admission, and a slot holds it from its
+first ``reserve`` to its ``release`` (``state_blocks_held``).
 """
 from __future__ import annotations
 
@@ -32,6 +39,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from bigdl_tpu.ops import paged_kv
 from bigdl_tpu.serving import decode_programs
 
 
@@ -238,6 +246,11 @@ class PagedCache(PageAllocator):
         self.band: Optional[BandAllocator] = None
         self.band_page_bytes = 0
         self._windows: dict = {}
+        # the leaves each layer keeps one block a slot of, where it has
+        # any, their bytes a slot, and the slots that hold theirs
+        self._blocks: dict = {}
+        self.block_bytes = 0
+        self._block_slots: set = set()
         self._gauge = gauge
 
     # ----------------------------------------------- cache and programs
@@ -253,12 +266,22 @@ class PagedCache(PageAllocator):
         cache = model.init_paged_cache(self.num_pages, self.page_size,
                                        self.slots, dtype,
                                        kv_dtype=self.kv_dtype, **kw)
+        self._blocks = {
+            lk: [name for name, spec in leaves.items()
+                 if paged_kv.is_block(spec)]
+            for lk, leaves in getattr(model, "decode_state", dict)().items()}
+        self._blocks = {lk: n for lk, n in self._blocks.items() if n}
         # bytes one physical page costs across every layer's pool of
-        # its extent (K + V + scales)
+        # its extent (K + V + scales), and one slot's blocks
+        leaf_bytes = lambda leaf: int(np.prod(leaf.shape[1:])) \
+            * leaf.dtype.itemsize
         page_bytes = lambda lks: sum(
-            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
-            for lk in lks for name, leaf in cache[lk].items()
-            if name != "length")
+            leaf_bytes(leaf) for lk in lks
+            for name, leaf in cache[lk].items()
+            if name != "length" and name not in self._blocks.get(lk, ()))
+        self.block_bytes = sum(leaf_bytes(cache[lk][name])
+                               for lk, names in self._blocks.items()
+                               for name in names)
         self.page_bytes = page_bytes(lk for lk in cache
                                      if lk not in self._windows)
         self.band_page_bytes = page_bytes(self._windows)
@@ -270,7 +293,7 @@ class PagedCache(PageAllocator):
     def build_write(self):
         return decode_programs.build_paged_write_slot(
             self._windows,
-            self.band.pages_per_band if self.band else 0)
+            self.band.pages_per_band if self.band else 0, self._blocks)
 
     def build_verify(self, model, k: int):
         return decode_programs.build_spec_verify(model, k, paged=True)
@@ -305,6 +328,8 @@ class PagedCache(PageAllocator):
             return False
         if self.band is not None:   # never short: sized for every slot
             self.band.ensure(slot, tokens)
+        if self._blocks:
+            self._block_slots.add(slot)
         if self._held() != held:
             self._gauge(*self._held())
         return True
@@ -313,6 +338,7 @@ class PagedCache(PageAllocator):
         super().release(slot)
         if self.band is not None:
             self.band.release(slot)
+        self._block_slots.discard(slot)
         self._gauge(*self._held())
 
     def _held(self) -> tuple:
@@ -322,18 +348,23 @@ class PagedCache(PageAllocator):
 
     # ---------------------------------------------------------- readouts
     def resident_bytes(self) -> int:
-        """Bytes of the pages actually held, both extents' — the
-        readout that retirement frees memory."""
+        """Bytes of the pages actually held, both extents', and of the
+        blocks of the slots held — the readout that retirement frees
+        memory."""
         return self.pages_in_use * self.page_bytes + (
             self.band.pages_in_use * self.band_page_bytes
-            if self.band else 0)
+            if self.band else 0) + len(self._block_slots) * self.block_bytes
 
     def span_args(self) -> Optional[dict]:
         """``loop/tick_dispatch``'s counters: the share of the ``S * M``
         extent this tick's attention has to read at a full layer
-        (``pages_held``) and, where window layers keep their own, at one
-        of those (``window_pages_held``)."""
+        (``pages_held``), where window layers keep their own, at one of
+        those (``window_pages_held``) and, where layers keep a block a
+        slot, the slots whose block is live (``state_blocks_held``: the
+        blocks this tick's state-space layers read and write)."""
         args = {"pages_held": self.pages_in_use}
         if self.band is not None:
             args["window_pages_held"] = self.band.pages_in_use
+        if self._blocks:
+            args["state_blocks_held"] = len(self._block_slots)
         return args

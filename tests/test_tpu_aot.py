@@ -449,3 +449,58 @@ def test_window_moe_tick_compiles_at_published_widths(one_chip):
     _pool_in_place(text, cache)
     assert report.report()["paged_attention"]["pallas"] == before + 2
     assert "paged_attn" in text and "ragged-dot" in text
+
+
+def test_hybrid_ssm_tick_and_chunk_compile_at_published_widths(one_chip):
+    """The Mamba-2 hybrid's paged tick and prompt chunk at the published
+    widths (one Mamba-2, one routed and the attention layer; bf16; the
+    cell's 128 slots x 8192 in pages of 64): every pool leaf, the state
+    blocks among them, donated and aliased with no whole-pool copy; a
+    layer's state step is one fusion that reads the f32 block once and
+    writes it once beside ``y``; attention is ``paged_attn``; the
+    chunk's expert products are the grouped-matmul kernel."""
+    import json
+    import os
+    import re
+
+    from bigdl_tpu.nn.hybrid_ssm import HybridSSMTransformer
+    from bigdl_tpu.ops.pallas import report
+    from bigdl_tpu.serving import paging
+    from bigdl_tpu.serving.decode_programs import (build_paged_tick,
+                                                   build_prefill_chunk)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3-super-11of88-ep4share.json")) as f:
+        cfg = json.load(f)["model"]
+    model = HybridSSMTransformer(**dict(cfg, hybrid_override_pattern="ME*"))
+    slots, max_len, page, chunk = KS.HYBRID_DECODE
+    kv = paging.PagedCache(slots, max_len, page,
+                           paging.default_num_pages(slots, max_len, page))
+    var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), BF16))
+    cache = jax.eval_shape(lambda: kv.init_cache(model, BF16))
+    state = cache["layer0"]["ssm"]
+    assert state.shape == (slots, 128, 64, 128) and state.dtype == F32
+    before = report.report().get("paged_attention", {}).get("pallas", 0)
+    compiled = build_paged_tick(model, **_on(one_chip)).lower(
+        var["params"], var["state"], cache,
+        S((slots, max_len // page), jnp.int32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots, 2), jnp.uint32), S((slots,), F32),
+        S((slots,), jnp.int32), S((slots,), F32)).compile()
+    text = compiled.as_text()
+    _pool_in_place(text, cache)
+    assert report.report()["paged_attention"]["pallas"] == before + 1
+    assert "paged_attn" in text and "ragged-dot" in text
+    block = "f32[%d,128,64,128]" % slots
+    steps = [line for line in text.splitlines()
+             if re.search(r"= \(.*%s.*\) fusion\(" % re.escape(block), line)
+             and "mixer/ssm" in line]
+    assert len(steps) == 1, steps
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    staging = jax.eval_shape(lambda: model.init_cache(1, max_len, BF16))
+    chunked = build_prefill_chunk(model, **_on(one_chip)).lower(
+        var["params"], var["state"], staging, S((1, chunk), jnp.int32),
+        S((1,), jnp.int32)).compile()
+    assert "grouped_matmul" in chunked.as_text()
+    assert chunked.memory_analysis().temp_size_in_bytes < 2 ** 30

@@ -57,7 +57,19 @@ FLASH_PREFIX = [(1, 64, 2048, 8192, 192)]
 # window cell's full buffer over all 128, 128 each
 GROUPED_MATMUL = [(4096, 7168, 2048, 16, 64), (4096, 2048, 7168, 16, 64),
                   (16384, 2048, 1024, 128, 128),
-                  (16384, 1024, 2048, 128, 128)]
+                  (16384, 1024, 2048, 128, 128),
+                  # the hybrid cell's two relu**2 products in the 1024
+                  # latent: 2048 x 22 rows over 128 held experts of 512,
+                  # 88 each expected (the full buffer: more than the
+                  # likely one lands at a quarter of the experts)
+                  (45056, 1024, 2688, 128, 88),
+                  (45056, 2688, 1024, 128, 88)]
+
+# the hybrid Mamba-2 cell's geometry (slots, max_len, page, prompt chunk;
+# benchmark/traffic/decode-reasoning-closed128.json): the tick steps 128
+# state blocks a Mamba-2 layer and reads the attention layer's pages
+# through paged_attn (32 query heads over 2 K/V heads of 128)
+HYBRID_DECODE = (128, 8192, 64, 2048)
 
 # ---------------------------------------------------------------------
 # cached-decode serving shapes (serving/decode.py, docs/decoding.md):

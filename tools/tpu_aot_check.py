@@ -247,6 +247,8 @@ def main(argv=None):
             S((m, k), jnp.bfloat16), S((g, k, n), jnp.bfloat16),
             S((g,), jnp.int32))
 
+    if not args.quick:
+        failures += _hybrid_serve_check(sh, mark)
     if args.step:
         failures += _step_check(sh, mark, fused=not args.unfused)
     if args.lm_step:
@@ -257,6 +259,57 @@ def main(argv=None):
     mark(f"paths: {kernel_report.report()}")
     mark("ALL LOWERED" if failures == 0 else f"{failures} FAILURES")
     return 1 if failures else 0
+
+
+def _hybrid_serve_check(sh, mark) -> int:
+    """The Mamba-2 hybrid cell's tick and prompt chunk at the published
+    widths (benchmark/configs/nemotron3-super-11of88-ep4share.json, all
+    eleven layers, bf16) and the cell's geometry: the pools donated and
+    aliased, the temporaries each program needs."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.nn.hybrid_ssm import HybridSSMTransformer
+    from bigdl_tpu.serving import paging
+    from bigdl_tpu.serving.decode_programs import (build_paged_tick,
+                                                   build_prefill_chunk)
+    from tools import kernel_shapes as KS
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3-super-11of88-ep4share.json")) as f:
+        model = HybridSSMTransformer(**json.load(f)["model"])
+    slots, max_len, page, chunk = KS.HYBRID_DECODE
+    kv = paging.PagedCache(slots, max_len, page,
+                           paging.default_num_pages(slots, max_len, page))
+    S, bf16 = jax.ShapeDtypeStruct, jnp.bfloat16
+    on = dict(in_shardings=sh, out_shardings=sh)
+    var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), bf16))
+    pool = jax.eval_shape(lambda: kv.init_cache(model, bf16))
+    staging = jax.eval_shape(lambda: model.init_cache(1, max_len, bf16))
+    failures = 0
+    for tag, build, args in (
+            ("tick", build_paged_tick, (
+                pool, S((slots, max_len // page), jnp.int32),
+                S((slots,), jnp.int32), S((slots,), jnp.bool_),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.float32),
+                S((slots,), jnp.int32), S((slots,), jnp.float32))),
+            ("chunk", build_prefill_chunk, (
+                staging, S((1, chunk), jnp.int32), S((1,), jnp.int32)))):
+        try:
+            mem = build(model, **on).lower(
+                var["params"], var["state"], *args).compile(
+                ).memory_analysis()
+            mark(f"hybrid {tag}: OK, arguments "
+                 f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, aliased "
+                 f"{mem.alias_size_in_bytes / 2**30:.2f} GiB, temporaries "
+                 f"{mem.temp_size_in_bytes / 2**20:.0f} MiB")
+        except Exception as e:
+            failures += 1
+            mark(f"hybrid {tag}: FAIL {str(e)[:160]}")
+    return failures
 
 
 def _table_check(path, sh, mark) -> int:
