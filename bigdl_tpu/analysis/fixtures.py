@@ -500,8 +500,9 @@ def _tuned_params_stale():
 
 @fixture("bad_kernel_shape", "pallas-routing")
 def _bad_kernel_shape():
-    """An inventory whose matmul M=100 divides no row tile and whose
-    int8 K is not 128-aligned: both would silently fall back to XLA."""
+    """An inventory whose matmul M=100 divides no row tile, whose int8
+    K is not 128-aligned and whose flash lengths tile neither pass or
+    only the forward: each would silently fall back to XLA."""
 
     class _Inventory:
         __file__ = __file__
@@ -510,7 +511,10 @@ def _bad_kernel_shape():
         CONV3_BWD = ()
         MATMUL = ((100, 64, 64),)
         INT8 = ((4096, 100, 256),)
-        FLASH = (1, 2, 1025, 128)  # no 128-multiple block divides 1025
+        # no 128-multiple block divides 1025; 1000 is one forward block
+        # but over the backward's cap; 32768 x 128 outgrows the
+        # backward's VMEM for dQ
+        FLASH = [(1, 2, 1025, 128), (1, 2, 1000, 128), (1, 2, 32768, 128)]
 
     return LintContext(name="fixture:bad_kernel_shape", kind="inventory",
                        jaxpr=None, meta={"inventory": _Inventory})

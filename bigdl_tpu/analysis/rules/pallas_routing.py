@@ -111,6 +111,11 @@ class PallasRoutingRule(Rule):
                     yield fail("flash_attention", (b, hh, t, d),
                                "sequence length has no 128-multiple "
                                "block divisor")
+                elif fa.bwd_blocks(t, t, d, itemsize) is None:
+                    yield fail("flash_attention_bwd", (b, hh, t, d),
+                               "no 128-multiple block divisor under the "
+                               "backward's cap, or the sequence's dQ "
+                               "over its VMEM budget")
 
     def _check_tuned_table(self, ctx: LintContext):
         """Every tuned-table entry must still be inside its family's

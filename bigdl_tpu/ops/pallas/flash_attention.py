@@ -9,9 +9,15 @@ score matrix in HBM is the bandwidth cliff for long sequences.
 Forward: one kernel instance per (batch*head, q-block); K/V stream
 through VMEM in blocks under an online-softmax accumulator (running max
 ``m``, running sum ``l``, rescaled output accumulator) — O(T) memory.
-Backward: custom-VJP recomputes probabilities blockwise from the saved
-logsumexp in a ``lax.scan`` (no (T, S) residual), trading FLOPs for HBM
-exactly like ``jax.checkpoint``.
+Backward (custom VJP, FlashAttention-2): one Pallas kernel,
+``flash_bwd``, recomputes the probabilities block by block from the
+saved logsumexp (no (T, S) residual, no score-sized block in HBM) and
+in one pass over (k block, q block) accumulates a k block's dK and dV
+and the whole sequence's dQ in VMEM; under ``causal`` the pairs above
+the diagonal are neither computed nor fetched.  Operands stay in the
+input dtype, every product accumulates in f32.  A shape it does not
+tile takes the blockwise XLA backward (``_bwd_blockwise``, a
+``lax.scan``).
 
 ``flash_attention(q, k, v, causal=..., sm_scale=...)`` expects
 ``(B, H, T, D)`` and picks the Pallas path on TPU, falling back to the
@@ -149,7 +155,9 @@ def _xla_attention_lse(q, k, v, causal, sm_scale):
 
 
 def _bwd_blockwise(q, k, v, o, lse, g, causal, sm_scale, bq):
-    """Recompute-probabilities backward, scanned over q blocks."""
+    """Recompute-probabilities backward in plain XLA, scanned over q
+    blocks: the oracle of the Pallas backward and its fallback for
+    shapes that kernel does not tile."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
     bq = min(bq, t)
@@ -188,6 +196,139 @@ def _bwd_blockwise(q, k, v, o, lse, g, causal, sm_scale, bq):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+# ----------------------------------------------------------------------
+# Pallas backward (FlashAttention-2, one pass for dK, dV and dQ)
+# ----------------------------------------------------------------------
+_NT = (((1,), (1,)), ((), ()))   # A @ B^T
+_NN = (((1,), (0,)), ((), ()))   # A @ B
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dqt_ref, dk_acc, dv_acc, kt_ref, dqt_acc,
+                *, bq: int, bk: int, causal: bool, sm_scale: float):
+    """Grid (batch*head, k block, q block), both sequential.  A k block's
+    dK and dV accumulate in f32 VMEM over the (innermost) q blocks; the
+    whole sequence's dQ, transposed to (q block, D, bq), accumulates in
+    f32 VMEM over every k block and is written once.  Scores are held
+    transposed, (bk, bq): the saved logsumexp and delta are rows
+    broadcast down the sublanes, and with K^T kept a k block every
+    product is A @ B or A @ B^T."""
+    kb = pl.program_id(1)
+    qi = pl.program_id(2)
+
+    @pl.when(jnp.logical_and(kb == 0, qi == 0))
+    def _():
+        dqt_acc[:] = jnp.zeros_like(dqt_acc)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+        kt_ref[:] = jnp.transpose(
+            k_ref[:].astype(jnp.float32)).astype(kt_ref.dtype)
+
+    def accumulate(masked):
+        q = q_ref[:] * sm_scale   # scaled as the forward scales it
+        do = do_ref[:]
+        s = jax.lax.dot_general(k_ref[:], q, _NT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[:])                            # (bk, bq)
+        dv_acc[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[:], do, _NT,
+                                 preferred_element_type=jnp.float32)
+        # dS / sm_scale; q carries the scale into dK, dQ takes it at the end
+        ds = (p * (dp - delta_ref[:])).astype(q.dtype)
+        dk_acc[:] += jax.lax.dot_general(
+            ds, q, _NN, preferred_element_type=jnp.float32)
+        dqt_acc[qi] += jax.lax.dot_general(
+            kt_ref[:], ds, _NN, preferred_element_type=jnp.float32)
+
+    if causal:
+        # a pair wholly above the diagonal runs nothing; only a pair
+        # that straddles it applies the mask
+        live = (qi + 1) * bq > kb * bk
+        below = qi * bq >= (kb + 1) * bk - 1   # every query sees every key
+        pl.when(below)(lambda: accumulate(False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(below)))(
+            lambda: accumulate(True))
+    else:
+        accumulate(False)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+        @pl.when(kb == pl.num_programs(1) - 1)
+        def _():
+            dqt_ref[:] = (dqt_acc[:] * sm_scale).astype(dqt_ref.dtype)
+
+
+# a sequence's dQ stays in VMEM: its f32 accumulator and the output's
+# two buffers (Mosaic refused 32 MiB of them on the v5e, took 24)
+_DQ_VMEM_BYTES = 16 * 2 ** 20
+
+
+def bwd_blocks(t: int, s: int, d: int, itemsize: int, block_q: int = 512,
+               block_k: int = 512):
+    """The (bq, bk) the Pallas backward tiles ``t`` queries against
+    ``s`` keys of width ``d`` with, or nothing: where no legal pair
+    exists (both are lane dims somewhere, the q block of the logsumexp
+    rows and of dQ^T, the k block of K^T: 128-multiples or whole) or
+    where the sequence's dQ does not fit the fast memory."""
+    bq, bk = fit_block(t, block_q), fit_block(s, block_k)
+    if not (bq and bk) or t * d * (4 + 2 * itemsize) > _DQ_VMEM_BYTES:
+        return None
+    return bq, bk
+
+
+def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, bq, bk,
+                      interpret):
+    b, h, t, d = q.shape
+    s = k.shape[2]
+    n, nq = b * h, t // bq
+    delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), -1)
+
+    def first_live_q(j, i):
+        # under causal, the q blocks above k block j hold the first live
+        # one, so the skipped steps fetch nothing
+        return jnp.maximum(i, (j * bk) // bq) if causal else i
+
+    q_spec = pl.BlockSpec((None, bq, d),
+                          lambda m, j, i: (m, first_live_q(j, i), 0))
+    row_spec = pl.BlockSpec((None, 1, bq),
+                            lambda m, j, i: (m, 0, first_live_q(j, i)))
+    k_spec = pl.BlockSpec((None, bk, d), lambda m, j, i: (m, j, 0))
+    dk, dv, dqt = pl.pallas_call(
+        functools.partial(_bwd_kernel, bq=bq, bk=bk, causal=causal,
+                          sm_scale=sm_scale),
+        grid=(n, s // bk, nq),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec, k_spec,
+                   pl.BlockSpec((None, nq, d, bq),
+                                lambda m, j, i: (m, 0, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n, s, d), k.dtype),
+                   jax.ShapeDtypeStruct((n, s, d), v.dtype),
+                   jax.ShapeDtypeStruct((n, nq, d, bq), q.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((d, bk), k.dtype),
+                        pltpu.VMEM((nq, d, bq), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="flash_bwd",  # the device trace finds the kernel by it
+    )(q.reshape(n, t, d), k.reshape(n, s, d), v.reshape(n, s, d),
+      g.reshape(n, t, d), lse.reshape(n, 1, t), delta.reshape(n, 1, t))
+    dq = jnp.swapaxes(dqt, 2, 3).reshape(b, h, t, d)
+    return dq, dk.reshape(b, h, s, d), dv.reshape(b, h, s, d)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, sm_scale, bq, bk, interpret):
     o, _ = _flash_fwd_pallas(q, k, v, causal, sm_scale, bq, bk, interpret)
@@ -200,8 +341,21 @@ def _flash_fwd(q, k, v, causal, sm_scale, bq, bk, interpret):
 
 
 def _flash_bwd(causal, sm_scale, bq, bk, interpret, res, g):
+    """The Pallas backward at the forward's blocks in interpret mode
+    (tests) and at :func:`bwd_blocks` on the chip; a shape those do not
+    tile takes the blockwise XLA backward."""
+    from bigdl_tpu.ops.pallas import report as _report
+
     q, k, v, o, lse = res
-    return _bwd_blockwise(q, k, v, o, lse, g, causal, sm_scale, bq)
+    blocks = (bq, bk) if interpret else bwd_blocks(
+        q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize)
+    if blocks is None:
+        _report.record("flash_attention_bwd", "xla",
+                       q.shape[:3] + k.shape[2:])
+        return _bwd_blockwise(q, k, v, o, lse, g, causal, sm_scale, bq)
+    _report.record("flash_attention_bwd", "pallas")
+    return _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, *blocks,
+                             interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -335,9 +489,9 @@ def flash_attention(
     # Mosaic custom calls can't be auto-partitioned: under a sharded
     # mesh (dp batch / tp heads) the kernel runs inside a shard_map
     # manual over those axes, with T and D replicated in (see
-    # ops/pallas/partition.py); the custom_vjp backward (plain XLA)
-    # differentiates through the shard_map, so dq/dk/dv come back with
-    # the same batch/head sharding
+    # ops/pallas/partition.py); the custom_vjp backward's kernels run
+    # inside the same shard_map, so dq/dk/dv come back with the same
+    # batch/head sharding
     from bigdl_tpu.ops.pallas.partition import shard_kernel_call
     from bigdl_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 

@@ -117,6 +117,23 @@ def test_kernel_compiles_for_v5e_at_real_width(one_chip, case):
     assert report.fallbacks()[n_fallbacks:] == []
 
 
+def test_flash_bwd_kernel_compiles_at_lm_shape(one_chip):
+    """The attention gradient at the LM cell's shape is the Pallas
+    backward kernel, not the blockwise XLA scan."""
+    from bigdl_tpu.ops.pallas import report
+    from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+
+    before = report.report().get("flash_attention_bwd", {}).get("pallas", 0)
+    n_fallbacks = len(report.fallbacks())
+    text = _compile(
+        jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(F32)), argnums=(0, 1, 2)),
+        one_chip, *[S(LM_QKV, BF16)] * 3)
+    assert "flash_bwd" in text and " while(" not in text
+    assert report.report()["flash_attention_bwd"]["pallas"] == before + 1
+    assert report.fallbacks()[n_fallbacks:] == []
+
+
 def test_flash_partitions_over_dp_tp_mesh(topo, one_chip):
     """The LM attention under a data=2 x model=2 mesh of described
     chips: the kernel wraps itself in a shard_map over both axes
@@ -136,6 +153,25 @@ def test_flash_partitions_over_dp_tp_mesh(topo, one_chip):
 
     text = _compile(attn, qkv, S(LM_QKV, BF16))
     assert "tpu_custom_call" in text
+
+
+def test_flash_bwd_partitions_over_dp_tp_mesh(topo, one_chip):
+    """The gradient under the same mesh: the backward kernel runs inside
+    the kernel's shard_map on each chip's batch and heads."""
+    from bigdl_tpu.ops.pallas.flash_attention import flash_attention
+    from bigdl_tpu.ops.pallas.partition import kernel_mesh_scope
+    from bigdl_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    mesh = make_mesh(MeshConfig(data=2, model=2), topo.devices)
+    qkv = NamedSharding(mesh, P("data", "model"))
+
+    def loss(q, k, v):
+        with kernel_mesh_scope(mesh):
+            return jnp.sum(flash_attention(q, k, v, causal=True).astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv,
+                    *[S(LM_QKV, BF16)] * 3)
+    assert "flash_bwd" in text
 
 
 # the decode cell's geometry (benchmark/traffic/decode-steady.json):

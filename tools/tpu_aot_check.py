@@ -208,6 +208,12 @@ def main(argv=None):
         aot(f"flash_attention {bq}x{hq}x{tq}x{dq}",
             lambda q: flash_attention(q, q, q, causal=True),
             S((bq, hq, tq, dq), jnp.bfloat16), kernel="flash_attention")
+        aot(f"flash_attention bwd {bq}x{hq}x{tq}x{dq}",
+            jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=True).astype(jnp.float32)),
+                argnums=(0, 1, 2)),
+            *[S((bq, hq, tq, dq), jnp.bfloat16)] * 3,
+            kernel="flash_attention_bwd")
 
     from bigdl_tpu.ops.pallas.paged_attention import paged_attn
 
@@ -570,6 +576,8 @@ def _lm_step_check(sh, mark) -> int:
         model, crit, methods = build_lm()
         flash_before = kernel_report.report().get(
             "flash_attention", {}).get("pallas", 0)
+        bwd_before = kernel_report.report().get(
+            "flash_attention_bwd", {}).get("pallas", 0)
         step = jax.jit(
             make_train_step(model, crit, methods,
                             compute_dtype=jnp.bfloat16),
@@ -602,6 +610,10 @@ def _lm_step_check(sh, mark) -> int:
             # failure — a compiled step without the kernel is exactly
             # the silent-fallback class this tool exists to refuse
             mark("lm-step: XLA FALLBACK (flash attention not routed)")
+            return 1
+        if kernel_report.report().get(
+                "flash_attention_bwd", {}).get("pallas", 0) <= bwd_before:
+            mark("lm-step: XLA FALLBACK (flash backward not routed)")
             return 1
         return 0
     except Exception as e:
