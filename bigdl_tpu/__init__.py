@@ -16,6 +16,9 @@ interop, quantized inference) re-designed for TPU:
 * Pallas kernels cover what XLA fusion does not (fused/ring attention,
   int8 matmul) where the reference called into MKL-DNN/BigQuant JNI.
 """
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # the ``setup/import`` span starts here
 
 from bigdl_tpu.version import __version__
 
@@ -30,3 +33,7 @@ from bigdl_tpu import dataset  # noqa: F401
 from bigdl_tpu import parallel  # noqa: F401
 from bigdl_tpu import serving  # noqa: F401  (bucketed serving engine)
 from bigdl_tpu import telemetry  # noqa: F401  (span tracing + watchdogs)
+
+from bigdl_tpu.telemetry.tracer import get_tracer as _get_tracer
+
+_get_tracer().add_setup_span("setup/import", _T_IMPORT, _time.perf_counter())

@@ -384,9 +384,10 @@ def _telemetry_parity():
     # is strictly host-side, so the program the loop dispatches must be
     # BYTE-IDENTICAL whether the tracer is enabled or not.  Trace the
     # engine's own step builder twice — tracing off, then on with a
-    # live Metrics sink + watchdog attached (the worst case: any
-    # instrumentation that reached the staged program would surface
-    # here) — and hand both jaxprs to the jaxpr-parity rule.
+    # live Metrics sink + watchdog attached and inside a set-up scope,
+    # whose compile listener records with tracing on or off (the worst
+    # case: any instrumentation that reached the staged program would
+    # surface here) — and hand both jaxprs to the jaxpr-parity rule.
     model = models.LeNet5()
     engine = LocalOptimizer(model, None, nn.ClassNLLCriterion(logits=True))
     engine.set_optim_method(SGD(1e-2))
@@ -399,7 +400,8 @@ def _telemetry_parity():
         with telemetry.Watchdog(log=None) as wd:
             wd.attach()
             sink = Metrics()  # a live span sink during staging
-            with sink.time("dispatch"):
+            with sink.time("dispatch"), telemetry.get_tracer().setup_span(
+                    "compile", {"program": "lint_step"}):
                 instrumented = jax.make_jaxpr(step)(*args)
     return LintContext(
         name="telemetry_step_parity", kind="train_step",

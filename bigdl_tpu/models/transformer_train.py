@@ -27,6 +27,7 @@ from bigdl_tpu.dataset import DataSet
 from bigdl_tpu.dataset.text import ptb_batchify
 from bigdl_tpu.models.ptb_train import _load_corpus
 from bigdl_tpu.models.train_utils import base_parser, configure, init_logging
+from bigdl_tpu.telemetry.tracer import get_tracer
 
 logger = logging.getLogger("bigdl_tpu.train")
 
@@ -37,9 +38,11 @@ def _window_dataset(ids, batch: int, steps: int):
         xs.reshape(-1, steps), ys.reshape(-1, steps), batch_size=batch)
 
 
+@get_tracer().setup_span("setup/build")
 def build(argv: Optional[list] = None):
     """Parse ``argv`` and build the run exactly as :func:`main` trains
-    it; returns ``(configured Optimizer, validation DataSet)``."""
+    it (a ``setup/build`` span); returns ``(configured Optimizer,
+    validation DataSet)``."""
     p = base_parser("transformer_train", batch_size=8, max_epoch=5,
                     lr=1e-3)
     p.add_argument("--seqLen", type=int, default=512)

@@ -831,7 +831,6 @@ class LocalOptimizer(Optimizer):
                  "rng": it_rng, "features": features,
                  "targets": targets, "lrs": lrs},
                 donated=("params", "model_state", "opt_states"))
-            t_compile = time.perf_counter()
         # async: 'dispatch' is enqueue-only — the device runs behind;
         # sync: 'compute' blocks on the scalar loss fetch as before
         if self._recent_batches is not None:
@@ -840,8 +839,8 @@ class LocalOptimizer(Optimizer):
             self._recent_batches.append(
                 (driver_state["neval"] + 1, features, targets))
         # which step paid for the XLA compile
-        compiling = tracer.span(
-            "compile", CAT_TRAIN, args={"program": self._step_program}
+        compiling = tracer.setup_span(
+            "compile", {"program": self._step_program}
         ) if xray_sig is not None else contextlib.nullcontext()
         with jax.profiler.StepTraceAnnotation(
                 "train_step", step_num=driver_state["neval"] + 1), \
@@ -860,11 +859,11 @@ class LocalOptimizer(Optimizer):
             if not self._async_engine:
                 loss = float(loss)  # sync point
         if xray_sig is not None:
-            # the first dispatch just paid the XLA compile; its wall
-            # time is the program's compile_s stamp
+            # the first dispatch just paid the XLA compile; its span's
+            # duration is the program's compile_s stamp
             programs.get_program_registry().register_compile(
                 self._step_program, xray_sig,
-                compile_s=time.perf_counter() - t_compile,
+                compile_s=compiling.duration,
                 cost=self._step_cost, expected=True)
         else:
             programs.get_program_registry().record_call(

@@ -187,7 +187,12 @@ class _Lane:
             if "embed" in self.params else jnp.float32
         self.kv = kv
         self.max_len = max_len
-        self.cache = kv.init_cache(model, self.dtype)
+        with tracer.setup_span("setup/cache_init",
+                               {"lane": prefix}) as scope:
+            self.cache = jax.block_until_ready(
+                kv.init_cache(model, self.dtype))
+            scope.args["bytes"] = sum(
+                leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.cache))
         self.programs = {
             "prefill": build_prefill(model, max_len, self.dtype),
             "write": kv.build_write()}
@@ -217,27 +222,27 @@ class _Lane:
         # before the call (ticks and writes donate their cache), and
         # registered before ``record_recompile`` so the forensic instant
         # precedes the recompile span the Watchdog pairs it with
-        sig = self._signature(jitted, args)
-        t0 = time.perf_counter()
+        sig = self._signature(jitted, args, program)
         # which tick (ambient correlation) paid for which program
-        with self._tracer.span("compile", CAT_DECODE,
-                               args={"program": program}):
+        with self._tracer.setup_span("compile",
+                                     {"program": program}) as compiling:
             out = jitted(*args)
-        dt = time.perf_counter() - t0
+        dt = compiling.duration
         programs.get_program_registry().register_compile(
             program, sig, compile_s=dt, cost=cost, expected=self.warming)
         self._metrics.record_recompile(dt)
         self._seen.add(key)
         return out
 
-    @staticmethod
-    def _signature(jitted, args):
+    def _signature(self, jitted, args, program: str):
         """Argument names from the program's own parameters, donation
-        from its trace; None when either cannot be had (forensics are
-        optional)."""
+        from its trace (a ``setup/signature`` span); None when either
+        cannot be had (forensics are optional)."""
         try:
             names = list(inspect.signature(jitted).parameters)
-            info = jitted.trace(*args).args_info[0]
+            with self._tracer.setup_span("setup/signature",
+                                         {"program": program}):
+                info = jitted.trace(*args).args_info[0]
             donated = [n for n, a in zip(names, info)
                        if any(leaf.donated
                               for leaf in jax.tree_util.tree_leaves(a))]
@@ -358,125 +363,128 @@ class DecodeEngine:
                  prefill_chunk: Optional[int] = None,
                  draft: Optional[tuple] = None,
                  draft_k: Optional[int] = None):
-        self.slots = int(slots)
-        self.max_len = int(max_len)
-        self.eos_id = eos_id
-        self.default_deadline_ms = default_deadline_ms
-        self.continuous = continuous
-        self.metrics = metrics if metrics is not None else ServingMetrics()
-        self.grid = BucketGrid([(int(t),) for t in prompt_buckets],
-                               prefill_batch_sizes, pad_value=0)
-        self._largest_bucket = max(int(t) for t in prompt_buckets)
-        self.prefill_chunk = int(prefill_chunk) if prefill_chunk \
-            else None
-        self._tracer = get_tracer()
+        with get_tracer().setup_span("setup/engine",
+                                     {"slots": int(slots)}) as scope:
+            self.slots = int(slots)
+            self.max_len = int(max_len)
+            self.eos_id = eos_id
+            self.default_deadline_ms = default_deadline_ms
+            self.continuous = continuous
+            self.metrics = metrics if metrics is not None else ServingMetrics()
+            self.grid = BucketGrid([(int(t),) for t in prompt_buckets],
+                                   prefill_batch_sizes, pad_value=0)
+            self._largest_bucket = max(int(t) for t in prompt_buckets)
+            self.prefill_chunk = int(prefill_chunk) if prefill_chunk \
+                else None
+            self._tracer = get_tracer()
 
-        if kv_layout not in ("dense", "paged"):
-            raise ValueError(f"kv_layout must be 'dense' or 'paged', "
-                             f"got {kv_layout!r}")
-        if kv_dtype is not None and kv_layout != "paged":
-            raise ValueError("kv_dtype requires kv_layout='paged'")
-        self.kv_layout = kv_layout
-        self.draft_k = 0
-        if draft is not None:
-            self.draft_k = int(draft_k if draft_k is not None else 3)
-            if self.draft_k < 1:
-                raise ValueError(f"draft_k must be >= 1, got "
-                                 f"{self.draft_k}")
-        if kv_layout == "paged":
-            page_size = int(page_size if page_size is not None else 16)
-            self._kv = paging.PagedCache(
-                self.slots, self.max_len, page_size,
-                int(num_pages if num_pages is not None
-                    else paging.default_num_pages(self.slots,
-                                                  self.max_len,
-                                                  page_size)),
-                kv_dtype, gauge=self.metrics.record_pages,
-                step=self._page_slack())
-        else:
-            self._kv = paging.DenseCache(self.slots, self.max_len)
+            if kv_layout not in ("dense", "paged"):
+                raise ValueError(f"kv_layout must be 'dense' or 'paged', "
+                                 f"got {kv_layout!r}")
+            if kv_dtype is not None and kv_layout != "paged":
+                raise ValueError("kv_dtype requires kv_layout='paged'")
+            self.kv_layout = kv_layout
+            self.draft_k = 0
+            if draft is not None:
+                self.draft_k = int(draft_k if draft_k is not None else 3)
+                if self.draft_k < 1:
+                    raise ValueError(f"draft_k must be >= 1, got "
+                                     f"{self.draft_k}")
+            if kv_layout == "paged":
+                page_size = int(page_size if page_size is not None else 16)
+                self._kv = paging.PagedCache(
+                    self.slots, self.max_len, page_size,
+                    int(num_pages if num_pages is not None
+                        else paging.default_num_pages(self.slots,
+                                                      self.max_len,
+                                                      page_size)),
+                    kv_dtype, gauge=self.metrics.record_pages,
+                    step=self._page_slack())
+            else:
+                self._kv = paging.DenseCache(self.slots, self.max_len)
 
-        def lane(prefix, model, variables, kv):
-            return _Lane(prefix, model, variables, kv, self.max_len,
-                         bool(self.prefill_chunk), self.metrics,
-                         self._tracer)
+            def lane(prefix, model, variables, kv):
+                return _Lane(prefix, model, variables, kv, self.max_len,
+                             bool(self.prefill_chunk), self.metrics,
+                             self._tracer)
 
-        self._target = lane("decode", model, variables, self._kv)
-        self._target.programs["tick"] = self._kv.build_tick(model)
-        self._draft: Optional[_Lane] = None
-        self._round = self._tick_round
-        self._round_note = "ticks"  # the X-ray's count of a row's rounds
-        if draft is not None:
-            # the draft's cache stays dense: it is small by construction
-            # and its lengths self-heal from the host ledger each round
-            self._draft = lane("draft", draft[0], draft[1],
-                               paging.DenseCache(self.slots,
-                                                 self.max_len))
-            self._draft.programs["propose"] = build_draft_propose(
-                draft[0], self.draft_k)
-            self._target.programs["verify"] = self._kv.build_verify(
-                model, self.draft_k)
-            self._round = self._spec_round
-            self._round_note = "spec_rounds"
-        self._lanes = [ln for ln in (self._target, self._draft) if ln]
-        self._declared = self._declare()
-        self._tick_cost = None  # ProgramCost, stamped before first tick
+            self._target = lane("decode", model, variables, self._kv)
+            self._target.programs["tick"] = self._kv.build_tick(model)
+            self._draft: Optional[_Lane] = None
+            self._round = self._tick_round
+            self._round_note = "ticks"  # the X-ray's count of a row's rounds
+            if draft is not None:
+                # the draft's cache stays dense: it is small by construction
+                # and its lengths self-heal from the host ledger each round
+                self._draft = lane("draft", draft[0], draft[1],
+                                   paging.DenseCache(self.slots,
+                                                     self.max_len))
+                self._draft.programs["propose"] = build_draft_propose(
+                    draft[0], self.draft_k)
+                self._target.programs["verify"] = self._kv.build_verify(
+                    model, self.draft_k)
+                self._round = self._spec_round
+                self._round_note = "spec_rounds"
+            self._lanes = [ln for ln in (self._target, self._draft) if ln]
+            scope.args["lanes"] = len(self._lanes)
+            self._declared = self._declare()
+            self._tick_cost = None  # ProgramCost, stamped before first tick
 
-        self._tokens = np.zeros((self.slots,), np.int32)
-        self._active = np.zeros((self.slots,), bool)
-        self._slot_state: List[Optional[_Slot]] = [None] * self.slots
-        # per-slot sampling state: raw PRNG keys round-trip through the
-        # tick as data; temp == 0 rows stay exact-greedy
-        self._keys = np.zeros((self.slots, 2), np.uint32)
-        self._temps = np.zeros((self.slots,), np.float32)
-        self._topks = np.zeros((self.slots,), np.int32)
-        self._topps = np.ones((self.slots,), np.float32)
-        # host mirror of each slot's cache extent, counting what the
-        # rounds dispatched so far have written (prompt + generated - 1
-        # once they are read): drives page budgeting and draft-length
-        # resync.  The round advances it, not the retirement
-        self._host_len = np.zeros((self.slots,), np.int32)
-        # the extent at which a slot's budget ends (prompt + max_new -
-        # 1): a budget end is known by count, ``_host_len`` reaching
-        # it, a tick before its token is read
-        self._limit = np.zeros((self.slots,), np.int32)
-        # the tick pipeline (depth one): the tick enqueued and unread,
-        # the rows of the tick last read that still held their slot,
-        # the rows whose token and key the host wrote since a tick last
-        # took both from the mirrors, and the host-decided tick inputs
-        # as last uploaded, ``name -> (host copy, device array)``
-        self._flight: Optional[_Flight] = None
-        self._served = np.zeros((self.slots,), bool)
-        self._host_rows = np.zeros((self.slots,), bool)
-        self._uploads: dict = {}
-        self._chunking: Optional[dict] = None
-        self._chunk_pending: "collections.deque[_DecodeRequest]" = \
-            collections.deque()
+            self._tokens = np.zeros((self.slots,), np.int32)
+            self._active = np.zeros((self.slots,), bool)
+            self._slot_state: List[Optional[_Slot]] = [None] * self.slots
+            # per-slot sampling state: raw PRNG keys round-trip through the
+            # tick as data; temp == 0 rows stay exact-greedy
+            self._keys = np.zeros((self.slots, 2), np.uint32)
+            self._temps = np.zeros((self.slots,), np.float32)
+            self._topks = np.zeros((self.slots,), np.int32)
+            self._topps = np.ones((self.slots,), np.float32)
+            # host mirror of each slot's cache extent, counting what the
+            # rounds dispatched so far have written (prompt + generated - 1
+            # once they are read): drives page budgeting and draft-length
+            # resync.  The round advances it, not the retirement
+            self._host_len = np.zeros((self.slots,), np.int32)
+            # the extent at which a slot's budget ends (prompt + max_new -
+            # 1): a budget end is known by count, ``_host_len`` reaching
+            # it, a tick before its token is read
+            self._limit = np.zeros((self.slots,), np.int32)
+            # the tick pipeline (depth one): the tick enqueued and unread,
+            # the rows of the tick last read that still held their slot,
+            # the rows whose token and key the host wrote since a tick last
+            # took both from the mirrors, and the host-decided tick inputs
+            # as last uploaded, ``name -> (host copy, device array)``
+            self._flight: Optional[_Flight] = None
+            self._served = np.zeros((self.slots,), bool)
+            self._host_rows = np.zeros((self.slots,), bool)
+            self._uploads: dict = {}
+            self._chunking: Optional[dict] = None
+            self._chunk_pending: "collections.deque[_DecodeRequest]" = \
+                collections.deque()
 
-        self._rids = itertools.count()
-        self._tick_no = 0
-        # request X-ray: exact per-request budget + p99 tail exemplars
-        # (one attribute check per call while the plane is dark)
-        self.xray = request_xray.RequestLedger(tracer=self._tracer)
-        self.exemplars = request_xray.ExemplarReservoir(
-            tracer=self._tracer)
-        self._periodic = PeriodicMetricsLogger(
-            self.log_line, every_s=metrics_log_every_s)
+            self._rids = itertools.count()
+            self._tick_no = 0
+            # request X-ray: exact per-request budget + p99 tail exemplars
+            # (one attribute check per call while the plane is dark)
+            self.xray = request_xray.RequestLedger(tracer=self._tracer)
+            self.exemplars = request_xray.ExemplarReservoir(
+                tracer=self._tracer)
+            self._periodic = PeriodicMetricsLogger(
+                self.log_line, every_s=metrics_log_every_s)
 
-        self._rq: "queue.Queue" = queue.Queue(maxsize=max(1, max_queue))
-        self._pending: "collections.deque[_DecodeRequest]" = \
-            collections.deque()
-        self._closed = False
-        self._discard = False
-        self._close_lock = threading.Lock()
-        self._loop_thread = threading.Thread(
-            target=self._loop, daemon=True, name="bigdl-decode-loop")
-        self._started = False
+            self._rq: "queue.Queue" = queue.Queue(maxsize=max(1, max_queue))
+            self._pending: "collections.deque[_DecodeRequest]" = \
+                collections.deque()
+            self._closed = False
+            self._discard = False
+            self._close_lock = threading.Lock()
+            self._loop_thread = threading.Thread(
+                target=self._loop, daemon=True, name="bigdl-decode-loop")
+            self._started = False
 
-        if warmup:
-            self.warmup()
-        if start:
-            self.start()
+            if warmup:
+                self.warmup()
+            if start:
+                self.start()
 
     # ------------------------------------------------------------------
     # the programs: one declaration, which warm-up runs
@@ -516,10 +524,14 @@ class DecodeEngine:
         for lane in self._lanes:
             lane.warming = True
         try:
-            self._stamp_tick()
-            held: dict = {}
-            for _, thunk in self._declared:
-                thunk(held)
+            with self._tracer.setup_span(
+                    "setup/warmup",
+                    {"declared": len(self._declared)}) as scope:
+                self._stamp_tick()
+                held: dict = {}
+                for _, thunk in self._declared:
+                    thunk(held)
+                scope.args["compiled"] = self.metrics.recompiles - before
         finally:
             for lane in self._lanes:
                 lane.warming = False

@@ -92,6 +92,7 @@ from bigdl_tpu.telemetry.tracer import (
     CAT_DECODE,
     CAT_HOST,
     CAT_SERVE,
+    CAT_SETUP,
     CAT_TRAIN,
     Span,
     Tracer,
@@ -127,4 +128,5 @@ __all__ = [
     "assemble_request_trees",
     "WorkloadRecorder", "load_workload",
     "CAT_TRAIN", "CAT_DATA", "CAT_SERVE", "CAT_DECODE", "CAT_HOST",
+    "CAT_SETUP",
 ]

@@ -23,6 +23,7 @@ import time
 from typing import Optional
 
 from ..utils import jax_compat
+from .tracer import get_tracer
 
 # per-chip peak dense (bf16) FLOP/s from the public TPU specs, matched
 # as substrings of the lowercased device_kind — the one peaks table of
@@ -144,18 +145,20 @@ def program_cost(name: str, *, lowered=None, compiled=None,
 
 def stamp_jitted(name: str, jitted, *args, table: "CostTable" = None,
                  n_devices: int = 1, **kwargs) -> Optional[ProgramCost]:
-    """Lower ``jitted`` (trace only, no backend compile) and stamp it.
+    """Lower ``jitted`` (trace only, no backend compile) and stamp it,
+    inside a ``setup/stamp`` span (a compile site: never per turn).
 
     Returns the stamp, or None when cost accounting is disabled or the
     lowering itself fails (never propagates — accounting is optional).
     """
     if not cost_accounting_enabled():
         return None
-    try:
-        lowered = jitted.lower(*args, **kwargs)
-    except Exception:
-        return None
-    cost = program_cost(name, lowered=lowered, n_devices=n_devices)
+    with get_tracer().setup_span("setup/stamp", {"program": name}):
+        try:
+            lowered = jitted.lower(*args, **kwargs)
+        except Exception:
+            return None
+        cost = program_cost(name, lowered=lowered, n_devices=n_devices)
     (table if table is not None else get_cost_table()).add(cost)
     return cost
 

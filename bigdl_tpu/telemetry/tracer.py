@@ -25,6 +25,13 @@ lifecycle edges, checkpoint writes, divergence drains).
   that touched it: ambient per thread (:func:`set_correlation`), or
   passed explicitly where an edge outlives a thread.
 
+* **Set-up records always** — import, engine build, warm-up and every
+  first compile are :meth:`Tracer.setup_span` scopes (category
+  ``setup``) that reach the ring whether or not the tracer is on; a
+  ``jax.monitoring`` listener splits the jax compiles under them into
+  ``compile/trace``, ``compile/lower`` and ``compile/backend`` children.
+  None is opened on a per-turn path.
+
 Env knobs: ``BIGDL_TPU_TRACE=1`` holds the global tracer on from
 import, ``BIGDL_TPU_TRACE_BUFFER`` sizes the ring (default 65536).
 """
@@ -33,7 +40,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import ContextDecorator, contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
@@ -46,6 +53,20 @@ CAT_DATA = "data"
 CAT_SERVE = "serve"
 CAT_DECODE = "decode"
 CAT_HOST = "host"
+CAT_SETUP = "setup"
+
+# jax's compile phases (jax 0.9.0 ``jax.monitoring`` events) -> the
+# child span each becomes under the open set-up scope; a backend compile
+# and a load from the persistent cache are timed under the same event
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
 
 
 class Span:
@@ -191,6 +212,16 @@ class Tracer:
             return
         if self._session and not self._forced and not _profiler_live():
             return  # the session ended under this span: not part of it
+        self._record(name, cat, t0, t1, corr, args)
+
+    def add_setup_span(self, name: str, t0: float, t1: float,
+                       args: Optional[Dict[str, Any]] = None) -> Span:
+        """Record a set-up interval (category ``setup``) whatever
+        ``enabled`` says: import, build, warm-up and first-compile sites
+        only, never a per-turn path."""
+        return self._record(name, CAT_SETUP, t0, t1, None, args)
+
+    def _record(self, name, cat, t0, t1, corr, args) -> Span:
         th = threading.current_thread()
         span = Span(name, cat, t0, t1, th.ident or 0, th.name,
                     corr if corr is not None else get_correlation(),
@@ -208,6 +239,7 @@ class Tracer:
                 fn(span)
             except Exception:
                 pass  # an observer must never take down engine threads
+        return span
 
     def instant(self, name: str, cat: str = CAT_HOST,
                 corr: Optional[str] = None,
@@ -244,6 +276,15 @@ class Tracer:
                 self.add_span(name, cat, t0, time.perf_counter(),
                               corr=corr, args=args)
 
+    def setup_span(self, name: str,
+                   args: Optional[Dict[str, Any]] = None) -> "SetupScope":
+        """A set-up scope (also a decorator): the enclosed block becomes
+        a ``setup`` span whatever ``enabled`` says, and a
+        ``TraceAnnotation`` while a profiler session is live.  jax's
+        compile phases inside it become its ``compile/*`` children and
+        its ``args`` count the persistent cache's hits and misses."""
+        return SetupScope(self, name, args)
+
     # -- reading -------------------------------------------------------
     def spans(self) -> List[Span]:
         """Snapshot of the ring in record order (oldest first)."""
@@ -253,6 +294,115 @@ class Tracer:
     def __len__(self) -> int:
         with self._lock:
             return len(self._buf)
+
+
+class SetupScope(ContextDecorator):
+    """One :meth:`Tracer.setup_span` scope: ``args`` is the dict the
+    span will carry (the block may add to it), ``span`` the recorded
+    :class:`Span` once the block has ended."""
+
+    def __init__(self, tracer: Tracer, name: str,
+                 args: Optional[Dict[str, Any]] = None):
+        self.tracer = tracer
+        self.name = name
+        self._given = args
+        self.args: Dict[str, Any] = dict(args) if args else {}
+        self.span: Optional[Span] = None
+        self._annotation = None
+        self._t0 = 0.0
+        # (name, t0, t1, fun, jax's own start), in the order they ended
+        self._phases: List[tuple] = []
+
+    def _recreate_cm(self):  # a fresh scope for each decorated call
+        return SetupScope(self.tracer, self.name, self._given)
+
+    @property
+    def duration(self) -> float:
+        return self.span.duration
+
+    def phase(self, name: str, t0: float, t1: float, fun: Optional[str],
+              start: float):
+        """A compile phase that ended under this scope (``start`` is
+        jax's own clock).  A phase that this one contains is folded
+        into it (jax traces a nested jit inside its caller's trace, and
+        lowering rules trace functions of their own): on one thread
+        phases nest, so the ones it contains are those that began at or
+        after its start."""
+        while self._phases and self._phases[-1][4] >= start:
+            self._phases.pop()
+        self._phases.append((name, t0, t1, fun, start))
+
+    def __enter__(self) -> "SetupScope":
+        _listen_to_compiles()
+        if TraceAnnotation.is_enabled():  # not a turn's poll: never counted
+            stats = {k: v for k, v in self.args.items()
+                     if isinstance(v, (int, float, str))}
+            self._annotation = TraceAnnotation(self.name, **stats)
+            self._annotation.__enter__()
+        _setup_stack().append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        _setup_stack().pop()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        for name, p0, p1, fun, _ in self._phases:
+            self.tracer.add_setup_span(name, p0, p1, {"fun": fun})
+        self.span = self.tracer.add_setup_span(self.name, self._t0, t1,
+                                               self.args)
+        return False
+
+
+def _setup_stack() -> List[SetupScope]:
+    """This thread's open set-up scopes, innermost last."""
+    stack = getattr(_tls, "setup", None)
+    if stack is None:
+        stack = _tls.setup = []
+    return stack
+
+
+def _on_compile_phase(event: str, start: float, end: float, **kw):
+    """jax reports a phase when it ends: the child span
+    ``[now - (end - start), now]`` of the thread's innermost set-up
+    scope."""
+    name = COMPILE_PHASES.get(event)
+    stack = getattr(_tls, "setup", None)
+    if name is None or not stack:
+        return
+    t1 = time.perf_counter()
+    stack[-1].phase(name, t1 - (end - start), t1, kw.get("fun_name"),
+                    start)
+
+
+def _on_cache_event(event: str, **_):
+    key = CACHE_EVENTS.get(event)
+    stack = getattr(_tls, "setup", None)
+    if key is not None and stack:
+        args = stack[-1].args
+        args[key] = args.get(key, 0) + 1
+
+
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
+
+
+def _listen_to_compiles():
+    """Register the compile listeners once a process, at the first
+    set-up scope (outside one they record nothing)."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    with _LISTEN_LOCK:
+        if not _LISTENING:
+            import jax.monitoring
+
+            jax.monitoring.register_event_time_span_listener(
+                _on_compile_phase)
+            jax.monitoring.register_event_listener(_on_cache_event)
+            _LISTENING = True
 
 
 _GLOBAL: Optional[Tracer] = None

@@ -1528,7 +1528,8 @@ def test_token_times_ttft_and_gaps(engine_lm, tracer):
 def test_off_path_decode_loop_creates_no_span(engine_lm, tracer,
                                               monkeypatch):
     """No session, tracer off: the decode loop creates no Span and
-    makes at most one profiler-state check per turn."""
+    makes at most one profiler-state check per turn (the engine's
+    build and warm-up leave their set-up spans, and only those)."""
     from bigdl_tpu.telemetry import tracer as tracer_mod
 
     checks, turns, made = [], [], []
@@ -1554,11 +1555,53 @@ def test_off_path_decode_loop_creates_no_span(engine_lm, tracer,
         monkeypatch.setattr(tracer_mod, "_profiler_live", live)
         monkeypatch.setattr(eng, "_drain_queue", counted)
         checks.clear()
+        built = tracer.spans()
         for f in [eng.submit([1, 2, 3], 30) for _ in range(3)]:
             f.result(120)
         n_checks, n_turns = len(checks), len(turns)
     assert n_turns >= 50 and n_checks <= n_turns + 1
-    assert not made and len(tracer) == 0
+    assert not made and tracer.spans() == built
+    assert {s.cat for s in built} == {"setup"}
+
+
+def test_setup_spans_record_the_engine_build_with_tracing_off(engine_lm,
+                                                              tracer):
+    """Tracer off: building and warming an engine leaves ``setup/engine``
+    holding each lane's ``setup/cache_init`` and ``setup/warmup``, which
+    holds one ``compile`` per declared program, each with a
+    ``compile/trace`` and a ``compile/backend`` child, all of category
+    ``setup``; serving afterwards adds no span."""
+    model, var = engine_lm
+    with _engine(model, var) as eng:
+        spans = tracer.spans()
+        for f in [eng.submit([1, 2, 3], 6) for _ in range(3)]:
+            f.result(120)
+        assert tracer.spans() == spans
+        declared = eng.declared_programs()
+    assert not tracer.enabled
+    assert {s.cat for s in spans} == {"setup"}
+
+    def inside(inner, outer):
+        return (inner.tid == outer.tid and outer.t0 <= inner.t0
+                and inner.t1 <= outer.t1)
+
+    (engine,) = [s for s in spans if s.name == "setup/engine"]
+    (warm,) = [s for s in spans if s.name == "setup/warmup"]
+    (cache,) = [s for s in spans if s.name == "setup/cache_init"]
+    assert engine.args == {"slots": 2, "lanes": 1}
+    assert warm.args == {"declared": declared, "compiled": declared}
+    assert cache.args["lane"] == "decode" and cache.args["bytes"] > 0
+    assert inside(cache, engine) and inside(warm, engine)
+    compiles = [s for s in spans if s.name == "compile"]
+    assert len(compiles) == declared
+    for c in compiles:
+        assert inside(c, warm)
+        assert {"compile/trace", "compile/backend"} <= {
+            s.name for s in spans
+            if s.name.startswith("compile/") and inside(s, c)}
+    assert {s.args["program"] for s in spans
+            if s.name == "setup/signature"} == {
+        c.args["program"] for c in compiles}
 
 
 def _scopes_in(jitted, *args):

@@ -30,6 +30,21 @@ def test_clean_zoo_lints_with_zero_findings():
     assert len(results) >= 15
 
 
+def test_telemetry_step_parity_holds_under_a_setup_scope():
+    """``telemetry_step_parity`` stages the instrumented step inside a
+    set-up scope too (its compile listener live): the step's jaxpr is
+    still byte-identical, and the scope reached the ring."""
+    from bigdl_tpu import telemetry
+
+    tr = telemetry.get_tracer()
+    tr.clear()
+    results, errors = analysis.lint(["telemetry_step_parity"])
+    assert not errors and results == {"telemetry_step_parity": []}
+    assert [s.args for s in tr.spans() if s.name == "compile"] == [
+        {"program": "lint_step"}]
+    tr.clear()
+
+
 # ---------------------------------------------------------------------------
 # seeded defects: each trips exactly its rule
 # ---------------------------------------------------------------------------

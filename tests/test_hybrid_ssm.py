@@ -674,7 +674,9 @@ def test_the_cells_files_hold_its_geometry_and_readers():
             "expert_load_max_over_mean")]
         + [n + ".ssm_moe_serve" for n in (
             "tick_mfu", "tick_hbm_roofline", "ssm_step_roofline",
-            "ssd_prefill_roofline", "moe_experts_roofline")])
+            "ssd_prefill_roofline", "moe_experts_roofline")]
+        + [n + ".setup" for n in (
+            "setup_program_s", "setup_trace_s", "setup_compile_s")])
     for name in listed:
         assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
                                            name + ".py")), name

@@ -18,6 +18,9 @@
   corr stats, the session as the switch), the four program_span
   readers of ``benchmark/metrics`` on hand-made rings, and the count
   guard of the off path;
+* set-up scopes, recorded with tracing off: the compile listener's
+  phases on their own thread, the persistent cache's counters, and
+  the three ``.setup`` readers on a hand-made ring;
 * ``bench.telemetry_ab`` really records under every plane (counts).
 """
 import functools
@@ -311,14 +314,16 @@ def test_explicit_enable_outlives_a_profiler_session(clean_tracer,
 def test_off_path_training_loop_creates_no_span(clean_tracer,
                                                 monkeypatch):
     """The guard that replaces the wall-clock overhead gates: with no
-    session and the tracer off, 50 iterations create no Span and make
-    at most one profiler-state check per loop turn (the training loop
-    and the prefetch producer each poll once a turn)."""
+    session and the tracer off, the first iteration makes exactly the
+    set-up spans (the cost stamp, the compile and its jax phases, all
+    category ``setup``), iterations 2-50 create no Span, and every loop
+    turn makes at most one profiler-state check (the training loop and
+    the prefetch producer each poll once a turn)."""
     import threading
 
     from bigdl_tpu.telemetry import tracer as tracer_mod
 
-    checks, made = {}, []
+    checks, made, after_first = {}, [], []
 
     def live():
         name = threading.current_thread().name
@@ -336,12 +341,28 @@ def test_off_path_training_loop_creates_no_span(clean_tracer,
     x = rs.randn(64, 8).astype(np.float32)
     y = rs.randint(0, 4, 64)
     model = nn.Sequential(nn.Linear(8, 16), nn.Tanh(), nn.Linear(16, 4))
+    fifty = Trigger.max_iteration(50)
+
+    def end(state):  # called before every iteration
+        if state["neval"] == 1:
+            after_first.append(len(made))
+        return fifty(state)
+
     engine = LocalOptimizer(model, DataSet.from_arrays(x, y, batch_size=16),
                             nn.ClassNLLCriterion(logits=True),
-                            Trigger.max_iteration(50))
+                            Trigger(end, "fifty"))
     engine.set_optim_method(SGD(0.1))
     engine.optimize()
-    assert not made and len(clean_tracer) == 0
+    spans = clean_tracer.spans()
+    assert [s.name for s in spans] == made
+    assert {s.cat for s in spans} == {"setup"}
+    assert {"setup/stamp", "compile", "compile/trace",
+            "compile/backend"} <= set(made) <= {
+        "setup/stamp", "compile", "compile/trace", "compile/lower",
+        "compile/backend"}
+    assert made.count("compile") == made.count("setup/stamp") == 1
+    # iterations 2-50 made none
+    assert after_first and set(after_first) == {len(made)}
     loop = threading.current_thread().name
     assert checks[loop] == 50
     # the producer runs ahead by its queue's depth, plus the turn that
@@ -416,6 +437,208 @@ def test_program_span_readers(clean_tracer, metric, kind, ring, want):
     assert read_metric(metric, run) == pytest.approx(want)
     other = "train" if kind == "decode" else "decode"
     assert read_metric(metric, {"kind": other}) is None
+
+
+def _setup_ring(tr):
+    """A hand-made ring of set-up spans on two threads (seconds after
+    ``base``): the main thread's import and engine build, a compile on
+    a second thread, a window span, and an engine built after it."""
+    import threading
+
+    base = 100.0
+
+    def add(name, t0, t1):
+        tr.add_setup_span(name, base + t0, base + t1)
+
+    add("setup/import", 0.0, 2.0)
+    for name, t0, t1 in (("compile/trace", 4.0, 4.6),
+                         ("compile/lower", 4.6, 4.9),
+                         ("setup/stamp", 4.0, 5.0),
+                         ("compile/trace", 5.0, 5.5),
+                         ("compile/backend", 5.5, 8.0),
+                         ("compile", 5.0, 8.0),
+                         ("compile/backend", 8.2, 9.7),
+                         ("compile", 8.0, 10.0),
+                         ("setup/cache_init", 3.0, 4.0),
+                         ("setup/warmup", 4.0, 10.0),
+                         ("setup/engine", 3.0, 10.0)):
+        add(name, t0, t1)
+    other = threading.Thread(target=lambda: [
+        add(name, t0, t1) for name, t0, t1 in (
+            ("compile/trace", 1.0, 1.5), ("compile/backend", 1.5, 3.5),
+            ("compile", 1.0, 4.0))])
+    other.start()
+    other.join(10)
+    assert not other.is_alive()
+    tr.enable()
+    tr.add_span("loop/tick_dispatch", "decode", base + 20.0, base + 20.01)
+    add("compile/backend", 31.0, 34.0)  # the harness's second engine
+    add("setup/engine", 30.0, 35.0)
+
+
+@pytest.mark.parametrize("metric,want", [
+    # main thread [0, 2] + [3, 10], the other thread [1, 4]
+    ("setup_program_s.setup", 9.0 + 3.0),
+    # traces and lowerings: 0.6 + 0.3 + 0.5 on main, 0.5 on the other
+    ("setup_trace_s.setup", 1.4 + 0.5),
+    # backend compiles and cache loads: 2.5 + 1.5 on main, 2.0 on the other
+    ("setup_compile_s.setup", 4.0 + 2.0),
+])
+def test_setup_readers(clean_tracer, metric, want):
+    """The three ``.setup`` readers on a hand-made ring: each thread's
+    covered seconds, a child counted once beside its parent, nothing
+    after the program's first span of another category; nothing from a
+    ring with no set-up span (the parent commit's)."""
+    from benchmark.run import read_metric
+
+    tr = clean_tracer
+    for cell in ("lm-train", "gigachat-decode-closed32"):
+        assert read_metric(metric, {"kind": cell}) is None
+    tr.enable()
+    tr.add_span("dispatch", "train", 0.0, 0.001)
+    assert read_metric(metric, {"kind": "train"}) is None
+    tr.disable()
+    tr.clear()
+    _setup_ring(tr)
+    assert read_metric(metric, {"kind": "decode"}) == pytest.approx(want)
+
+
+def test_setup_scope_records_with_tracing_off(clean_tracer, monkeypatch):
+    """A set-up scope reaches the ring with the tracer off, also as a
+    decorator (a fresh span a call) and when its block raises, enters
+    no ``TraceAnnotation`` without a session, and its duration is its
+    span's."""
+    from bigdl_tpu.telemetry import tracer as tracer_mod
+
+    entered, live = [], [False]
+
+    class Annotation:
+        is_enabled = staticmethod(lambda: live[0])
+
+        def __init__(self, name, **kw):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Annotation)
+    tr = clean_tracer
+    assert not tr.enabled
+
+    @tr.setup_span("setup/build", {"k": 1})
+    def build(x):
+        return x + 1
+
+    assert build(1) == 2 and build(2) == 3
+    with tr.setup_span("compile", {"program": "p"}) as scope:
+        scope.args["extra"] = 7
+    spans = tr.spans()
+    assert [s.name for s in spans] == ["setup/build", "setup/build",
+                                       "compile"]
+    assert {s.cat for s in spans} == {telemetry.CAT_SETUP}
+    assert spans[0].args == spans[1].args == {"k": 1}
+    assert spans[0] is not spans[1]
+    assert spans[2].args == {"program": "p", "extra": 7}
+    assert scope.duration == spans[2].duration >= 0.0
+    assert not entered                      # no session: no annotation
+    with pytest.raises(ValueError):         # a failed build is on record
+        with tr.setup_span("setup/engine"):
+            raise ValueError("bad argument")
+    assert tr.spans()[-1].name == "setup/engine"
+    assert not tracer_mod._setup_stack()
+    live[0] = True
+    with tr.setup_span("compile", {"program": "q"}):
+        pass
+    assert entered == ["compile"]
+
+
+def test_compile_phases_go_under_their_own_threads_scope(clean_tracer):
+    """Two threads compile at once, each inside a ``compile`` scope of
+    its own: each scope gets its trace, lower and backend children on
+    its own thread and inside its own span, and a compile on a thread
+    with no scope open records nothing."""
+    import threading
+
+    import jax.numpy as jnp
+
+    tr = clean_tracer
+    both_open = threading.Barrier(3)
+    done = threading.Event()
+
+    def work(k):
+        f = jax.jit(lambda x: jnp.sin(x) * float(k))
+        x = np.ones((3 + k,), np.float32)
+        with tr.setup_span("compile", {"program": f"p{k}"}):
+            both_open.wait(30)
+            f(x).block_until_ready()
+            done.wait(30)
+
+    threads = [threading.Thread(target=work, args=(k,), name=f"c{k}")
+               for k in (1, 2)]
+    for t in threads:
+        t.start()
+    both_open.wait(30)
+    jax.jit(lambda x: x * 3.0)(np.ones((7,), np.float32))  # no scope here
+    done.set()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    spans = tr.spans()
+    assert all(s.thread in ("c1", "c2") for s in spans)
+    for k in (1, 2):
+        (c,) = [s for s in spans
+                if s.name == "compile" and s.args["program"] == f"p{k}"]
+        kids = [s for s in spans
+                if s.name.startswith("compile/") and s.thread == f"c{k}"]
+        assert {"compile/trace", "compile/lower", "compile/backend"} <= {
+            s.name for s in kids}
+        assert all(s.tid == c.tid and c.t0 <= s.t0 <= s.t1 <= c.t1
+                   for s in kids)
+
+
+def test_setup_scope_counts_persistent_cache_hits_and_misses(clean_tracer,
+                                                             tmp_path):
+    """With a persistent cache in a fresh directory and no minimum
+    compile time, a first compile reads ``cache_misses`` 1 on its span
+    and a fresh jit of the same function ``cache_hits`` 1."""
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_enable_compilation_cache")
+    saved = {k: getattr(jax.config, k) for k in keys}
+
+    def fresh():
+        def f(x):
+            return jnp.tanh(x) * 2.0 + 1.0
+        return jax.jit(f)
+
+    x = jnp.ones((5,), jnp.float32)
+    tr = clean_tracer
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+        with tr.setup_span("compile", {"program": "first"}) as first:
+            fresh()(x).block_until_ready()
+        with tr.setup_span("compile", {"program": "second"}) as second:
+            fresh()(x).block_until_ready()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert first.args == {"program": "first", "cache_misses": 1}
+    assert second.args == {"program": "second", "cache_hits": 1}
+    assert "compile/backend" in {s.name for s in tr.spans()
+                                 if first.span.t0 <= s.t0
+                                 and s.t1 <= first.span.t1}
 
 
 def test_paged_attn_roofline_reads_the_traced_ticks_own_pages(clean_tracer):

@@ -639,7 +639,9 @@ def test_the_cells_files_say_what_the_issue_asked_for():
             "expert_load_max_over_mean")]
         + [n + ".swa_moe_serve" for n in (
             "tick_mfu", "tick_hbm_roofline", "paged_attn_roofline",
-            "moe_experts_roofline", "window_rows_read_share")])
+            "moe_experts_roofline", "window_rows_read_share")]
+        + [n + ".setup" for n in (
+            "setup_program_s", "setup_trace_s", "setup_compile_s")])
     for name in listed:
         assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
                                            name + ".py")), name
