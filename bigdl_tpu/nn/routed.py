@@ -27,11 +27,12 @@ them alike and a caller that sums ranks counts them once
 Static shapes: the ``T*k`` token-expert assignments are sorted by the
 local expert they name (assignments to absent experts last), the rows
 that landed here gathered in that order, and the three expert products
-run over the groups.  A buffer of more than ``SHORT_BATCH_ROWS`` rows
-(a prefill chunk, a bucketed prefill) takes the repo's grouped-matmul
-kernel (ops/pallas/grouped_matmul.py), which visits only the row tiles
-that landed, at a row tile of the rows an expert expects,
-``T * k / n_routed``; a tick's buffer, and every buffer off the TPU,
+run over the groups.  Every buffer, a tick's as a prefill chunk's,
+takes the repo's grouped-matmul kernel (ops/pallas/grouped_matmul.py)
+where its shape routes there: the kernel visits only the row tiles that
+landed, at a row tile of the rows an expert expects,
+``T * k / n_routed``, and never reads the weights of an expert that got
+no row.  A shape the kernel refuses, and every buffer off the TPU,
 takes ``jax.lax.ragged_dot``.  Each token then gathers its own rows
 where they landed and sums them, weighted, in f32 in the order of its
 experts' local ids.
@@ -200,11 +201,10 @@ class RoutedExperts(Module):
 
     # --------------------------------------------------------- experts
     def _product(self, x, w, counts, rows: int):
-        """One grouped product ``(cap, K) x (E, K, N)``: the kernel for a
-        long buffer where it routes, at a row tile of the ``rows`` an
-        expert expects, ``ragged_dot`` otherwise."""
-        tiling = grouped_matmul.routes(x.shape, w.shape, x.dtype, rows) \
-            if x.shape[0] > SHORT_BATCH_ROWS else None
+        """One grouped product ``(cap, K) x (E, K, N)``: the kernel
+        wherever the buffer's shape routes, at a row tile of the ``rows``
+        an expert expects, ``ragged_dot`` otherwise."""
+        tiling = grouped_matmul.routes(x.shape, w.shape, x.dtype, rows)
         if tiling:
             return grouped_matmul.grouped_matmul(x, w, counts, tiling=tiling)
         return jax.lax.ragged_dot(x, w, counts)

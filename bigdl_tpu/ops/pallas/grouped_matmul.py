@@ -27,8 +27,10 @@ tile of the dtype and 256: a group then spans about one tile and its
 share of straddled tiles is small.  The routed layer passes
 ``T * k / n_routed``: 64 rows in the latent cell's chunk (2048 tokens, 8
 of 256 experts), 128 in the window cell's (8 of 128), 16-32 at a prefill
-bucket of 512.  ``tn`` is the widest divisor of N in whole lanes whose
-double-buffered ``(K, tn)`` block fits ``WEIGHT_VMEM_BYTES``.
+bucket of 512, and 1-2 at a 32-row decode tick, where ``tm`` is one bf16
+tile of 16 and a tile holds the rows of several groups.  ``tn`` is the
+widest divisor of N in whole lanes whose double-buffered ``(K, tn)``
+block fits ``WEIGHT_VMEM_BYTES``.
 
 **Bounds** (v5e: 819 GB/s, 197 TFLOP/s bf16).  At the window cell's
 shapes (128 groups, 16384 rows, K x N = 2048 x 1024 and 1024 x 2048,
@@ -37,7 +39,13 @@ shapes (128 groups, 16384 rows, K x N = 2048 x 1024 and 1024 x 2048,
 recompute (up to a tile a group: 1.5-2x the rows at ``tm`` 64-128).  At
 the latent cell's (16 held groups, about 1024 of 4096 rows landed, 7168
 x 2048 with ``tn`` 1024 and 2048 x 7168 with ``tn`` 3584) a product
-reads 470 MB, 0.57 ms, for 30 GFLOP: the weights bound it.
+reads 470 MB, 0.57 ms, for 30 GFLOP: the weights bound it.  A 32-row
+tick is all weights: in the window cell 256 rows over 128 groups, of
+which about 111 get a row, read 111 blocks of 4 MiB, 466 MB, 0.57 ms
+(the 17 idle groups are never read), for 1.1 GFLOP; in the latent cell
+about 16 of the 256 rows land and touch about 10 of the 16 held groups,
+300 MB, 0.37 ms (470 MB, 0.57 ms, where all 16 are touched), in about
+11 visits a column.
 """
 from __future__ import annotations
 

@@ -355,7 +355,8 @@ def test_latent_moe_tick_compiles_at_published_widths(one_chip):
     # ungated 4.89 MiB + 2.03 of logits (32 x 16032 f32, padded)
     _sampling_is_gated(compiled, cfg["vocab_size"], 7.0)
     assert report.report()["latent_paged_attention"]["pallas"] == before + 1
-    assert "latent_paged_attn" in text and "ragged-dot" in text
+    assert "latent_paged_attn" in text and "grouped_matmul" in text
+    assert "ragged-dot" not in text
 
 
 def test_latent_serving_kernels_compile_at_cell_shapes(one_chip):
@@ -430,8 +431,8 @@ def test_window_serving_kernels_compile_at_cell_shapes(one_chip):
 @pytest.mark.parametrize("shape", KS.GROUPED_MATMUL,
                          ids=lambda s: "x".join(map(str, s)))
 def test_grouped_matmul_compiles_at_chunk_shapes(one_chip, shape):
-    """The routed experts' long-buffer kernel at both routed cells'
-    chunk shapes, at the tile the rule picks there (whole K: the weight
+    """The routed experts' kernel at the routed cells' chunk and tick
+    shapes, at the tile the rule picks there (whole K: the weight
     block's double buffer has to fit the scoped VMEM it asks for)."""
     from bigdl_tpu.ops.pallas import grouped_matmul as gm
 
@@ -484,7 +485,8 @@ def test_window_moe_tick_compiles_at_published_widths(one_chip):
     text = compiled.as_text()
     _pool_in_place(text, cache)
     assert report.report()["paged_attention"]["pallas"] == before + 2
-    assert "paged_attn" in text and "ragged-dot" in text
+    assert "paged_attn" in text and "grouped_matmul" in text
+    assert "ragged-dot" not in text
 
 
 def test_hybrid_ssm_tick_and_chunk_compile_at_published_widths(one_chip):
@@ -494,7 +496,8 @@ def test_hybrid_ssm_tick_and_chunk_compile_at_published_widths(one_chip):
     blocks among them, donated and aliased with no whole-pool copy; a
     layer's state step is one fusion that reads the f32 block once and
     writes it once beside ``y``; attention is ``paged_attn``; the
-    chunk's expert products are the grouped-matmul kernel."""
+    tick's and the chunk's expert products are the grouped-matmul
+    kernel, in both branches of the buffer."""
     import json
     import os
     import re
@@ -527,7 +530,8 @@ def test_hybrid_ssm_tick_and_chunk_compile_at_published_widths(one_chip):
     text = compiled.as_text()
     _pool_in_place(text, cache)
     assert report.report()["paged_attention"]["pallas"] == before + 1
-    assert "paged_attn" in text and "ragged-dot" in text
+    assert "paged_attn" in text and "grouped_matmul" in text
+    assert "ragged-dot" not in text
     block = "f32[%d,128,64,128]" % slots
     steps = [line for line in text.splitlines()
              if re.search(r"= \(.*%s.*\) fusion\(" % re.escape(block), line)

@@ -50,11 +50,11 @@ LATENT_PAGED_ATTN = [(32, 64, 640, 512, 16, 512)]
 # (ops/pallas/flash_attention.prefix_flash_attention)
 FLASH_PREFIX = [(1, 64, 2048, 8192, 192)]
 
-# the routed experts' long buffer (M, K, N, groups, rows an expert
-# expects): a 2048-token prefill chunk's three grouped products
+# the routed experts' buffer (M, K, N, groups, rows an expert expects):
+# a 2048-token prefill chunk's three grouped products
 # (ops/pallas/grouped_matmul.py via nn/routed.py) - the latent cell's
 # likely buffer over its 16 held experts, 64 rows each expected, and the
-# window cell's full buffer over all 128, 128 each
+# window cell's full buffer over all 128, 128 each - and a 32-row tick's
 GROUPED_MATMUL = [(4096, 7168, 2048, 16, 64), (4096, 2048, 7168, 16, 64),
                   (16384, 2048, 1024, 128, 128),
                   (16384, 1024, 2048, 128, 128),
@@ -63,7 +63,12 @@ GROUPED_MATMUL = [(4096, 7168, 2048, 16, 64), (4096, 2048, 7168, 16, 64),
                   # 88 each expected (the full buffer: more than the
                   # likely one lands at a quarter of the experts)
                   (45056, 1024, 2688, 128, 88),
-                  (45056, 2688, 1024, 128, 88)]
+                  (45056, 2688, 1024, 128, 88),
+                  # the tick's 32 rows x 8 experts: the latent cell's
+                  # 256 over its 16 held of 256 (1 expected, about 16
+                  # land) and the window cell's over all 128 (2 each)
+                  (256, 7168, 2048, 16, 1), (256, 2048, 7168, 16, 1),
+                  (256, 2048, 1024, 128, 2), (256, 1024, 2048, 128, 2)]
 
 # the hybrid Mamba-2 cell's geometry (slots, max_len, page, prompt chunk;
 # benchmark/traffic/decode-reasoning-closed128.json): the tick steps 128
