@@ -284,12 +284,16 @@ class Mamba2Mixer(Module):
 class GroupedAttention(GatedWindowAttention):
     """Grouped-query attention with no norm on q or k, no gate, no
     rotary and no band: the paged layer of nn/window_moe.py without the
-    parts that family adds."""
+    parts that family adds.  ``scale`` multiplies the scores (default
+    ``head_dim ** -0.5``)."""
 
     def __init__(self, hidden_size: int, num_heads: int, kv_heads: int,
-                 head_dim: int, name: Optional[str] = None):
+                 head_dim: int, scale: Optional[float] = None,
+                 name: Optional[str] = None):
         super().__init__(hidden_size, num_heads, kv_heads, head_dim,
                          window=None, name=name)
+        if scale is not None:
+            self.scale = float(scale)
 
     def init_params(self, rng, dtype=jnp.float32):
         ks = jax.random.split(rng, 4)
@@ -335,22 +339,26 @@ class HybridBlock(Module):
         return {"norm": {"weight": jnp.ones((self.hidden_size,), dtype)},
                 self.mixer: (self.attn or self.ffn).init_params(rng, dtype)}
 
+    def mix(self, params, x, attend, rows=None):
+        """A stateful mixer over ``RMSNorm(x)`` -> ``(a, aux)``."""
+        h = rms_norm(x, params["norm"]["weight"], self.eps)
+        if self.kind == MAMBA:
+            with jax.named_scope("mixer"):
+                return attend(h, rows)
+        with jax.named_scope("attention"), jax.named_scope("full"):
+            return attend(h, rows)
+
     def run(self, params, x, attend, rows=None):
         """``attend(h, rows) -> (a, aux)`` is a stateful mixer's path;
         ``rows`` (N, T) bool marks the tokens that are no padding.  ->
         ``(x, aux or None, expert counts or None)``."""
-        h = rms_norm(x, params["norm"]["weight"], self.eps)
         if self.kind == MOE:
+            h = rms_norm(x, params["norm"]["weight"], self.eps)
             with jax.named_scope("ffn"):
                 f, counts = self.ffn.apply_counted(params["ffn"], h,
                                                    rows=rows)
             return x + f, None, counts
-        if self.kind == MAMBA:
-            with jax.named_scope("mixer"):
-                a, aux = attend(h, rows)
-        else:
-            with jax.named_scope("attention"), jax.named_scope("full"):
-                a, aux = attend(h, rows)
+        a, aux = self.mix(params, x, attend, rows)
         return x + a, aux, None
 
     def apply(self, params, state, x, training=False, rng=None):

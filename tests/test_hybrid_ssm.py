@@ -462,14 +462,16 @@ def _digest(family: str) -> str:
     the full forward over 300 tokens (the routed buffer's long-batch
     branch), a prefill and four decode steps."""
     if family == "latent":
-        import test_latent_moe as fam
+        from test_latent_moe import build as build_family
+    elif family == "window":
+        from test_window_moe import build as build_family
     else:
-        import test_window_moe as fam
-    model, var, _ = fam.build(seed=4)
+        build_family = build
+    model, var, _ = build_family(seed=4)
     params = jax.tree_util.tree_map(lambda v: v.astype(jnp.bfloat16),
                                     var["params"])
     ids = np.asarray(jax.random.randint(jax.random.PRNGKey(11), (2, 300),
-                                        0, 96))
+                                        0, model.vocab_size))
     h = hashlib.sha256()
     logits, _ = jax.jit(model.apply)(params, var["state"], ids)
     h.update(np.asarray(logits, np.float32).tobytes())
@@ -489,11 +491,15 @@ def _digest(family: str) -> str:
     ("latent",
      "c8259cca729aa5f0bfb508857f637fb3c8541bf6777a9504ebcb49f36243eef2"),
     ("window",
-     "ec79a78c504c73dd7f0798eb85670427eb463b7f0f65b0c4442d1a97ccde5508")])
+     "ec79a78c504c73dd7f0798eb85670427eb463b7f0f65b0c4442d1a97ccde5508"),
+    ("hybrid",
+     "e24c9023acd6fe2f111a2c2e249a5faf92f9ac41152f31bd931fe58840201055")])
 def test_the_gated_families_logits_are_bit_equal_to_before(family, digest):
     """The routed layer took the two-matrix expert and the latent space
-    as arguments; the gigachat and trinity families' logits are what
-    they were before, bit for bit (the digests of the parent commit)."""
+    as arguments, and the Granite family shares the Mamba-2 and
+    attention layers; the gigachat, trinity and nemotron families'
+    logits are what they were before, bit for bit (each digest taken
+    before the change it guards)."""
     assert _digest(family) == digest
 
 

@@ -544,3 +544,66 @@ def test_hybrid_ssm_tick_and_chunk_compile_at_published_widths(one_chip):
         S((1,), jnp.int32)).compile()
     assert "grouped_matmul" in chunked.as_text()
     assert chunked.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_granite_tick_chunk_and_prefill_compile_at_published_widths(
+        one_chip):
+    """Granite 4.0-H's paged tick, prompt chunk and widest bucketed
+    prefill at the published widths (one Mamba-2 and one attention
+    layer, each with its dense MLP; bf16; the cell's 64 slots x 4096 in
+    pages of 64): every pool leaf, the state blocks among them, donated
+    and aliased with no whole-pool copy; the state step is one fusion
+    that reads the f32 block once and writes it once; attention is
+    ``paged_attn`` at the family's scale; the MLP is under its own
+    scope; the prompt programs' temporaries stay under a GiB."""
+    import json
+    import os
+    import re
+
+    from bigdl_tpu.nn.granite_hybrid import GraniteHybridTransformer
+    from bigdl_tpu.ops.pallas import report
+    from bigdl_tpu.serving import paging
+    from bigdl_tpu.serving.decode_programs import (build_paged_tick,
+                                                   build_prefill,
+                                                   build_prefill_chunk)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)["model"]
+    model = GraniteHybridTransformer(**dict(
+        cfg, layer_types=["mamba", "attention"]))
+    assert model.layers[1].attn.scale == 0.015625
+    slots, max_len, page, chunk, batch = KS.GRANITE_DECODE
+    kv = paging.PagedCache(slots, max_len, page,
+                           paging.default_num_pages(slots, max_len, page))
+    var = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), BF16))
+    cache = jax.eval_shape(lambda: kv.init_cache(model, BF16))
+    state = cache["layer0"]["ssm"]
+    assert state.shape == (slots, 64, 64, 128) and state.dtype == F32
+    before = report.report().get("paged_attention", {}).get("pallas", 0)
+    compiled = build_paged_tick(model, **_on(one_chip)).lower(
+        var["params"], var["state"], cache,
+        S((slots, max_len // page), jnp.int32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots, 2), jnp.uint32), S((slots,), F32),
+        S((slots,), jnp.int32), S((slots,), F32)).compile()
+    text = compiled.as_text()
+    _pool_in_place(text, cache)
+    assert report.report()["paged_attention"]["pallas"] == before + 1
+    assert "paged_attn" in text and "/mlp/" in text
+    block = "f32[%d,64,64,128]" % slots
+    steps = [line for line in text.splitlines()
+             if re.search(r"= \(.*%s.*\) fusion\(" % re.escape(block), line)
+             and "mixer/ssm" in line]
+    assert len(steps) == 1, steps
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    staging = jax.eval_shape(lambda: model.init_cache(1, max_len, BF16))
+    chunked = build_prefill_chunk(model, **_on(one_chip)).lower(
+        var["params"], var["state"], staging, S((1, chunk), jnp.int32),
+        S((1,), jnp.int32)).compile()
+    assert chunked.memory_analysis().temp_size_in_bytes < 2 ** 30
+    prefill = build_prefill(model, max_len, BF16, **_on(one_chip)).lower(
+        var["params"], var["state"], S((batch, chunk), jnp.int32),
+        S((batch,), jnp.int32)).compile()
+    assert prefill.memory_analysis().temp_size_in_bytes < 2 ** 30

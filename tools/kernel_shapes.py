@@ -76,6 +76,13 @@ GROUPED_MATMUL = [(4096, 7168, 2048, 16, 64), (4096, 2048, 7168, 16, 64),
 # through paged_attn (32 query heads over 2 K/V heads of 128)
 HYBRID_DECODE = (128, 8192, 64, 2048)
 
+# the Granite 4.0-H cell's geometry (slots, max_len, page, prompt chunk,
+# largest prefill batch; benchmark/traffic/decode-chat-closed64.json):
+# the tick steps 64 state blocks in each of 36 Mamba-2 layers and reads
+# four attention layers' pages through paged_attn (32 query heads over 8
+# K/V heads of 64); a bucketed prefill runs 4 prompts of 2048
+GRANITE_DECODE = (64, 4096, 64, 2048, 4)
+
 # ---------------------------------------------------------------------
 # cached-decode serving shapes (serving/decode.py, docs/decoding.md):
 # the slot-grid geometry shared by bench.py --decode-ab, the
